@@ -573,6 +573,10 @@ class JobClient:
             params["group_breakdown"] = "true"
         return self._request("GET", "/usage", params=params)
 
+    def running(self) -> List[Dict]:
+        """Every live instance (task_id, job_uuid, status, hostname)."""
+        return self._request("GET", "/running")
+
     def queue(self) -> Dict:
         return self._request("GET", "/queue")
 

@@ -27,8 +27,9 @@ class MatcherConfig:
     # kernel with no J x H work at all; "cpu" = numpy fallback;
     # "tpu-megakernel" = single-launch Pallas fused cycle (rank +
     # admission + match + gang reduce in one kernel, ops/pallas_cycle.py;
-    # interpret-mode on CPU — bit-identical to the fused XLA driver, and
-    # what "auto" prefers at the CYCLE level on a real TPU backend).
+    # interpret-mode on CPU — bit-identical to the fused XLA driver).
+    # An explicit pin only: "auto" never selects it, and a pin whose
+    # kernel does not lower on the device raises instead of degrading.
     backend: str = "auto"
     auto_large_j_threshold: int = 2000
     # what "auto" optimizes for ABOVE the threshold
@@ -507,11 +508,13 @@ class PipelineConfig:
     #: speculation: intermediate unfetched cycles' candidates can't be
     #: masked out of later stages, so the conflict-drop rate rises.
     depth: int = 2
-    #: JAX persistent compilation cache directory ("" = disabled): fused
-    #: cycle executables survive process restarts, so a failover or
-    #: rolling restart re-traces but never re-COMPILES (the 16.5 s
-    #: first-call spikes in BENCH_r05 land at boot, inside warmup, or
-    #: not at all — never inside a live cycle).
+    #: JAX persistent compilation cache directory: fused cycle
+    #: executables survive process restarts, so a failover or rolling
+    #: restart re-traces but never re-COMPILES.  Ignored when
+    #: ``JAX_COMPILATION_CACHE_DIR`` is set (the environment places the
+    #: cache and no directory is set in code); "" = ``<checkout>/
+    #: .jax_cache`` on a TPU, no cache on CPU
+    #: (ops/telemetry.enable_compilation_cache holds the rule).
     compilation_cache_dir: str = ""
     #: boot-time warmup sweep: pre-compile (and execute once, with
     #: zeroed inputs) the compact fused cycle at the bucket grid implied

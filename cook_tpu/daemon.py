@@ -659,6 +659,14 @@ class CookDaemon:
                     self.store, self.sched_config, clusters,
                     rank_backend=self.rank_backend, plugins=self.plugins,
                     rate_limits=self.rate_limits)
+                dev = self.scheduler.device
+                print(f"cook_tpu: scheduler kernels on platform="
+                      f"{dev['platform']} device_kind={dev['device_kind']} "
+                      f"count={dev['count']}", flush=True)
+                # a kernel that cannot build stops the cycle threads;
+                # same exit as a lost election: non-zero, supervisor
+                # restarts, nothing keeps serving on a dead device path
+                self.scheduler.on_fatal = lambda _exc: self._on_loss()
                 self.scheduler.run()
                 self.api.scheduler = self.scheduler
                 if self.fleet is not None:

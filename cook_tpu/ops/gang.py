@@ -324,6 +324,8 @@ def apply_gang_cycle(jobs, assign: np.ndarray, offers,
         elif device:
             try:
                 out, dropped = gang_reduce_kernel(assign, pack)
+            except telemetry.KernelBuildError:
+                raise  # repeats every cycle: not a fault to absorb
             except Exception:
                 import logging
                 logging.getLogger(__name__).exception(

@@ -39,16 +39,24 @@ megakernel / fused / split / depth-2 pipelined drivers, rigid and
 elastic gangs, compact and quantized wire.
 
 On CPU the kernel runs in interpret mode (tier-1 honest, like
-ops/pallas_match.py); on TPU a Mosaic lowering failure degrades to the
-fused XLA driver with ``cook_kernel_fallback_total{kernel=
-pallas.megacycle}`` — the cycle never dies (docs/ROBUSTNESS.md).
+ops/pallas_match.py).  ON THE TPU IT DOES NOT LOWER: Pallas refuses it
+for the v5e at the first in-kernel gather (``res_base[rows]``:
+``ValueError: Shape mismatch in input, indices and output`` from the
+Mosaic gather lowering rule, at T=128Ki and at T=1Ki alike — CHANGES.md
+PR 21), before the in-kernel sort, the scatter, or the VMEM budget below
+are ever tried.  So ``auto`` never selects it, and an explicit
+``tpu-megakernel`` pin raises ``KernelBuildError`` on the chip instead
+of degrading (docs/ROBUSTNESS.md); only a runtime fault on an executable
+that has run falls back to the fused XLA driver with
+``cook_kernel_fallback_total{kernel=pallas.megacycle}``.  A kernel that
+compiles there is ROADMAP S3.
 
-VMEM budget per pool program (docs/PERFORMANCE.md kernel registry):
-rows/flags/order/assign-chain ~ 6 x 4B x T, the structured mask
-composition C x H x 1B, host stacks 2 x H x 16B, base gathers T x 20B —
-~13 MB at T=128Ki, C=1Ki, H=8Ki, inside a v5e core's ~16 MB less the
-double-buffered wire blocks.  Oversize shapes must fall back to the
-fused XLA driver (the dispatch wrapper in sched/fused.py does).
+VMEM budget per pool program, on paper (docs/PERFORMANCE.md kernel
+registry): rows/flags/order/assign-chain ~ 6 x 4B x T, the structured
+mask composition C x H x 1B, host stacks 2 x H x 16B, base gathers
+T x 20B — ~13 MB at T=128Ki, C=1Ki, H=8Ki against a v5e core's ~16 MB,
+not counting that 1-D and (N, 4) blocks pad their last dimension to 128
+lanes.
 """
 
 from __future__ import annotations
@@ -164,8 +172,7 @@ def _kernel(rows_ref, flags_ref, res_ref, disk_ref, tokens_ref,
     def _bank_base():
         pool_base = jnp.sum(usage * (valid & ~pending)[:, None],
                             axis=0)[:4]
-        pl.store(base_s, (pl.dslice(p, 1), pl.dslice(0, 4)),
-                 pool_base.reshape(1, 4))
+        base_s[pl.ds(p, 1), :] = pool_base.reshape(1, 4)
         # neutral output writes: phase-1 programs revisit and overwrite
         qrows_ref[0, :] = jnp.zeros((T,), dtype=jnp.int32)
         nq_ref[0, :] = jnp.zeros((1,), dtype=jnp.int32)
@@ -206,8 +213,7 @@ def _kernel(rows_ref, flags_ref, res_ref, disk_ref, tokens_ref,
         bases = base_s[...]                                 # [P, 4]
         gid_all = gid_all_ref[...][:, 0]
         gid = gid_all[p]
-        pool_base = pl.load(base_s, (pl.dslice(p, 1),
-                                     pl.dslice(0, 4)))[0]
+        pool_base = base_s[pl.ds(p, 1), :][0]
         group_base = jnp.sum(
             bases * ((gid_all == gid) & (gid >= 0))[:, None], axis=0)
 

@@ -47,14 +47,45 @@ def _on_compile(kernel: str, n: int) -> None:
         sp.set_tag("recompiled_kernel", kernel)
 
 
+class KernelBuildError(RuntimeError):
+    """A kernel entry point raised on an argument signature it has never
+    completed with in this process: a trace, lowering or compile error
+    (Mosaic refusing a Pallas kernel, an API the installed JAX dropped),
+    or a first-run failure that cannot be told from one.  Unlike a
+    runtime fault on a known-good executable it repeats on every call,
+    so the degrade paths (docs/ROBUSTNESS.md) re-raise it instead of
+    counting it on ``cook_kernel_fallback_total`` forever."""
+
+    def __init__(self, kernel: str, cause: BaseException):
+        super().__init__(f"kernel {kernel} failed on first use "
+                         f"({type(cause).__name__}: {cause})")
+        self.kernel = kernel
+
+
+def _signature(args, kwargs) -> tuple:
+    """(shape, dtype) per argument leaf — what selects a jit executable,
+    cheap enough for a once-per-cycle dispatch.  Leaves without a shape
+    (python scalars, static strings) key on their type only."""
+    import jax
+    return tuple(
+        (getattr(leaf, "shape", None), getattr(leaf, "dtype", type(leaf)))
+        for leaf in jax.tree_util.tree_leaves((args, kwargs)))
+
+
 class InstrumentedJit:
     """Transparent wrapper over a jitted callable that detects cache
-    growth (= a fresh trace+compile) per call.  Attribute access (lower,
-    _cache_size, static argname plumbing) forwards to the wrapped fn."""
+    growth (= a fresh trace+compile) per call, and splits failures into
+    runtime faults (re-raised as they are; callers may degrade) and
+    :class:`KernelBuildError` (first use of an executable; nothing may
+    absorb it).  Attribute access (lower, _cache_size, static argname
+    plumbing) forwards to the wrapped fn."""
 
     def __init__(self, kernel: str, fn):
         self._kernel = kernel
         self._fn = fn
+        # argument signatures this entry point has completed with at
+        # least once: the line between a runtime fault and a build error
+        self._proven: set = set()
         try:
             functools.update_wrapper(self, fn, updated=())
         except Exception:  # jit objects without full wrapper attrs
@@ -62,28 +93,27 @@ class InstrumentedJit:
 
     def __call__(self, *args, **kwargs):
         fn = self._fn
-        before: Optional[int]
+        sig = _signature(args, kwargs)
+        before = fn._cache_size()
         try:
-            before = fn._cache_size()
-        except Exception:
-            before = None
-        out = fn(*args, **kwargs)
+            out = fn(*args, **kwargs)
+        except Exception as exc:
+            if sig in self._proven:
+                raise  # a known-good executable failed: a runtime fault
+            raise KernelBuildError(self._kernel, exc) from exc
+        self._proven.add(sig)
         # every SUCCESSFUL call through an instrumented entry point is
         # one device kernel dispatch: the per-cycle launch count
         # (ISSUE 14) falls out of the wrapper every kernel already
         # passes through.  Counted after the call — a dispatch that
-        # raises (Mosaic lowering gap, injected fault) never launched,
-        # and charging it would double-count against its fallback
+        # raises (injected fault, device loss) never launched, and
+        # charging it would double-count against its fallback
         registry.counter_inc("cook_kernel_launches", 1.0,
                              {"kernel": self._kernel})
         recorder.note_kernel_launch(self._kernel)
-        if before is not None:
-            try:
-                after = fn._cache_size()
-            except Exception:
-                after = before
-            if after > before:
-                _on_compile(self._kernel, after - before)
+        after = fn._cache_size()
+        if after > before:
+            _on_compile(self._kernel, after - before)
         return out
 
     def __getattr__(self, name: str) -> Any:
@@ -134,35 +164,50 @@ def profile_upload(stage_ms: float, inp) -> None:
           f"({nbytes / 1e6:.1f}MB)", file=sys.stderr)
 
 
-def enable_compilation_cache(path: str) -> bool:
-    """Point JAX's persistent compilation cache at ``path`` (created if
-    missing) with no minimum-compile-time floor, so fused-cycle
-    executables survive process restarts: a failover or rolling restart
-    re-traces but never re-compiles.  Returns True when the cache is
-    active; False (never raises) when this jax build lacks the knobs —
-    the scheduler must still boot on such builds, just without the
-    cache."""
-    if not path:
-        return False
-    try:
-        import jax
+#: the compile cache of a checkout that configures none (git-ignored).
+#: A fixed path, never a temp name: the directory is part of JAX's cache
+#: key, so a cache that moves never hits.
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+
+def enable_compilation_cache(configured: str = "") -> Optional[str]:
+    """The ONE place the persistent compilation cache is placed.
+
+    ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself and no
+    directory is set in code (whoever runs the process owns the
+    placement).  Unset: the configured ``compilation_cache_dir``, else
+    :data:`DEFAULT_CACHE_DIR` — the default on a TPU only, because the
+    XLA:CPU executables it would cache are pinned to the build machine's
+    CPU features and tier-1 must not fill a checkout with them.
+    Returns the directory in effect (None = no cache).
+
+    The min-compile-time / min-entry-size floors are dropped either way:
+    compile-once-per-fleet beats the write-amplification guard for a
+    scheduler whose kernel set is small and stable."""
+    import jax
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR") or None
+    if path is None:
+        path = configured or (
+            DEFAULT_CACHE_DIR if jax.default_backend() == "tpu" else None)
+        if path is None:
+            return None
         os.makedirs(path, exist_ok=True)
         jax.config.update("jax_compilation_cache_dir", path)
-        try:
-            # compile-once-per-fleet beats the write-amplification guard
-            # for a scheduler whose kernel set is small and stable
-            jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                              0.0)
-        except Exception:
-            pass  # older knob name / absent: dir alone still caches
-        try:
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes",
-                              -1)
-        except Exception:
-            pass
-    except Exception:
-        return False
-    return True
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    return path
+
+
+def device_info() -> dict:
+    """Where this process's kernels run, as JAX reports it — the
+    ``device`` block of /debug/health and every CycleRecord."""
+    import jax
+    devices = jax.devices()
+    return {"platform": devices[0].platform,
+            "device_kind": devices[0].device_kind,
+            "count": len(devices)}
 
 
 _monitoring_installed = False
@@ -175,10 +220,7 @@ def install_jax_monitoring() -> bool:
     global _monitoring_installed
     if _monitoring_installed:
         return True
-    try:
-        from jax import monitoring
-    except Exception:  # pragma: no cover - jax without monitoring
-        return False
+    from jax import monitoring
     monitoring.register_event_listener(
         lambda event, **kw: registry.counter_inc(
             "cook_jax_event", 1.0, {"event": event}))

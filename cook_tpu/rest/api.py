@@ -2025,6 +2025,10 @@ class CookApi:
             # (and carry its read-view block below) instead of looking
             # like a healthy leader-shaped process
             "role": self._role(),
+            # where the scheduler's kernels run (platform, device_kind,
+            # count as JAX reports them); null on a node that schedules
+            # nothing and so holds no backend
+            "device": getattr(self.scheduler, "device", None),
             # normalized 0-1 saturation signals (sched/fleet.py
             # formulas; docs/OBSERVABILITY.md) — the adaptive-admission
             # input contract, recomputed live for this probe

@@ -613,10 +613,12 @@ class Matcher:
             return reference_impl.greedy_match(job_res, cmask, avail, cap)
         try:
             return self._dispatch_device(mc, job_res, cmask, avail, cap)
+        except telemetry.KernelBuildError:
+            raise  # repeats every cycle: not a fault to absorb
         except Exception:
-            # a kernel dispatch failure (XLA error, device loss, injected
-            # fault) degrades to the host reference path instead of
-            # killing the whole match cycle (docs/ROBUSTNESS.md)
+            # a RUNTIME kernel fault (XLA execution error, device loss,
+            # injected fault) degrades to the host reference path instead
+            # of killing the whole match cycle (docs/ROBUSTNESS.md)
             import logging
             logging.getLogger(__name__).exception(
                 "kernel dispatch failed; falling back to host match")

@@ -26,6 +26,7 @@ from typing import Dict, List, Optional, Tuple
 
 from ..cluster.base import ComputeCluster, LaunchSpec
 from ..config import Config
+from ..ops import telemetry
 from ..state.schema import (
     DruMode,
     InstanceStatus,
@@ -130,23 +131,41 @@ class Scheduler:
         self.reserved_hosts: Dict[str, str] = {}
         self._stop = threading.Event()
         self._threads: List[threading.Thread] = []
+        # a KernelBuildError out of a cycle thread stops every loop and
+        # lands here; ``on_fatal`` lets the owning process (daemon.py)
+        # turn it into a non-zero exit
+        self.fatal_error: Optional[BaseException] = None
+        self.on_fatal = None
         # fused production cycle driver, created lazily on first step_cycle;
         # _pipeline wraps it when config.pipeline.depth > 0 (the pipelined
         # optimistic driver, sched/pipeline.py)
         self._fused = None
         self._pipeline = None
-        # cold-start tail killer (config.PipelineConfig): persistent
-        # compilation cache + boot-time warmup sweep, so first-call
-        # compiles land here — inside the takeover window — and never
-        # inside a live cycle.  Both are opt-in config; the cpu rank
-        # backend has no fused path to warm.
+        # where the kernels run, said once at construction and carried on
+        # /debug/health and every CycleRecord: a process that slid off
+        # the accelerator must be visible without reading a traceback
+        if rank_backend == "cpu":
+            self.device = {"platform": "numpy", "device_kind": "host",
+                           "count": 0}
+        else:
+            self.device = telemetry.device_info()
+            self.device["compilation_cache_dir"] = \
+                telemetry.enable_compilation_cache(
+                    self.config.pipeline.compilation_cache_dir)
+        from ..utils import flight
+        flight.set_device(self.device)
+        import logging
+        logging.getLogger(__name__).info(
+            "scheduler kernels run on platform=%s device_kind=%s count=%d",
+            self.device["platform"], self.device["device_kind"],
+            self.device["count"])
         if rank_backend != "cpu":
-            pl = self.config.pipeline
-            if pl.compilation_cache_dir:
-                from ..ops.telemetry import enable_compilation_cache
-                enable_compilation_cache(pl.compilation_cache_dir)
-            if pl.warmup_tasks and pl.warmup_hosts:
-                self.warmup_kernels()
+            # cold-start tail killer (config.PipelineConfig): with the
+            # persistent compilation cache placed above, the boot-time
+            # warmup sweep lands first-call compiles here — inside the
+            # takeover window — and never inside a live cycle.  The cpu
+            # rank backend has no fused path to warm.
+            self.warmup_kernels()
         # GC discipline for the production cycle: with 100k+ live entities
         # the interpreter's automatic gen2 collections (full scans of a
         # multi-million-object heap) land mid-cycle and double the p99.
@@ -681,20 +700,24 @@ class Scheduler:
         if not (pl.warmup_tasks and pl.warmup_hosts):
             return 0
         self._ensure_fused()
-        try:
-            with tracing.span("fused.warmup", tasks=pl.warmup_tasks,
-                              hosts=pl.warmup_hosts, sweep=pl.warmup_sweep):
-                return self._fused.warmup(
-                    tasks=pl.warmup_tasks, hosts=pl.warmup_hosts,
-                    users=pl.warmup_users, sweep=pl.warmup_sweep,
-                    gpu=pl.warmup_gpu)
-        except Exception:
-            # a warmup failure is a cold start, not an outage: the live
-            # path compiles on first use exactly as before
-            import logging
-            logging.getLogger(__name__).exception(
-                "fused-cycle warmup failed; first cycles compile live")
-            return 0
+        # no except: every warm-up call is an executable's first use, so
+        # a failure here is a build error that would repeat in every live
+        # cycle — the boot fails with it (the daemon's failed-takeover
+        # path exits non-zero) instead of serving on a cold, broken path
+        t0 = time.perf_counter()
+        with tracing.span("fused.warmup", tasks=pl.warmup_tasks,
+                          hosts=pl.warmup_hosts,
+                          sweep=pl.warmup_sweep) as sp:
+            runs = self._fused.warmup(
+                tasks=pl.warmup_tasks, hosts=pl.warmup_hosts,
+                users=pl.warmup_users, sweep=pl.warmup_sweep,
+                gpu=pl.warmup_gpu)
+            sp.set_tag("runs", runs)
+        # on the health device block: the span ring forgets a boot-time
+        # span within seconds of the first cycles
+        self.device["warmup_runs"] = runs
+        self.device["warmup_s"] = round(time.perf_counter() - t0, 3)
+        return runs
 
     def step_cycle(self) -> Dict[str, MatchCycleResult]:
         """PRODUCTION cycle: rank + admission + match for every active
@@ -722,10 +745,14 @@ class Scheduler:
             try:
                 with tracing.span("fused.cycle"):
                     queues, results = driver.step(self)
+            except telemetry.KernelBuildError:
+                # a kernel that never built fails the same way every
+                # cycle: surface it, never degrade around it
+                raise
             except Exception:
-                # device dispatch failed (XLA error, device loss, injected
-                # fault): degrade to the split host path for this cycle
-                # instead of skipping scheduling entirely
+                # a RUNTIME fault (XLA execution error, device loss,
+                # injected fault): degrade to the split host path for
+                # this cycle instead of skipping scheduling entirely
                 import logging
                 logging.getLogger(__name__).exception(
                     "fused cycle failed; degrading to host split path")
@@ -1344,23 +1371,40 @@ class Scheduler:
         """Start background cycle threads (the chime equivalent)."""
         cfg = self.config
 
+        import logging
+        log = logging.getLogger(__name__)
+
+        def tick(fn) -> bool:
+            """One cycle; False = the scheduler cannot go on."""
+            try:
+                fn()
+            except telemetry.KernelBuildError as exc:
+                # not a cycle error to log and retry: the kernel fails
+                # the same way every tick.  Stop cycling and tell the
+                # owner, who exits non-zero (daemon) — a process that
+                # stays up with exit code 0 while its device path is
+                # dead is the failure this refuses to hide
+                log.critical("kernel failed to build; scheduler "
+                             "stopping", exc_info=True)
+                self.fatal_error = exc
+                self._stop.set()
+                if self.on_fatal is not None:
+                    self.on_fatal(exc)
+                return False
+            except Exception:  # pragma: no cover - cycle errors are logged
+                log.exception("cycle failed")
+            return True
+
         def loop(interval, fn, immediate: bool = False) -> None:
             # interval may be a callable so dynamically-tunable cadences
             # (the rebalancer's no-restart interval-seconds) take effect on
             # the next tick instead of being frozen at startup
-            if immediate and not self._stop.is_set():
-                try:
-                    fn()
-                except Exception:  # pragma: no cover - cycle errors are logged
-                    import logging
-                    logging.getLogger(__name__).exception("cycle failed")
+            if immediate and not self._stop.is_set() and not tick(fn):
+                return
             while not self._stop.wait(interval() if callable(interval)
                                       else interval):
-                try:
-                    fn()
-                except Exception:  # pragma: no cover - cycle errors are logged
-                    import logging
-                    logging.getLogger(__name__).exception("cycle failed")
+                if not tick(fn):
+                    return
 
         if cfg.cycle_mode == "fused" and self.ranker.backend != "cpu":
             # production path: one fused rank+match dispatch per cycle,

@@ -391,7 +391,9 @@ class _SchedWorker(_BaseWorker):
             [store],
             max_age_s=float(spec.get("summary_max_age_s", 1.0)),
             peer_fetch=feed, assert_bound=True)
-        return {"pools": my_pools}
+        # announced beside the port: the supervisor (and whoever reads
+        # the addr file) sees which device each shard's kernels run on
+        return {"pools": my_pools, "device": sched.device}
 
     def handle(self, req: Dict[str, Any]) -> Dict[str, Any]:
         cmd = req.get("cmd")
@@ -531,6 +533,81 @@ class ShardProc:
         return int(self.addr["port"])
 
 
+class ShardPlacementError(RuntimeError):
+    """The supervisor cannot give every device-using worker a TPU chip
+    of its own; it refuses to start rather than let one slide to CPU."""
+
+
+def _tpu_chips() -> int:
+    """TPU chips on this host, counted WITHOUT touching JAX (a parent
+    that initialises the backend holds every chip and its workers then
+    fail or hang): the accelerator device nodes the TPU driver exposes —
+    ``/dev/accelN`` (v2-v4) or ``/dev/vfio/N`` (v5e and later)."""
+    import glob
+    import re
+    nodes = glob.glob("/dev/accel[0-9]*") or [
+        n for n in glob.glob("/dev/vfio/*")
+        if re.fullmatch(r"\d+", os.path.basename(n))]
+    return len(nodes)
+
+
+def _holds_jax_backend() -> bool:
+    """Has THIS process initialised a JAX backend (and so claimed the
+    chips)?  Asked without importing jax."""
+    bridge = sys.modules.get("jax._src.xla_bridge")
+    return bridge is not None and bridge.backends_are_initialized()
+
+
+def _worker_chip_env(spec: Dict[str, Any], n_shards: int
+                     ) -> List[Dict[str, str]]:
+    """Per-worker environment that pins each device-using worker to ONE
+    TPU chip (a chip belongs to one process at a time, and an unpinned
+    worker claims them all).  Empty dicts when no pinning applies: a
+    worker role/backend that never initialises JAX, ``JAX_PLATFORMS``
+    naming another platform, or a host without TPU chips.  Refuses — no
+    worker may slide to CPU — when this process already holds the chips
+    or there are fewer chips than shards."""
+    uses_device = spec.get("role", "sched") == "sched" and (
+        spec.get("cfg") or {}).get("rank_backend", "tpu") != "cpu"
+    # JAX_PLATFORMS unset, empty, or listing tpu first ("tpu", "tpu,cpu")
+    # all reach for the chips; anything else never will
+    first = os.environ.get("JAX_PLATFORMS", "").split(",")[0].strip()
+    if not uses_device or first not in ("", "tpu"):
+        return [{} for _ in range(n_shards)]
+    chips = _tpu_chips()
+    if not chips:
+        return [{} for _ in range(n_shards)]
+    if _holds_jax_backend():
+        raise ShardPlacementError(
+            "the supervisor's own process has initialised a JAX backend "
+            f"and holds this host's {chips} TPU chip(s); its shard "
+            "workers could not get one.  Start the supervisor from a "
+            "process that has not touched JAX")
+    if n_shards > chips:
+        raise ShardPlacementError(
+            f"{n_shards} shard workers need {n_shards} TPU chips, this "
+            f"host has {chips}: one chip belongs to one process, and a "
+            "worker without one would schedule on the CPU unnoticed")
+    return [{
+        # one chip, a one-process topology of its own, and its own mesh
+        # controller port (Cloud TPU's recipe for several single-chip
+        # processes on one host); JAX_PLATFORMS makes losing the chip a
+        # start-up failure instead of a CPU fallback (the chip machine
+        # ships JAX_PLATFORMS=tpu,cpu); ALLOW_MULTIPLE_LIBTPU_LOAD lets
+        # the workers' libtpu loads coexist.  This exact set ran two
+        # concurrent single-chip workers on a v5e 2x2 host; unpinned,
+        # the second worker aborts on libtpu's lockfile (CHANGES.md
+        # PR 21)
+        "JAX_PLATFORMS": "tpu",
+        "TPU_VISIBLE_DEVICES": str(i),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+        "TPU_MESH_CONTROLLER_ADDRESS": f"localhost:{8476 + i}",
+        "TPU_MESH_CONTROLLER_PORT": str(8476 + i),
+        "ALLOW_MULTIPLE_LIBTPU_LOAD": "1",
+    } for i in range(n_shards)]
+
+
 class ShardSupervisor:
     """Spawn and drive N shard worker processes.
 
@@ -561,6 +638,7 @@ class ShardSupervisor:
         env = dict(os.environ)
         env["PYTHONPATH"] = pkg_parent + (
             os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
+        chip_env = _worker_chip_env(self.base_spec, self.n_shards)
         for i in range(self.n_shards):
             spec = dict(self.base_spec, shard=i, n_shards=self.n_shards,
                         addr_file=addr_files[i], peers=addr_files)
@@ -571,14 +649,20 @@ class ShardSupervisor:
             log = open(os.path.join(self.root, f"shard-{i}.log"), "wb")
             proc = subprocess.Popen(
                 [sys.executable, "-m", "cook_tpu.sched.shard", spec_file],
-                stdout=log, stderr=subprocess.STDOUT, env=env,
-                cwd=pkg_parent)
+                stdout=log, stderr=subprocess.STDOUT,
+                env={**env, **chip_env[i]}, cwd=pkg_parent)
             log.close()
             self.procs.append(ShardProc(i, proc, addr_files[i], spec_file))
         deadline = time.monotonic() + boot_timeout_s
-        for sp in self.procs:
-            remaining = max(0.5, deadline - time.monotonic())
-            sp.addr = read_addr_file(sp.addr_file, remaining)
+        try:
+            for sp in self.procs:
+                remaining = max(0.5, deadline - time.monotonic())
+                sp.addr = read_addr_file(sp.addr_file, remaining)
+        except BaseException:
+            # a worker that did boot must not outlive a failed start: on
+            # a TPU host it would go on holding its chip
+            self.stop()
+            raise
         return self
 
     # ----------------------------------------------------------------- rpc

@@ -74,6 +74,20 @@ def current_shard() -> Optional[int]:
     return _shard_id
 
 
+# process-wide device identity (platform / device_kind / count as JAX
+# reports them; sched/scheduler.py sets it at construction): a process
+# holds one backend, so like the shard id it is process state stamped
+# onto every CycleRecord — a cycle that ran off the accelerator says so
+_device: Optional[Dict[str, Any]] = None
+
+
+def set_device(device: Dict[str, Any]) -> None:
+    """Declare where this process's kernels run; every CycleRecord
+    minted after carries the block."""
+    global _device
+    _device = {k: device[k] for k in ("platform", "device_kind", "count")}
+
+
 class CycleRecord:
     """One scheduler cycle's instrument-panel readings."""
 
@@ -83,7 +97,8 @@ class CycleRecord:
                  "h2d_bytes", "d2h_bytes", "sync_wait_ms", "faults",
                  "error", "pipeline_depth", "pipeline_inflight",
                  "pipeline_conflicts", "delta_rows", "full_repacks",
-                 "audit_events", "kernel_launches", "path", "shard", "_t0")
+                 "audit_events", "kernel_launches", "path", "shard",
+                 "device", "_t0")
 
     def __init__(self, seq: int, kind: str):
         self.seq = seq
@@ -92,6 +107,7 @@ class CycleRecord:
         # controllers; None on the classic single process) — the key the
         # stitched /debug/cycles roll-up and fleet trace group by
         self.shard: Optional[int] = _shard_id
+        self.device = _device
         self.trace_id: Optional[str] = None
         self.start_s = time.time()
         self.duration_ms = 0.0
@@ -167,6 +183,7 @@ class CycleRecord:
             "kernel_launches": self.kernel_launches,
             "path": self.path,
             "shard": self.shard,
+            "device": self.device,
             "error": self.error,
         }
 

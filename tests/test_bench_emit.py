@@ -7,8 +7,9 @@ head.  The contract now is: every emission prints the full payload line
 followed by a compact (≤1 KB) summary line, so the last retained line is
 always complete JSON regardless of where the tail window cuts.
 
-Reference for the scoreboard the driver fills: BENCH_r0{1..4}.json at the
-repo root (all ``parsed=null``).
+What the line may NOT carry (ISSUE 21): a number this run did not
+measure.  A zero-section run publishes ``value: null``, and a run that
+finds no TPU without an explicit ``BENCH_FORCE_CPU=1`` exits non-zero.
 """
 
 import json
@@ -31,7 +32,6 @@ def _fat_payload():
                           "fused_cycle", "store_cycle", "match_large",
                           "rebalance", "end2end", "pallas_scale",
                           "pipeline", "placement_quality"],
-        "value_source": "live",
     }
     for i in range(500):
         detail[f"section_metric_{i}"] = {"p50_ms": 123.456, "p99_ms": 789.0,
@@ -60,9 +60,9 @@ def test_compact_payload_is_under_1kb_and_carries_headline():
     assert parsed["sections_done"]  # list of names or a count, never absent
 
 
-def test_compact_payload_survives_corrupt_capture_value():
-    """A corrupt prior capture can leak an arbitrary structure into
-    ``value``; the compact line must still come out ≤1 KB and parseable."""
+def test_compact_payload_survives_corrupt_value():
+    """An arbitrary structure leaking into ``value`` must still come out
+    ≤1 KB and parseable."""
     p = _fat_payload()
     p["value"] = {"oops": ["x" * 100] * 50}  # ~5 KB structure
     out = bench.compact_payload(p)
@@ -82,7 +82,7 @@ def test_compact_payload_minimal_payload():
 def test_build_payload_records_sections_done():
     payload = bench.build_payload(
         {"rank": None, "sync_floor": {"sync_floor_ms": 1.0}},
-        {"sync_floor": "cpu"}, {"rank": "boom"}, None, 0.0)
+        {"sync_floor": "cpu"}, {"rank": "boom"}, 0.0)
     assert payload["detail"]["sections_done"] == ["sync_floor"]
 
 
@@ -105,10 +105,26 @@ def test_driver_bounded_tail_parses_last_line():
     for key in ("metric", "value", "unit", "vs_baseline", "platform",
                 "scale", "sections_done"):
         assert key in parsed, f"missing {key}: {last}"
-    # the repo carries a committed on-chip capture, so even a zero-section
-    # run must stand on a real number, never null
-    assert parsed["value"] is not None
+    # a zero-section run measured nothing and says so: no committed
+    # number is ever published in its place
+    assert parsed["value"] is None
     # second-to-last line is the full payload, also valid JSON
     full = json.loads(p.stdout.strip().splitlines()[-2])
     assert full["metric"] == parsed["metric"]
     assert "detail" in full
+
+
+def test_no_chip_and_no_forced_cpu_is_an_error():
+    """No TPU and no explicit BENCH_FORCE_CPU=1: non-zero exit, and the
+    last line still parses with a null value and the reason."""
+    env = dict(os.environ)
+    env.pop("BENCH_FORCE_CPU", None)
+    env.update({"BENCH_SECTIONS": "none", "JAX_PLATFORMS": "cpu",
+                "BENCH_PROBE_ATTEMPTS": "1"})
+    p = subprocess.run(
+        [sys.executable, os.path.join(REPO, "bench.py")],
+        capture_output=True, text=True, timeout=300, env=env, cwd=REPO)
+    assert p.returncode != 0
+    parsed = json.loads(p.stdout.strip().splitlines()[-1])
+    assert parsed["value"] is None
+    assert "no TPU" in parsed["error"]

@@ -115,7 +115,18 @@ def churn(store, wave, n=4, seed=11):
     return fresh
 
 
-def drive(cfg, cycles=4, split=False, **kw):
+def drive(cfg, cycles=4, split=False, expect_mega=None, **kw):
+    """Drive a world; when the megakernel is the pinned backend, the
+    fallback must not be what produced the decisions: every cycle's
+    path is ``megakernel`` and the kernel's fallback counter stands
+    still (a dead kernel behind a working fallback passed this matrix
+    once — ISSUE 21)."""
+    if expect_mega is None:
+        expect_mega = (not split and cfg.default_matcher.backend
+                       == "tpu-megakernel")
+    fallback = {"kernel": "pallas.megacycle"}
+    n0 = counter_value("cook_kernel_fallback", fallback)
+    seq0 = flight_recorder.last_seq()
     store, sched, jobs = build_world(cfg, **kw)
     for w in range(cycles):
         if split:
@@ -129,6 +140,11 @@ def drive(cfg, cycles=4, split=False, **kw):
         sched.step_match()
     else:
         sched.step_cycle()
+    if expect_mega:
+        paths = [r["path"] for r in flight_recorder.recent(cycles + 8)
+                 if r["seq"] > seq0 and r["kind"] == "fused"]
+        assert paths and set(paths) == {"megakernel"}, paths
+        assert counter_value("cook_kernel_fallback", fallback) == n0
     return decisions(store, jobs)
 
 
@@ -580,22 +596,47 @@ class TestTelemetryAndFallback:
         rec = flight_recorder.recent(3)[-1]
         assert rec["path"] == "fused"
 
-    def test_dispatch_failure_degrades_to_fused_xla(self, monkeypatch):
+    def test_runtime_fault_degrades_to_fused_xla(self, monkeypatch):
+        """A RUNTIME fault at dispatch (not a build error) still takes
+        the counted fallback — the robustness contract."""
         from cook_tpu.ops import pallas_cycle as pc
         base = drive(make_cfg(backend="auto"), cycles=1)
         n0 = counter_value("cook_kernel_fallback",
                                     {"kernel": "pallas.megacycle"})
 
         def boom(*a, **kw):
-            raise RuntimeError("mosaic lowering exploded")
+            raise RuntimeError("device lost mid-dispatch")
         monkeypatch.setattr(pc, "megacycle", boom)
-        got = drive(make_cfg(), cycles=1)
+        got = drive(make_cfg(), cycles=1, expect_mega=False)
         assert got == base
         assert counter_value(
             "cook_kernel_fallback",
             {"kernel": "pallas.megacycle"}) > n0
         rec = flight_recorder.recent(3)[-1]
         assert rec["path"] == "fused"
+
+    def test_trace_error_on_explicit_pin_raises(self, monkeypatch):
+        """A kernel that cannot trace or lower fails the same way every
+        cycle: the explicit pin raises instead of re-tracing behind
+        cook_kernel_fallback_total forever (what pl.store's removal did
+        under JAX 0.9.0 until ISSUE 21)."""
+        from cook_tpu.ops import pallas_cycle as pc
+        from cook_tpu.ops.telemetry import KernelBuildError
+
+        def dead_kernel(*refs, **static):
+            raise AttributeError(
+                "module 'jax.experimental.pallas' has no attribute 'store'")
+        monkeypatch.setattr(pc, "_kernel", dead_kernel)
+        monkeypatch.setattr(pc, "_FNS", {})  # no executable from before
+        n0 = counter_value("cook_kernel_fallback",
+                           {"kernel": "pallas.megacycle"})
+        store, sched, jobs = build_world(make_cfg())
+        with pytest.raises(KernelBuildError, match="pallas.megacycle"):
+            sched.step_cycle()
+        assert counter_value("cook_kernel_fallback",
+                             {"kernel": "pallas.megacycle"}) == n0
+        assert all(store.job(j.uuid).state.value == "waiting"
+                   for j in jobs)
 
 
 # ---------------------------------------------------------------------------
