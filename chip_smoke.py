@@ -23,10 +23,15 @@ from 200 users made from ``--seed``.
 
 A chip belongs to one process at a time, so this parent never imports
 JAX: every phase that needs the chip is a child started after the
-previous one has exited.  Exit code 0 and a last stdout line
-``{"ok": true, "device": {...}, ...}`` only when every check held ON A
-TPU.  No accelerator (``JAX_PLATFORMS=cpu``) or no program beside this
-file: a message on stderr, a non-zero exit, no result line.
+previous one has exited.  Standard output ends with two lines: the
+full report (``{"report": {...}}``: versions, path, cycles, placements,
+fallback and recompile counts, warm-up walls, parity, native libraries,
+every check), then the result, exactly
+``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``
+with the device as JAX reports it.  Exit code 0 and ``"ok": true`` only
+when every check held ON A TPU.  No accelerator (``JAX_PLATFORMS=cpu``)
+or no program beside this file: a message on stderr, a non-zero exit, no
+result line.
 
 ``--allow-cpu`` and the size flags exist to debug this script in a
 sandbox without a chip; a run that uses any of them ends with
@@ -662,6 +667,14 @@ def drive_daemon(args, probe: dict, workdir: str) -> dict:
 
 
 # -------------------------------------------------------------------- main
+def result_line(ok: bool, probe: dict) -> str:
+    """The last line of standard output: these keys and no others, the
+    device as JAX reported it to the probe child."""
+    return json.dumps({"ok": bool(ok), "device": {
+        "platform": str(probe["platform"]), "kind": str(probe["kind"]),
+        "count": int(probe["count"])}})
+
+
 def run(args) -> int:
     if not os.path.isdir(os.path.join(HERE, "cook_tpu")):
         log(f"no cook_tpu package beside {os.path.abspath(__file__)}: "
@@ -685,9 +698,6 @@ def run(args) -> int:
         return 3
 
     result: dict = {
-        "ok": False,
-        "device": {"platform": probe["platform"], "kind": probe["kind"],
-                   "count": probe["count"]},
         "platform": probe["platform"], "device_kind": probe["kind"],
         "n_devices": probe["count"], "versions": probe["versions"],
         "seed": args.seed, "jobs": args.jobs, "hosts": args.hosts,
@@ -755,12 +765,13 @@ def run(args) -> int:
     if debug:
         result["debug_run"] = ("--allow-cpu or a size flag was used: "
                                "never a pass")
-    result["ok"] = not failures and not debug \
-        and probe["platform"] == "tpu"
+    ok = not failures and not debug and probe["platform"] == "tpu"
     for f in failures:
         log(f"FAILED: {f}")
-    print(json.dumps(result), flush=True)
-    return 0 if result["ok"] else 1
+    print(json.dumps({"report": result}), flush=True)
+    # the last line is the result and nothing else: the report is above
+    print(result_line(ok, probe), flush=True)
+    return 0 if ok else 1
 
 
 def main(argv=None) -> int:

@@ -41,6 +41,23 @@ class TestChipSmokeRefuses:
         assert "no cook_tpu package" in p.stderr
 
 
+class TestChipSmokeResultLine:
+    def test_last_line_has_exactly_the_contract_keys(self):
+        import importlib.util
+        import json
+        spec = importlib.util.spec_from_file_location(
+            "chip_smoke", os.path.join(REPO, "chip_smoke.py"))
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        probe = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1,
+                 "versions": {"jax": "0.9.0"}}
+        line = mod.result_line(True, probe)
+        assert "\n" not in line
+        assert json.loads(line) == {"ok": True, "device": {
+            "platform": "tpu", "kind": "TPU v5 lite", "count": 1}}
+        assert json.loads(mod.result_line(False, probe))["ok"] is False
+
+
 class TestCompilationCachePlacement:
     """env set -> nothing set in code; unset -> config, else .jax_cache
     (on a TPU only)."""
