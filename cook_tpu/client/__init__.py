@@ -667,6 +667,13 @@ class JobClient:
         pipeline depth, repack counters, audit queue depth."""
         return self._request("GET", "/debug/health")
 
+    def debug_profile(self, seconds: float = 5.0) -> Dict:
+        """POST /debug/profile — admin: start a jax.profiler trace of
+        the daemon for ``seconds``; returns its directory (409 while a
+        session is active)."""
+        return self._request("POST", "/debug/profile",
+                             body={"seconds": seconds})
+
     def job_timeline(self, uuid: str) -> Dict:
         """GET /debug/job/<uuid>/timeline — the job's full scheduling
         audit trail plus, while it waits, the unscheduled explainer's

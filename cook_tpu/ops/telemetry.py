@@ -28,7 +28,6 @@ from __future__ import annotations
 
 import functools
 import os
-import sys
 import time
 from contextlib import contextmanager
 from typing import Any, Optional
@@ -146,22 +145,6 @@ def sync_wait(kind: str = "fetch"):
         dt = time.perf_counter() - t0
         registry.observe("cook_sync_wait_seconds", dt, {"kind": kind})
         recorder.note_sync_wait(dt)
-
-
-def profile_upload(stage_ms: float, inp) -> None:
-    """COOK_PROFILE_UPLOAD=1 debug probe for the dispatch path: block
-    until the staged inputs land on device and print stage/upload times.
-    Lives here so the hot loop in sched/fused.py carries one call, not a
-    conditional-import block."""
-    if not os.environ.get("COOK_PROFILE_UPLOAD"):
-        return
-    import jax
-    t0 = time.perf_counter()
-    jax.block_until_ready(list(inp))
-    nbytes = sum(getattr(a, "nbytes", 0) for a in inp)
-    print(f"[profile] stage={stage_ms}ms upload="
-          f"{(time.perf_counter() - t0) * 1e3:.0f}ms "
-          f"({nbytes / 1e6:.1f}MB)", file=sys.stderr)
 
 
 #: the compile cache of a checkout that configures none (git-ignored).

@@ -36,6 +36,7 @@ import base64
 import copy
 import hmac
 import json
+import os
 import re
 import socket
 import threading
@@ -142,6 +143,9 @@ API_ROUTES = [
      "this cell's bounded per-user summary table + host inventory for "
      "a federation front door's global fair-share merge and goodput "
      "routing (never job state)", False),
+    ("POST", "/debug/profile",
+     "start a jax.profiler trace of this process for {seconds: n} "
+     "(admin); the program's spans land in it as annotations", False),
     ("GET", "/metrics", "Prometheus metrics", False),
     ("GET", "/metrics/fleet",
      "merged fleet exposition: every member's /metrics re-labeled "
@@ -2000,6 +2004,62 @@ class CookApi:
             raise ApiError(400, "limit must be an integer")
         return self.request_obs.snapshot(limit=limit)
 
+    #: at most this long a profiler session per POST /debug/profile
+    PROFILE_MAX_SECONDS = 120.0
+    _profile_lock = threading.Lock()
+    _profile_dir: Optional[str] = None   # the session in flight, if any
+
+    def debug_profile(self, body: Dict, user: str) -> Dict:
+        """POST /debug/profile {"seconds": n} — admin only.  Starts a
+        ``jax.profiler`` trace of THIS process into
+        ``<data_dir>/profiles/<stamp>`` and stops it n seconds later;
+        answers at once with the directory.  Scheduler threads' spans
+        enter profiler annotations (utils/tracing.py), so the xplane's
+        host lines carry ``fused.pack``, ``cycle.launch``,
+        ``journal.append`` ... beside the device ops.  One session per
+        process: 409 while one is active."""
+        self.require_admin(user)
+        try:
+            seconds = float((body or {}).get("seconds", 5))
+        except (TypeError, ValueError):
+            raise ApiError(400, "seconds must be a number")
+        if not 0 < seconds <= self.PROFILE_MAX_SECONDS:
+            raise ApiError(400, "seconds must be in (0, "
+                                f"{self.PROFILE_MAX_SECONDS:g}]")
+        import tempfile
+        base = getattr(self.store, "_journal_dir", None) \
+            or tempfile.gettempdir()
+        path = os.path.join(base, "profiles", time.strftime(
+            "%Y%m%dT%H%M%S", time.gmtime()) + f"-{os.getpid()}")
+        cls = type(self)
+        with cls._profile_lock:
+            if cls._profile_dir is not None:
+                raise ApiError(409, "a profiler session is active: "
+                                    f"{cls._profile_dir}")
+            try:
+                import jax
+                os.makedirs(path, exist_ok=True)
+                jax.profiler.start_trace(path)
+            except Exception as e:
+                # a session somebody else started (a harness) included
+                raise ApiError(409, f"the profiler did not start: {e}")
+            cls._profile_dir = path
+
+        def stop() -> None:
+            import jax
+            try:
+                jax.profiler.stop_trace()
+            except Exception:  # stopped by somebody else meanwhile
+                pass
+            finally:
+                with cls._profile_lock:
+                    cls._profile_dir = None
+
+        timer = threading.Timer(seconds, stop)
+        timer.daemon = True
+        timer.start()
+        return {"directory": path, "seconds": seconds}
+
     def debug_health(self) -> Dict:
         """GET /debug/health — the one-shot operator roll-up `cs debug
         health` renders: every "is this cell healthy" signal that
@@ -2029,6 +2089,10 @@ class CookApi:
             # count as JAX reports them); null on a node that schedules
             # nothing and so holds no backend
             "device": getattr(self.scheduler, "device", None),
+            # when Scheduler.run() started the loop threads: the 30 s
+            # sweeps fall at multiples of their interval after it
+            "scheduler": {"started_s": getattr(self.scheduler,
+                                               "started_s", None)},
             # normalized 0-1 saturation signals (sched/fleet.py
             # formulas; docs/OBSERVABILITY.md) — the adaptive-admission
             # input contract, recomputed live for this probe
@@ -2950,7 +3014,7 @@ class _Handler(BaseHTTPRequestHandler):
                     "/debug/federation/summary",
                     "/debug/faults", "/debug/replication",
                     "/debug/requests", "/debug/health", "/debug/storage",
-                    "/metrics",
+                    "/debug/profile", "/metrics",
                     "/metrics/fleet",
                     "/failure_reasons", "/settings", "/swagger-docs",
                     "/swagger-ui"}
@@ -3179,6 +3243,8 @@ class _Handler(BaseHTTPRequestHandler):
                 return api.progress(parts[1], self._body())
             if path == "/shutdown-leader":
                 return api.shutdown_leader(self._user())
+            if path == "/debug/profile":
+                return api.debug_profile(self._body(), self._user())
         elif method == "PUT":
             if path == "/retry":
                 return api.retry(self._body(), self._user(),

@@ -596,35 +596,57 @@ class FusedCycleDriver:
         This closes the 'fused cycle packs from entities' gap tracked in
         docs/PARITY.md; decision parity with the entity pack is asserted by
         tests/test_fused_cycle.py."""
-        store, cfg = self.store, self.config
-        idx = store.ensure_index()
-        # ONE snapshot of the reservations: the rebalancer thread mutates
-        # reserved_hosts concurrently, and every later read in this pack
-        # (owner rows, host blocks, local owners) must see the same set
-        resv = dict(scheduler.reserved_hosts)
-        # tx-event delta feed (state/index.py attach_pack_consumer): one
-        # drain per pack.  A quiet pool — zero journaled rows, no fence —
-        # reuses its cached [T]-sized pack products wholesale instead of
-        # rebuilding them (the incremental-view-maintenance fast path;
-        # ineligible shapes fall through to the full rebuild below)
-        if self._delta_cid is None:
-            self._delta_cid = idx.attach_pack_consumer()
-        delta = idx.pack_delta(self._delta_cid, pool.name)
-        cached = self._pack_cache.get(pool.name)
-        if (cached is not None and not delta.fence
-                and delta.rows.size == 0
-                and delta.epoch == cached["epoch"]
-                and delta.version == cached["version"]
-                and not self.plugins.launch_filters
-                and not self._resv_owner_in_pack(idx, resv, cached)):
-            return self._pack_pool_cached(scheduler, pool, cached, resv,
-                                          exclude=exclude,
-                                          token_delta=token_delta)
-        self._pack_cache.pop(pool.name, None)
-        snap = idx.fused_arrays(pool.name, owner_uuids=list(resv),
-                                compact=True)
+        # the pack's three parts carry spans of their own (pack.index,
+        # pack.offers, pack.rows -> detail_ms.pack_*): which of the index
+        # refresh, the offer staging and the row selection arrivals push
+        # off the fast path reads off /debug/cycles
+        store = self.store
+        with tracing.span("pack.index", pool=pool.name):
+            idx = store.ensure_index()
+            # ONE snapshot of the reservations: the rebalancer thread
+            # mutates reserved_hosts concurrently, and every later read
+            # in this pack (owner rows, host blocks, local owners) must
+            # see the same set
+            resv = dict(scheduler.reserved_hosts)
+            # tx-event delta feed (state/index.py attach_pack_consumer):
+            # one drain per pack.  A quiet pool — zero journaled rows, no
+            # fence — reuses its cached [T]-sized pack products wholesale
+            # instead of rebuilding them (the incremental-view-
+            # maintenance fast path; ineligible shapes fall through to
+            # the full rebuild below)
+            if self._delta_cid is None:
+                self._delta_cid = idx.attach_pack_consumer()
+            delta = idx.pack_delta(self._delta_cid, pool.name)
+            cached = self._pack_cache.get(pool.name)
+            quiet = (cached is not None and not delta.fence
+                     and delta.rows.size == 0
+                     and delta.epoch == cached["epoch"]
+                     and delta.version == cached["version"]
+                     and not self.plugins.launch_filters
+                     and not self._resv_owner_in_pack(idx, resv, cached))
+            snap = None
+            if not quiet:
+                self._pack_cache.pop(pool.name, None)
+                snap = idx.fused_arrays(pool.name, owner_uuids=list(resv),
+                                        compact=True)
+        if quiet:
+            with tracing.span("pack.rows", pool=pool.name, cached=True):
+                return self._pack_pool_cached(scheduler, pool, cached, resv,
+                                              exclude=exclude,
+                                              token_delta=token_delta)
         if snap is None:
             return None
+        with tracing.span("pack.rows", pool=pool.name):
+            return self._pack_rows_columnar(scheduler, pool, snap, delta,
+                                            resv, exclude=exclude,
+                                            token_delta=token_delta)
+
+    def _pack_rows_columnar(self, scheduler, pool: Pool, snap, delta,
+                            resv: Dict, exclude=None,
+                            token_delta=None) -> _PackedPool:
+        """The full (non-quiet) columnar pack over one index snapshot:
+        exception rows, admission flags, caps."""
+        store, cfg = self.store, self.config
         arrays, rows_s = snap.arrays, snap.rows_s
         uuid_base, complex_rows, owner_rows = \
             snap.uuid_base, snap.complex_s, snap.owner_rows
@@ -849,6 +871,11 @@ class FusedCycleDriver:
         path's reservation/exception handling (None when no offers; the
         empty-offer fallback shapes are set here so the two paths can
         never diverge)."""
+        with tracing.span("pack.offers", pool=pool.name):
+            return self._stage_offers(pp, scheduler, pool)
+
+    def _stage_offers(self, pp: _PackedPool, scheduler, pool: Pool
+                      ) -> Optional[Dict[str, int]]:
         cfg = self.config
         offers: List[Offer] = []
         for cluster in scheduler.launchable_clusters(pool.name):
@@ -1323,15 +1350,20 @@ class FusedCycleDriver:
         from ..utils.faults import injector as _faults
         _faults.fire("fused.dispatch")
 
-        pools = [p for p in self.store.pools()
-                 if p.state == "active" and p.scheduler is not SchedulerKind.DIRECT]
+        # the pool table is the cycle's first read of the store: a cycle
+        # that meets a sweep waits for the store lock HERE, so the read
+        # has a span (and a detail_ms key, "pools") of its own — outside
+        # fused.pack, which times what it always timed
+        with tracing.span("fused.pools"):
+            pools = [p for p in self.store.pools()
+                     if p.state == "active"
+                     and p.scheduler is not SchedulerKind.DIRECT]
         packed: List[_PackedPool] = []
         excl = exclude or {}
         tokd = token_delta or {}
         # "cycle.rank" is the canonical rank-phase span on the cycle trace
         # (flight.PHASE_BY_SPAN): host-side rank staging — the columnar
         # pack that feeds the device the rank+match problem
-        pack_t0 = time.perf_counter()
         with tracing.span("cycle.rank"), tracing.span("fused.pack"):
             for pool in pools:
                 pp = self._pack_pool(scheduler, pool,
@@ -1363,14 +1395,14 @@ class FusedCycleDriver:
                             continue
                     refreshed.append(pp)
                 packed = refreshed
-        _flight.note_phase_detail(
-            "pack", (time.perf_counter() - pack_t0) * 1000.0)
         if avail_delta:
-            for pp in packed:
-                for h, o in enumerate(pp.offers):
-                    d = avail_delta.get((o.cluster, o.hostname))
-                    if d is not None:
-                        pp.avail[h] = np.maximum(pp.avail[h] - d, 0.0)
+            # the pipelined driver's own host work (detail_ms.pipeline)
+            with tracing.span("pipeline.host", step="avail-delta"):
+                for pp in packed:
+                    for h, o in enumerate(pp.offers):
+                        d = avail_delta.get((o.cluster, o.hostname))
+                        if d is not None:
+                            pp.avail[h] = np.maximum(pp.avail[h] - d, 0.0)
         staged = _StagedCycle(pools)
         if not packed:
             return staged
@@ -1445,148 +1477,150 @@ class FusedCycleDriver:
         )
         arr = lambda k, fill: stack(lambda pp: padT(pp.arrays[k], fill))
         structured = group[0].columnar
-        stage_t0 = time.perf_counter()
-        avail_p = np.zeros((P, H, 4), dtype=F32)
-        cap_p = np.zeros((P, H, 4), dtype=F32)
-        for i, pp in enumerate(group):
-            avail_p[i, :pp.avail.shape[0]] = pp.avail
-            cap_p[i, :pp.capacity.shape[0]] = pp.capacity
-        scalars = dict(
-            num_considerable=jnp.asarray(np.array(
-                [pp.num_considerable for pp in group]
-                + [0] * (P - len(group)), dtype=np.int32)),
-            pool_quota=jnp.asarray(np.stack(
-                [pp.pool_quota for pp in group]
-                + [np.full(4, INF, dtype=F32)] * (P - len(group)))),
-            group_quota=jnp.asarray(np.stack(
-                [pp.group_quota for pp in group]
-                + [np.full(4, INF, dtype=F32)] * (P - len(group)))),
-            group_id=jnp.asarray(np.array(
-                [pp.group_id for pp in group]
-                + [-1] * (P - len(group)), dtype=np.int32)))
-        if structured:
-            # COMPACT wire form: the per-task upload is the sorted row
-            # permutation + one flags byte (~5 B/task); resource
-            # columns live in the device-resident base mirror and
-            # everything else is derived on device (expand_compact).
-            # every pp in the group shares one compaction epoch (step
-            # re-packs or drops stale pools right after the pack loop),
-            # so the mirror's row indices are valid for all of them —
-            # assert rather than silently uploading mixed-epoch content
-            # under one mirror key
-            epoch = max(pp.base_compactions for pp in group)
-            assert all(pp.base_compactions == epoch for pp in group), \
-                [pp.base_compactions for pp in group]
-            base_pp = max(group, key=lambda pp: pp.res_base.shape[0])
-            mir_res, mir_disk = self._sync_base_mirror(
-                base_pp.res_base, base_pp.disk_base, epoch)
-            E = bucket(max(max(len(pp.exc_rows), pp.exc_mask.shape[0])
-                           for pp in group), minimum=8)
-            U = bucket(max(pp.shares_u.shape[0] for pp in group),
-                       minimum=8)
-            rows_p = np.zeros((P, T), dtype=np.int32)
-            flags_p = np.zeros((P, T), dtype=np.uint8)
-            exc_rows_p = np.full((P, E), -1, dtype=np.int32)
-            exc_mask_p = np.zeros((P, E, H), dtype=bool)
-            host_gpu_p = np.zeros((P, H), dtype=bool)
-            # padding hosts stay blocked so zero-resource jobs can
-            # never land on them (the dense path's zero rows did this)
-            host_blocked_p = np.ones((P, H), dtype=bool)
-            shares_u_p = np.full((P, U, 3), INF, dtype=F32)
-            quota_u_p = np.full((P, U, 4), INF, dtype=F32)
-            tokens_u_p = np.full((P, U), INF, dtype=F32)
+        # detail_ms.stage: arrays -> padded, stacked wire form (the upload
+        # of what is not device-resident included)
+        with tracing.span("fused.stage", pools=len(group)) as stage_sp:
+            avail_p = np.zeros((P, H, 4), dtype=F32)
+            cap_p = np.zeros((P, H, 4), dtype=F32)
             for i, pp in enumerate(group):
-                rows_p[i, :pp.n_tasks] = pp.rows_s
-                flags_p[i, :pp.n_tasks] = pp.flags
-                exc_rows_p[i, :len(pp.exc_rows)] = pp.exc_rows
-                e, h = pp.exc_mask.shape
-                exc_mask_p[i, :e, :h] = pp.exc_mask
-                host_gpu_p[i, :pp.host_gpu.shape[0]] = pp.host_gpu
-                host_blocked_p[i, :pp.host_blocked.shape[0]] = \
-                    pp.host_blocked
-                shares_u_p[i, :pp.shares_u.shape[0]] = pp.shares_u
-                quota_u_p[i, :pp.quota_u.shape[0]] = pp.quota_u
-                tokens_u_p[i, :pp.tokens_u.shape[0]] = pp.tokens_u
-            mega = None
-            use_mega = self._megakernel_selected(group)
-            if self.config.resident_pack:
-                # DEVICE-RESIDENT wire arrays: steady state ships only
-                # the scatter delta, not the [P, T] world (ISSUE 7)
-                key = (tuple(pp.pool.name for pp in group), P, T)
-                rows_dev, flags_dev = self._sync_resident(
-                    gpu_mode, key, rows_p, flags_p, epoch)
-                resident = True
-            elif use_mega and self.config.quantized_wire:
-                # the quantized wire carries rows/flags narrow; no wide
-                # upload happens at all on this path
-                rows_dev = flags_dev = None
-                resident = False
-            else:  # rebuild mode: dispatch_group accounts the upload
-                rows_dev = jnp.asarray(rows_p)
-                flags_dev = jnp.asarray(flags_p)
-                resident = False
-            if use_mega:
-                inp = None
-                mega = self._stage_mega(
-                    group, rows_p=rows_p, flags_p=flags_p,
-                    rows_dev=rows_dev, flags_dev=flags_dev,
-                    mir_res=mir_res, mir_disk=mir_disk,
-                    tokens_u_p=tokens_u_p, shares_u_p=shares_u_p,
-                    quota_u_p=quota_u_p, scalars=scalars,
-                    host_gpu_p=host_gpu_p, host_blocked_p=host_blocked_p,
-                    exc_rows_p=exc_rows_p, exc_mask_p=exc_mask_p,
-                    avail_p=avail_p, cap_p=cap_p, T=T, H=H, P=P,
-                    resident=resident)
+                avail_p[i, :pp.avail.shape[0]] = pp.avail
+                cap_p[i, :pp.capacity.shape[0]] = pp.capacity
+            scalars = dict(
+                num_considerable=jnp.asarray(np.array(
+                    [pp.num_considerable for pp in group]
+                    + [0] * (P - len(group)), dtype=np.int32)),
+                pool_quota=jnp.asarray(np.stack(
+                    [pp.pool_quota for pp in group]
+                    + [np.full(4, INF, dtype=F32)] * (P - len(group)))),
+                group_quota=jnp.asarray(np.stack(
+                    [pp.group_quota for pp in group]
+                    + [np.full(4, INF, dtype=F32)] * (P - len(group)))),
+                group_id=jnp.asarray(np.array(
+                    [pp.group_id for pp in group]
+                    + [-1] * (P - len(group)), dtype=np.int32)))
+            if structured:
+                # COMPACT wire form: the per-task upload is the sorted row
+                # permutation + one flags byte (~5 B/task); resource
+                # columns live in the device-resident base mirror and
+                # everything else is derived on device (expand_compact).
+                # every pp in the group shares one compaction epoch (step
+                # re-packs or drops stale pools right after the pack loop),
+                # so the mirror's row indices are valid for all of them —
+                # assert rather than silently uploading mixed-epoch content
+                # under one mirror key
+                epoch = max(pp.base_compactions for pp in group)
+                assert all(pp.base_compactions == epoch for pp in group), \
+                    [pp.base_compactions for pp in group]
+                base_pp = max(group, key=lambda pp: pp.res_base.shape[0])
+                mir_res, mir_disk = self._sync_base_mirror(
+                    base_pp.res_base, base_pp.disk_base, epoch)
+                E = bucket(max(max(len(pp.exc_rows), pp.exc_mask.shape[0])
+                               for pp in group), minimum=8)
+                U = bucket(max(pp.shares_u.shape[0] for pp in group),
+                           minimum=8)
+                rows_p = np.zeros((P, T), dtype=np.int32)
+                flags_p = np.zeros((P, T), dtype=np.uint8)
+                exc_rows_p = np.full((P, E), -1, dtype=np.int32)
+                exc_mask_p = np.zeros((P, E, H), dtype=bool)
+                host_gpu_p = np.zeros((P, H), dtype=bool)
+                # padding hosts stay blocked so zero-resource jobs can
+                # never land on them (the dense path's zero rows did this)
+                host_blocked_p = np.ones((P, H), dtype=bool)
+                shares_u_p = np.full((P, U, 3), INF, dtype=F32)
+                quota_u_p = np.full((P, U, 4), INF, dtype=F32)
+                tokens_u_p = np.full((P, U), INF, dtype=F32)
+                for i, pp in enumerate(group):
+                    rows_p[i, :pp.n_tasks] = pp.rows_s
+                    flags_p[i, :pp.n_tasks] = pp.flags
+                    exc_rows_p[i, :len(pp.exc_rows)] = pp.exc_rows
+                    e, h = pp.exc_mask.shape
+                    exc_mask_p[i, :e, :h] = pp.exc_mask
+                    host_gpu_p[i, :pp.host_gpu.shape[0]] = pp.host_gpu
+                    host_blocked_p[i, :pp.host_blocked.shape[0]] = \
+                        pp.host_blocked
+                    shares_u_p[i, :pp.shares_u.shape[0]] = pp.shares_u
+                    quota_u_p[i, :pp.quota_u.shape[0]] = pp.quota_u
+                    tokens_u_p[i, :pp.tokens_u.shape[0]] = pp.tokens_u
+                mega = None
+                use_mega = self._megakernel_selected(group)
+                if self.config.resident_pack:
+                    # DEVICE-RESIDENT wire arrays: steady state ships only
+                    # the scatter delta, not the [P, T] world (ISSUE 7)
+                    key = (tuple(pp.pool.name for pp in group), P, T)
+                    rows_dev, flags_dev = self._sync_resident(
+                        gpu_mode, key, rows_p, flags_p, epoch)
+                    resident = True
+                elif use_mega and self.config.quantized_wire:
+                    # the quantized wire carries rows/flags narrow; no wide
+                    # upload happens at all on this path
+                    rows_dev = flags_dev = None
+                    resident = False
+                else:  # rebuild mode: dispatch_group accounts the upload
+                    rows_dev = jnp.asarray(rows_p)
+                    flags_dev = jnp.asarray(flags_p)
+                    resident = False
+                if use_mega:
+                    inp = None
+                    mega = self._stage_mega(
+                        group, rows_p=rows_p, flags_p=flags_p,
+                        rows_dev=rows_dev, flags_dev=flags_dev,
+                        mir_res=mir_res, mir_disk=mir_disk,
+                        tokens_u_p=tokens_u_p, shares_u_p=shares_u_p,
+                        quota_u_p=quota_u_p, scalars=scalars,
+                        host_gpu_p=host_gpu_p, host_blocked_p=host_blocked_p,
+                        exc_rows_p=exc_rows_p, exc_mask_p=exc_mask_p,
+                        avail_p=avail_p, cap_p=cap_p, T=T, H=H, P=P,
+                        resident=resident)
+                else:
+                    inp = CompactPoolCycleInputs(
+                        rows=rows_dev,
+                        flags=flags_dev,
+                        res_base=mir_res,
+                        disk_base=mir_disk,
+                        tokens_u=jnp.asarray(tokens_u_p),
+                        shares_u=jnp.asarray(shares_u_p),
+                        quota_u=jnp.asarray(quota_u_p),
+                        **scalars,
+                        host_gpu=jnp.asarray(host_gpu_p),
+                        host_blocked=jnp.asarray(host_blocked_p),
+                        exc_rows=jnp.asarray(exc_rows_p),
+                        exc_mask=jnp.asarray(exc_mask_p),
+                        avail=jnp.asarray(avail_p),
+                        capacity=jnp.asarray(cap_p))
             else:
-                inp = CompactPoolCycleInputs(
-                    rows=rows_dev,
-                    flags=flags_dev,
-                    res_base=mir_res,
-                    disk_base=mir_disk,
-                    tokens_u=jnp.asarray(tokens_u_p),
-                    shares_u=jnp.asarray(shares_u_p),
-                    quota_u=jnp.asarray(quota_u_p),
+                mega = None
+                cmask_p = np.zeros((P, T, H), dtype=bool)
+                for i, pp in enumerate(group):
+                    cmask_p[i, :pp.n_tasks, :pp.cmask.shape[1]] = pp.cmask
+                inp = PoolCycleInputs(
+                    usage=jnp.asarray(arr("usage", 0)),
+                    quota=jnp.asarray(arr("quota", INF)),
+                    shares=jnp.asarray(arr("shares", INF)),
+                    first_idx=jnp.asarray(arr("first_idx", 0)),
+                    user_rank=jnp.asarray(arr("user_rank", 2**31 - 1)),
+                    pending=jnp.asarray(arr("pending", False)),
+                    valid=jnp.asarray(arr("valid", False)),
+                    enqueue_ok=jnp.asarray(
+                        stack(lambda pp: padT(pp.enqueue_ok, False))),
+                    launch_ok=jnp.asarray(
+                        stack(lambda pp: padT(pp.launch_ok, False))),
+                    tokens=jnp.asarray(
+                        stack(lambda pp: padT(pp.tokens, 0.0))),
                     **scalars,
-                    host_gpu=jnp.asarray(host_gpu_p),
-                    host_blocked=jnp.asarray(host_blocked_p),
-                    exc_rows=jnp.asarray(exc_rows_p),
-                    exc_mask=jnp.asarray(exc_mask_p),
+                    job_res=jnp.asarray(
+                        stack(lambda pp: padT(pp.job_res, 0.0))),
+                    cmask=jnp.asarray(cmask_p),
                     avail=jnp.asarray(avail_p),
                     capacity=jnp.asarray(cap_p))
-        else:
-            mega = None
-            cmask_p = np.zeros((P, T, H), dtype=bool)
-            for i, pp in enumerate(group):
-                cmask_p[i, :pp.n_tasks, :pp.cmask.shape[1]] = pp.cmask
-            inp = PoolCycleInputs(
-                usage=jnp.asarray(arr("usage", 0)),
-                quota=jnp.asarray(arr("quota", INF)),
-                shares=jnp.asarray(arr("shares", INF)),
-                first_idx=jnp.asarray(arr("first_idx", 0)),
-                user_rank=jnp.asarray(arr("user_rank", 2**31 - 1)),
-                pending=jnp.asarray(arr("pending", False)),
-                valid=jnp.asarray(arr("valid", False)),
-                enqueue_ok=jnp.asarray(
-                    stack(lambda pp: padT(pp.enqueue_ok, False))),
-                launch_ok=jnp.asarray(
-                    stack(lambda pp: padT(pp.launch_ok, False))),
-                tokens=jnp.asarray(
-                    stack(lambda pp: padT(pp.tokens, 0.0))),
-                **scalars,
-                job_res=jnp.asarray(
-                    stack(lambda pp: padT(pp.job_res, 0.0))),
-                cmask=jnp.asarray(cmask_p),
-                avail=jnp.asarray(avail_p),
-                capacity=jnp.asarray(cap_p))
 
-        # static match-problem cap: the configured max_jobs_considered
-        # (>= every pool's dynamic num_considerable), bucketed so the
-        # compiled cycle is reused across config tweaks
-        cap = bucket(max(
-            self.config.matcher_for_pool(pp.pool.name).max_jobs_considered
-            for pp in group))
-        stage_ms = round((time.perf_counter() - stage_t0) * 1000.0, 1)
-        _flight.note_phase_detail("stage", stage_ms)
+            # static match-problem cap: the configured max_jobs_considered
+            # (>= every pool's dynamic num_considerable), bucketed so the
+            # compiled cycle is reused across config tweaks
+            cap = bucket(max(
+                self.config.matcher_for_pool(pp.pool.name).max_jobs_considered
+                for pp in group))
+        stage_ms = (None if stage_sp.duration_s is None
+                    else round(stage_sp.duration_s * 1000.0, 1))
         return _StagedGroup(gpu_mode=gpu_mode, group=group, inp=inp,
                             structured=structured, cap=cap, T=T, H=H,
                             stage_ms=stage_ms,
@@ -1788,7 +1822,6 @@ class FusedCycleDriver:
         from ..ops import pallas_cycle
         from ..utils.metrics import registry
         m = sg.mega
-        telemetry.profile_upload(sg.stage_ms, m["wire"])
         telemetry.count_transfer("h2d", m["h2d_bytes"])
         try:
             with tracing.span("fused.dispatch", pools=len(sg.group),
@@ -1837,7 +1870,6 @@ class FusedCycleDriver:
         point)."""
         if sg.mega is not None:
             return self._dispatch_mega(sg)
-        telemetry.profile_upload(sg.stage_ms, sg.inp)
         # staged wire bytes this dispatch: the device-resident base
         # mirror fields are never re-uploaded per cycle (the mirror sync
         # accounts its own transfers), and in resident-pack mode the
@@ -1861,20 +1893,22 @@ class FusedCycleDriver:
             res = self._cycle_fn(sg.gpu_mode, min(sg.cap, sg.T),
                                  sg.structured,
                                  compact=sg.structured)(sg.inp)
+            # fetch ONLY the compact outputs: [C]-sized candidate
+            # triples + the queue count.  The full [T] arrays
+            # (order/queue_ok/assign) and the rank-ordered queue_rows
+            # stay device-resident; the published RankedQueue fetches
+            # queue_rows lazily when a consumer actually touches the
+            # queue: the old four-[T]-array fetch was 2.1 MB per cycle
+            # at T=131k; this fetches ~50 KB.  (Inside the span: each
+            # kick lets go of the GIL, and getting it back can take
+            # milliseconds while a sweep runs.)
+            outs = (res.cand_row, res.cand_assign, res.cand_qpos,
+                    res.n_queue)
+            for out_arr in outs:
+                copy_async = getattr(out_arr, "copy_to_host_async", None)
+                if copy_async is not None:
+                    copy_async()
         _flight.note_path("fused")
-        # fetch ONLY the compact outputs: [C]-sized candidate
-        # triples + the queue count.  The full [T] arrays
-        # (order/queue_ok/assign) and the rank-ordered queue_rows
-        # stay device-resident; the published RankedQueue fetches
-        # queue_rows lazily when a consumer actually touches the
-        # queue: the old four-[T]-array fetch was 2.1 MB per cycle
-        # at T=131k; this fetches ~50 KB.
-        outs = (res.cand_row, res.cand_assign, res.cand_qpos,
-                res.n_queue)
-        for out_arr in outs:
-            copy_async = getattr(out_arr, "copy_to_host_async", None)
-            if copy_async is not None:
-                copy_async()
         return _GroupDispatch(sg, res, outs)
 
     def fetch_group(self, gd: "_GroupDispatch"):
@@ -1900,7 +1934,6 @@ class FusedCycleDriver:
         # megakernel dispatches also fetched the fused gang stage's
         # verdicts (post-reduction assignment + drop mask per slot)
         gang_fetched = gd.fetched[4:6] if len(gd.fetched) >= 6 else None
-        apply_t0 = time.perf_counter()
         with tracing.span("cycle.launch", pools=len(gd.sg.group)):
             for i, pp in enumerate(gd.sg.group):
                 gang_pre = (None if gang_fetched is None else
@@ -1910,8 +1943,6 @@ class FusedCycleDriver:
                                  int(n_queue[i]), gd.res.queue_rows, i,
                                  queues, results, reconciler=reconciler,
                                  gang_pre=gang_pre)
-        _flight.note_phase_detail(
-            "apply", (time.perf_counter() - apply_t0) * 1000.0)
 
     def step(self, scheduler) -> Tuple[Dict[str, List[Job]],
                                        Dict[str, MatchCycleResult]]:
@@ -1920,6 +1951,8 @@ class FusedCycleDriver:
         pre-pipeline behavior (pipeline_depth=0 routes here).  Returns
         (pending queues, match results); direct pools are handled by the
         scheduler separately."""
+        staged_tx = getattr(self.store, "_tx_id", -1)
+        staged_at = time.perf_counter()
         staged = self.stage(scheduler)
         queues: Dict[str, List[Job]] = {p.name: [] for p in staged.pools}
         results: Dict[str, MatchCycleResult] = {}
@@ -1928,6 +1961,8 @@ class FusedCycleDriver:
                               tasks=sg.T, hosts=sg.H, gpu=sg.gpu_mode):
                 gd = self.dispatch_group(sg)
                 self.fetch_group(gd)
+            _flight.note_staged(
+                staged_tx, (time.perf_counter() - staged_at) * 1000.0)
             self.apply_group(scheduler, gd, queues, results)
         return queues, results
 
@@ -1958,7 +1993,8 @@ class FusedCycleDriver:
         # slice this pool's row off the [P, T] output eagerly (an async
         # device op): the published queue's closure must NOT keep the whole
         # P-wide buffer — or the rest of pp — alive for its lifetime
-        dev_rows = queue_rows_dev[pool_slot]
+        with tracing.span("apply.audit", step="queue-slice"):
+            dev_rows = queue_rows_dev[pool_slot]
         rows_s = pp.rows_s
         fetched_rows: List[Optional[np.ndarray]] = [None]
 
@@ -2001,42 +2037,44 @@ class FusedCycleDriver:
                     pp.id2job[pp.task_ids[r]]
                     for r in local_rows_with_drops(drop_qpos)]
 
-        scheduler._stifle_offensive(pp.offensive)
-
-        result = MatchCycleResult()
-        slots = np.flatnonzero(cand_row >= 0)
-        result.considered = len(slots)
-        # fused gang verdicts (megakernel dispatch): usable only while
-        # the candidate view the kernel reduced over stays INTACT — any
-        # vanished job, reconcile drop, clip, or group-placement reset
-        # below invalidates them and the host reduction recomputes
-        # (identical math, ops/gang.py; parity-asserted).  The pool
-        # must also have STAGED its gang wire: an auto pool riding a
-        # pinned group on CPU dispatches mega without one, and its
-        # all -1 gang ids would read as "nothing dropped"
-        gang_ok = gang_pre is not None and pp.gang_wire is not None
-        if pp.columnar:
-            uuid_prefix = pp.uuid_base[pp.rows_s[cand_row[slots]]]
-            fetched = self.store.jobs_bulk([str(u) for u in uuid_prefix])
-            cand_jobs, cand_keep = [], []
-            for s, job in zip(slots, fetched):
-                if job is not None:
-                    cand_jobs.append(job)
-                    cand_keep.append(s)
-            if len(cand_keep) != len(slots):
-                gang_ok = False
-            slots = np.array(cand_keep, dtype=np.int64)
-        else:
-            cand_jobs = [pp.id2job[pp.task_ids[r]] for r in cand_row[slots]]
-        # per-job rank attribution for the fetched candidate slots
-        # (bounded by the considerable cap, never [T]-sized): the
-        # device-computed queue position, straight off the compact
-        # outputs already on host (utils/audit.py)
-        if len(slots):
-            self.store.audit.ranked(
-                [j.uuid for j in cand_jobs],
-                [int(q) for q in cand_qpos[slots]], pool_name,
-                users=[j.user for j in cand_jobs])
+        with tracing.span("apply.lookup", step="fetch"):
+            result = MatchCycleResult()
+            slots = np.flatnonzero(cand_row >= 0)
+            result.considered = len(slots)
+            # fused gang verdicts (megakernel dispatch): usable only while
+            # the candidate view the kernel reduced over stays INTACT — any
+            # vanished job, reconcile drop, clip, or group-placement reset
+            # below invalidates them and the host reduction recomputes
+            # (identical math, ops/gang.py; parity-asserted).  The pool
+            # must also have STAGED its gang wire: an auto pool riding a
+            # pinned group on CPU dispatches mega without one, and its
+            # all -1 gang ids would read as "nothing dropped"
+            gang_ok = gang_pre is not None and pp.gang_wire is not None
+            if pp.columnar:
+                uuid_prefix = pp.uuid_base[pp.rows_s[cand_row[slots]]]
+                fetched = self.store.jobs_bulk([str(u) for u in uuid_prefix])
+                cand_jobs, cand_keep = [], []
+                for s, job in zip(slots, fetched):
+                    if job is not None:
+                        cand_jobs.append(job)
+                        cand_keep.append(s)
+                if len(cand_keep) != len(slots):
+                    gang_ok = False
+                slots = np.array(cand_keep, dtype=np.int64)
+            else:
+                cand_jobs = [pp.id2job[pp.task_ids[r]]
+                             for r in cand_row[slots]]
+        with tracing.span("apply.audit"):
+            scheduler._stifle_offensive(pp.offensive)
+            # per-job rank attribution for the fetched candidate slots
+            # (bounded by the considerable cap, never [T]-sized): the
+            # device-computed queue position, straight off the compact
+            # outputs already on host (utils/audit.py)
+            if len(slots):
+                self.store.audit.ranked(
+                    [j.uuid for j in cand_jobs],
+                    [int(q) for q in cand_qpos[slots]], pool_name,
+                    users=[j.user for j in cand_jobs])
         if len(slots) == 0 or not pp.offers:
             # mirror Matcher.match_pool: an empty cycle returns the
             # considerable set unmatched and leaves backoff untouched
@@ -2045,152 +2083,156 @@ class FusedCycleDriver:
             results[pool_name] = result
             return
 
-        cand_host = cand_assign[slots].astype(np.int64)
-        # clip padding-host assignments (can't happen: padding hosts have
-        # zero capacity and all-False masks, but stay defensive)
-        clipped = cand_host >= len(pp.offers)
-        if clipped.any():
-            cand_host[clipped] = -1
-            gang_ok = False
-        conflict_qpos = None
-        res_conflict = None
-        dropped_head_matched = False
-        if reconciler is not None:
-            with tracing.span("fused.reconcile", pool=pool_name,
-                              candidates=len(slots)):
-                state_drop, res_drop = reconciler(pp, cand_jobs, cand_host)
-            # a dropped HEAD that held an assignment DID match (it
-            # launched one cycle earlier, or the overlap consumed its
-            # host): backoff must not shrink for a transient conflict
-            dropped_head_matched = bool(
-                (state_drop[0] or res_drop[0]) and cand_host[0] >= 0) \
-                if len(slots) else False
-            if state_drop.any() or res_drop.any():
+        with tracing.span("apply.lookup", step="validate"):
+            cand_host = cand_assign[slots].astype(np.int64)
+            # clip padding-host assignments (can't happen: padding hosts have
+            # zero capacity and all-False masks, but stay defensive)
+            clipped = cand_host >= len(pp.offers)
+            if clipped.any():
+                cand_host[clipped] = -1
                 gang_ok = False
-            if res_drop.any():
-                cand_host[res_drop] = -1
-            if state_drop.any():
-                qp = cand_qpos[slots[state_drop]]
-                conflict_qpos = qp[qp >= 0]
-                keep = ~state_drop
-                slots = slots[keep]
-                cand_jobs = [j for j, k in zip(cand_jobs, keep) if k]
-                cand_host = cand_host[keep]
-                res_drop = res_drop[keep]
-            res_conflict = res_drop if res_drop.any() else None
-            if len(slots) == 0:
-                # every candidate conflicted away: like the empty cycle,
-                # leave backoff untouched (the head DID match — it just
-                # launched one cycle earlier than this stale snapshot saw)
-                publish_queue(conflict_qpos)
-                result.queue_pruned = conflict_qpos is not None \
-                    and len(conflict_qpos) > 0
-                results[pool_name] = result
-                return
-        pre_validate = cand_host.copy()
-        cand_host = validate_group_placement(
-            cand_jobs, cand_host, pp.offers, pp.ctx)
-        if gang_ok and (cand_host != pre_validate).any():
-            # a within-batch placement rule reset an assignment after
-            # the kernel's gang stage saw it: the fused verdict is stale
-            gang_ok = False
-        # gang all-or-nothing over the fetched candidates (ops/gang.py,
-        # docs/GANG.md): partial gangs reset to unmatched with their
-        # capacity refilled to group-less candidates in the SAME cycle.
-        # Under the pipelined driver a reconcile-dropped member already
-        # left its gang incomplete, so a conflicted gang drops atomically
-        # here.  Structural no-op when no candidate is a gang member.
-        groups_ctx = pp.ctx.groups if pp.ctx is not None else {}
-        if any(j.group is not None
-               and getattr(groups_ctx.get(j.group), "gang", False)
-               for j in cand_jobs):
-            from ..ops.gang import apply_gang_cycle
-            from .elastic import satisfied_gangs
-            H = len(pp.offers)
-            cand_res = np.array(
-                [[j.resources.cpus, j.resources.mem, j.resources.gpus,
-                  j.resources.disk] for j in cand_jobs], dtype=F32)
-            satisfied = satisfied_gangs(self.store, groups_ctx)
-            if gang_ok:
-                # the fused gang stage's membership is pack-time state:
-                # a satisfied-set flip since staging (member failure,
-                # grace shrink landing mid-cycle) changes who the
-                # reduction even counts — recompute on host then
-                wire_gangs = (frozenset(pp.gang_wire.uuids)
-                              if pp.gang_wire is not None else frozenset())
-                now_satisfied = frozenset(
-                    u for u in (satisfied or ())
-                    if u in wire_gangs or u in pp.gang_satisfied)
-                if now_satisfied != pp.gang_satisfied:
+            conflict_qpos = None
+            res_conflict = None
+            dropped_head_matched = False
+            if reconciler is not None:
+                with tracing.span("fused.reconcile", pool=pool_name,
+                                  candidates=len(slots)):
+                    state_drop, res_drop = reconciler(pp, cand_jobs, cand_host)
+                # a dropped HEAD that held an assignment DID match (it
+                # launched one cycle earlier, or the overlap consumed its
+                # host): backoff must not shrink for a transient conflict
+                dropped_head_matched = bool(
+                    (state_drop[0] or res_drop[0]) and cand_host[0] >= 0) \
+                    if len(slots) else False
+                if state_drop.any() or res_drop.any():
                     gang_ok = False
-            precomputed = None
-            if gang_ok:
-                precomputed = (np.asarray(gang_pre[0])[slots],
-                               np.asarray(gang_pre[1])[slots].astype(bool))
-            cand_host, gstats = apply_gang_cycle(
-                cand_jobs, cand_host, pp.offers, groups_ctx,
-                job_res=cand_res,
-                cmask_fn=lambda: build_constraint_mask(
-                    cand_jobs, pp.offers, pp.ctx),
-                # reconcile-adjusted availability when an overlapped
-                # cycle overdrafted the staged snapshot: the rescue and
-                # refill passes must not re-place onto a host the
-                # reconciler just protected
-                avail=(pp.avail_headroom if pp.avail_headroom is not None
-                       else pp.avail[:H]),
-                capacity=pp.capacity[:H],
-                device=False,
-                refill_ok=(~res_conflict if res_conflict is not None
-                           else None),
-                audit_trail=self.store.audit, audit_pool=pool_name,
-                satisfied=satisfied,
-                precomputed=precomputed)
-            if gstats is not None:
-                result.gang_partial = gstats.partial
-        if res_conflict is not None:
-            # resource-conflicted candidates are a pipeline transient,
-            # not a placement failure: keep them out of the unscheduled
-            # explainer's persisted per-host summaries
-            rp_keep = ~res_conflict
-            self.matcher.record_placement_failures(
-                [j for j, k in zip(cand_jobs, rp_keep) if k],
-                cand_host[rp_keep], pp.offers, pp.ctx)
-        else:
-            self.matcher.record_placement_failures(
+                if res_drop.any():
+                    cand_host[res_drop] = -1
+                if state_drop.any():
+                    qp = cand_qpos[slots[state_drop]]
+                    conflict_qpos = qp[qp >= 0]
+                    keep = ~state_drop
+                    slots = slots[keep]
+                    cand_jobs = [j for j, k in zip(cand_jobs, keep) if k]
+                    cand_host = cand_host[keep]
+                    res_drop = res_drop[keep]
+                res_conflict = res_drop if res_drop.any() else None
+                if len(slots) == 0:
+                    # every candidate conflicted away: like the empty cycle,
+                    # leave backoff untouched (the head DID match — it just
+                    # launched one cycle earlier than this stale snapshot saw)
+                    publish_queue(conflict_qpos)
+                    result.queue_pruned = conflict_qpos is not None \
+                        and len(conflict_qpos) > 0
+                    results[pool_name] = result
+                    return
+            pre_validate = cand_host.copy()
+            cand_host = validate_group_placement(
                 cand_jobs, cand_host, pp.offers, pp.ctx)
-
-        result.head_matched = bool(cand_host[0] >= 0) or dropped_head_matched
-        mc = self.config.matcher_for_pool(pool_name)
-        self.matcher._backoff[pool_name].update(mc, result.head_matched)
-
-        for j, job in enumerate(cand_jobs):
-            h = int(cand_host[j])
-            if h < 0:
-                result.unmatched.append(job)
+            if gang_ok and (cand_host != pre_validate).any():
+                # a within-batch placement rule reset an assignment after
+                # the kernel's gang stage saw it: the fused verdict is stale
+                gang_ok = False
+            # gang all-or-nothing over the fetched candidates (ops/gang.py,
+            # docs/GANG.md): partial gangs reset to unmatched with their
+            # capacity refilled to group-less candidates in the SAME cycle.
+            # Under the pipelined driver a reconcile-dropped member already
+            # left its gang incomplete, so a conflicted gang drops atomically
+            # here.  Structural no-op when no candidate is a gang member.
+            groups_ctx = pp.ctx.groups if pp.ctx is not None else {}
+            if any(j.group is not None
+                   and getattr(groups_ctx.get(j.group), "gang", False)
+                   for j in cand_jobs):
+                from ..ops.gang import apply_gang_cycle
+                from .elastic import satisfied_gangs
+                H = len(pp.offers)
+                cand_res = np.array(
+                    [[j.resources.cpus, j.resources.mem, j.resources.gpus,
+                      j.resources.disk] for j in cand_jobs], dtype=F32)
+                satisfied = satisfied_gangs(self.store, groups_ctx)
+                if gang_ok:
+                    # the fused gang stage's membership is pack-time state:
+                    # a satisfied-set flip since staging (member failure,
+                    # grace shrink landing mid-cycle) changes who the
+                    # reduction even counts — recompute on host then
+                    wire_gangs = (frozenset(pp.gang_wire.uuids)
+                                  if pp.gang_wire is not None else frozenset())
+                    now_satisfied = frozenset(
+                        u for u in (satisfied or ())
+                        if u in wire_gangs or u in pp.gang_satisfied)
+                    if now_satisfied != pp.gang_satisfied:
+                        gang_ok = False
+                precomputed = None
+                if gang_ok:
+                    precomputed = (np.asarray(gang_pre[0])[slots],
+                                   np.asarray(gang_pre[1])[slots].astype(bool))
+                cand_host, gstats = apply_gang_cycle(
+                    cand_jobs, cand_host, pp.offers, groups_ctx,
+                    job_res=cand_res,
+                    cmask_fn=lambda: build_constraint_mask(
+                        cand_jobs, pp.offers, pp.ctx),
+                    # reconcile-adjusted availability when an overlapped
+                    # cycle overdrafted the staged snapshot: the rescue and
+                    # refill passes must not re-place onto a host the
+                    # reconciler just protected
+                    avail=(pp.avail_headroom if pp.avail_headroom is not None
+                           else pp.avail[:H]),
+                    capacity=pp.capacity[:H],
+                    device=False,
+                    refill_ok=(~res_conflict if res_conflict is not None
+                               else None),
+                    audit_trail=self.store.audit, audit_pool=pool_name,
+                    satisfied=satisfied,
+                    precomputed=precomputed)
+                if gstats is not None:
+                    result.gang_partial = gstats.partial
+        with tracing.span("apply.audit"):
+            if res_conflict is not None:
+                # resource-conflicted candidates are a pipeline transient,
+                # not a placement failure: keep them out of the unscheduled
+                # explainer's persisted per-host summaries
+                rp_keep = ~res_conflict
+                self.matcher.record_placement_failures(
+                    [j for j, k in zip(cand_jobs, rp_keep) if k],
+                    cand_host[rp_keep], pp.offers, pp.ctx)
             else:
-                result.matched.append((job, pp.offers[h]))
+                self.matcher.record_placement_failures(
+                    cand_jobs, cand_host, pp.offers, pp.ctx)
+
+            result.head_matched = (bool(cand_host[0] >= 0)
+                                   or dropped_head_matched)
+            mc = self.config.matcher_for_pool(pool_name)
+            self.matcher._backoff[pool_name].update(mc, result.head_matched)
+
+            for j, job in enumerate(cand_jobs):
+                h = int(cand_host[j])
+                if h < 0:
+                    result.unmatched.append(job)
+                else:
+                    result.matched.append((job, pp.offers[h]))
         with tracing.span("fused.launch", pool=pool_name,
                           matched=len(result.matched)):
             self.matcher._launch(pool_name, result, scheduler.clusters)
-        # drop this cycle's launches — and any reconcile-conflicted
-        # candidates — from the queue by exact position (launched
-        # candidates are always queue members — match_valid implies
-        # queue_ok, so cand_qpos is valid for every launched slot)
-        drops = ([conflict_qpos] if conflict_qpos is not None
-                 and len(conflict_qpos) else [])
-        if result.launched_job_uuids:
-            cand_uuids = np.array([j.uuid for j in cand_jobs])
-            launched_c = np.isin(cand_uuids,
-                                 np.array(result.launched_job_uuids))
-            drops.append(cand_qpos[slots[launched_c]])
-        if drops:
-            publish_queue(np.concatenate(drops))
-            result.queue_pruned = True
-        else:
-            publish_queue()
-        _audit.note_skips(self.store.audit, {
-            "unmatched": [j.uuid for j in result.unmatched],
-            "launch-failed": [(u, {"why": why})
-                              for u, why in result.launch_failures],
-        }, pool=pool_name)
-        results[pool_name] = result
+        with tracing.span("apply.audit"):
+            # drop this cycle's launches — and any reconcile-conflicted
+            # candidates — from the queue by exact position (launched
+            # candidates are always queue members — match_valid implies
+            # queue_ok, so cand_qpos is valid for every launched slot)
+            drops = ([conflict_qpos] if conflict_qpos is not None
+                     and len(conflict_qpos) else [])
+            if result.launched_job_uuids:
+                cand_uuids = np.array([j.uuid for j in cand_jobs])
+                launched_c = np.isin(cand_uuids,
+                                     np.array(result.launched_job_uuids))
+                drops.append(cand_qpos[slots[launched_c]])
+            if drops:
+                publish_queue(np.concatenate(drops))
+                result.queue_pruned = True
+            else:
+                publish_queue()
+            _audit.note_skips(self.store.audit, {
+                "unmatched": [j.uuid for j in result.unmatched],
+                "launch-failed": [(u, {"why": why})
+                                  for u, why in result.launch_failures],
+            }, pool=pool_name)
+            results[pool_name] = result

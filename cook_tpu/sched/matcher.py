@@ -717,11 +717,28 @@ class Matcher:
         """Transactional guard then cluster launch (reference:
         launch-matched-tasks! scheduler.clj:1028: the store transaction
         failing MUST block the backend launch)."""
-        from ..policy import pool_user_key
+        # the three host stages around the guard transaction carry spans
+        # of their own (flight.DETAIL_BY_SPAN: launch.prepare is
+        # apply_lookup, launch.specs and the dispatch are apply_cluster)
+        with tracing.span("launch.prepare", pool=pool_name):
+            entries, by_task, gangs = self._launch_entries(result)
+        # ONE guard transaction for the whole cycle's launches (reference:
+        # launch-matched-tasks! transacts all task txns at once,
+        # scheduler.clj:810-1009); per-job guard failures are reported and
+        # those jobs never reach a backend
+        insts, failures = self.store.launch_instances(entries)
+        result.launch_failures.extend(failures)
+        with tracing.span("launch.specs", pool=pool_name):
+            by_cluster = self._launch_specs(pool_name, result, insts,
+                                            by_task, gangs)
+        self._launch_dispatch(pool_name, by_cluster, clusters)
+
+    def _launch_entries(self, result: MatchCycleResult):
+        """The guard transaction's entries for ``result.matched`` (rate
+        limited per cluster, unit by unit), task id -> (job, offer), and
+        the gang groups of the matched jobs."""
         cluster_rl = self.rate_limits.cluster_launch
-        launch_rl = self.rate_limits.job_launch
         cluster_budget: Dict[str, float] = {}
-        by_cluster: Dict[str, List[LaunchSpec]] = {}
         entries: List[Dict] = []
         by_task: Dict[str, Tuple[Job, Offer]] = {}
         # gang cohorts launch atomically: every member clears the
@@ -785,12 +802,18 @@ class Matcher:
                         LOCATION_ATTRIBUTE, ""),
                     **({"gang": guuid} if guuid else {})))
                 by_task[task_id] = (job, offer)
-        # ONE guard transaction for the whole cycle's launches (reference:
-        # launch-matched-tasks! transacts all task txns at once,
-        # scheduler.clj:810-1009); per-job guard failures are reported and
-        # those jobs never reach a backend
-        insts, failures = self.store.launch_instances(entries)
-        result.launch_failures.extend(failures)
+        return entries, by_task, gangs
+
+    def _launch_specs(self, pool_name: str, result: MatchCycleResult,
+                      insts, by_task: Dict[str, Tuple[Job, Offer]],
+                      gangs: Dict) -> Dict[str, List[LaunchSpec]]:
+        """Per-cluster LaunchSpecs of the instances the guard admitted,
+        with their bookkeeping (queue-latency histogram, rate-limit
+        spends, launched ids on ``result``)."""
+        from ..policy import pool_user_key
+        cluster_rl = self.rate_limits.cluster_launch
+        launch_rl = self.rate_limits.job_launch
+        by_cluster: Dict[str, List[LaunchSpec]] = {}
         for inst in insts:
             job, offer = by_task[inst.task_id]
             # launch-time wait histogram: the queue-latency SLO's
@@ -841,9 +864,14 @@ class Matcher:
                 container=job.container))
             result.launched_task_ids.append(inst.task_id)
             result.launched_job_uuids.append(job.uuid)
-        # per-cluster launches fan out in parallel (reference: future per
-        # cluster, scheduler.clj:1034-1048) — one slow backend must not
-        # serialize the others
+        return by_cluster
+
+    def _launch_dispatch(self, pool_name: str,
+                         by_cluster: Dict[str, List[LaunchSpec]],
+                         clusters: Dict[str, ComputeCluster]) -> None:
+        """Per-cluster launches fan out in parallel (reference: future per
+        cluster, scheduler.clj:1034-1048) — one slow backend must not
+        serialize the others."""
         def launch_on(cluster, specs):
             from ..utils.retry import breakers
             cluster.kill_lock.acquire_read()
@@ -861,7 +889,10 @@ class Matcher:
                 cluster.kill_lock.release_read()
             # dispatch acked by the backend: confirm the launch intents
             # (tasks whose status already arrived were cleared in-line)
-            self.store.clear_launch_intents([s.task_id for s in specs])
+            with tracing.span("store.clear-intents", pool=pool_name,
+                              cluster=cluster.name):
+                self.store.clear_launch_intents(
+                    [s.task_id for s in specs])
 
         targets = [(clusters[name], specs)
                    for name, specs in by_cluster.items() if name in clusters]

@@ -51,6 +51,7 @@ reconciliation absorbs it, throughput pays for it.
 from __future__ import annotations
 
 import itertools
+import time
 from collections import deque
 from typing import Dict, List, Optional, Tuple
 
@@ -69,14 +70,19 @@ class _InFlight:
     """One optimistic cycle between dispatch and apply."""
 
     __slots__ = ("id", "staged", "dispatches", "fetched", "exclude",
-                 "consumed", "tokens_spent", "delta", "knows", "staged_tx")
+                 "consumed", "tokens_spent", "delta", "knows", "staged_tx",
+                 "staged_at")
 
     def __init__(self, id_: int, staged: _StagedCycle,
-                 dispatches: List[_GroupDispatch], staged_tx: int = -1):
+                 dispatches: List[_GroupDispatch], staged_tx: int = -1,
+                 staged_at: float = 0.0):
         self.id = id_
         self.staged = staged
         self.dispatches = dispatches
         self.staged_tx = staged_tx
+        # perf_counter at the start of the stage that produced this
+        # entry's candidates (CycleRecord.pipeline_lag_ms)
+        self.staged_at = staged_at
         self.fetched = False
         # computed at fetch: per-pool candidate footprint for masking the
         # NEXT stage -- pool name -> ("rows"|"uuids", epoch, ids) -- and
@@ -175,11 +181,33 @@ class PipelinedCycleDriver:
         """Stage a cycle off the current store, masked by the candidate
         footprints of every fetched-but-unapplied entry in ``after``, and
         dispatch all its groups (async output copies start rolling)."""
+        staged_at = time.perf_counter()
+        with tracing.span("pipeline.host", step="footprints"):
+            exclude, avail_delta, token_delta, knows = \
+                self._merge_footprints(after or [])
+        staged_tx = self._store_tx()
+        staged = self.fused.stage(scheduler, exclude=exclude or None,
+                                  avail_delta=avail_delta or None,
+                                  token_delta=token_delta or None)
+        dispatches = []
+        for sg in staged.groups:
+            with tracing.span("cycle.match", pools=len(sg.group),
+                              tasks=sg.T, hosts=sg.H, gpu=sg.gpu_mode):
+                dispatches.append(self.fused.dispatch_group(sg))
+        entry = _InFlight(next(self._ids), staged, dispatches,
+                          staged_tx=staged_tx, staged_at=staged_at)
+        entry.knows = knows
+        return entry
+
+    @staticmethod
+    def _merge_footprints(after: List[_InFlight]):
+        """The candidate footprints of the fetched-but-unapplied entries,
+        merged: (exclude, avail_delta, token_delta, ids merged)."""
         exclude: Dict[str, tuple] = {}
         avail_delta: Dict[tuple, np.ndarray] = {}
         token_delta: Dict[str, Dict[str, float]] = {}
         knows = set()
-        for e in after or []:
+        for e in after:
             knows.add(e.id)
             # per-pool MERGE (plain update would keep only the last
             # entry's mask when several fetched entries cover one pool —
@@ -205,19 +233,7 @@ class PipelinedCycleDriver:
                 cur_pool = token_delta.setdefault(pool_name, {})
                 for user, n in spent.items():
                     cur_pool[user] = cur_pool.get(user, 0.0) + n
-        staged_tx = self._store_tx()
-        staged = self.fused.stage(scheduler, exclude=exclude or None,
-                                  avail_delta=avail_delta or None,
-                                  token_delta=token_delta or None)
-        dispatches = []
-        for sg in staged.groups:
-            with tracing.span("cycle.match", pools=len(sg.group),
-                              tasks=sg.T, hosts=sg.H, gpu=sg.gpu_mode):
-                dispatches.append(self.fused.dispatch_group(sg))
-        entry = _InFlight(next(self._ids), staged, dispatches,
-                          staged_tx=staged_tx)
-        entry.knows = knows
-        return entry
+        return exclude, avail_delta, token_delta, knows
 
     # ----------------------------------------------------------------- fetch
     def _fetch(self, entry: _InFlight) -> None:
@@ -229,7 +245,8 @@ class PipelinedCycleDriver:
                               gpu=gd.sg.gpu_mode):
                 self.fused.fetch_group(gd)
         entry.fetched = True
-        self._candidate_footprint(entry)
+        with tracing.span("pipeline.host", step="candidate-footprint"):
+            self._candidate_footprint(entry)
 
     def _candidate_footprint(self, entry: _InFlight) -> None:
         """From the fetched outputs, the footprint the NEXT stage must
@@ -339,12 +356,21 @@ class PipelinedCycleDriver:
                                         for p in entry.staged.pools}
         results: Dict[str, MatchCycleResult] = {}
         reconciler = self._make_reconciler(entry)
+        _flight.note_staged(
+            entry.staged_tx,
+            (time.perf_counter() - entry.staged_at) * 1000.0)
         for gd in entry.dispatches:
             self.fused.apply_group(scheduler, gd, queues, results,
                                    reconciler=reconciler)
-        # propagate this entry's ACTUAL launch consumption to in-flight
-        # entries that did not already subtract its candidate footprint
-        # at stage time (depth > 2, or a stage that raced this apply)
+        with tracing.span("pipeline.host", step="consumed"):
+            self._propagate_consumed(entry, results)
+        return queues, results
+
+    def _propagate_consumed(self, entry: _InFlight,
+                            results: Dict[str, MatchCycleResult]) -> None:
+        """Propagate this entry's ACTUAL launch consumption to in-flight
+        entries that did not already subtract its candidate footprint
+        at stage time (depth > 2, or a stage that raced this apply)."""
         consumed: Dict[tuple, np.ndarray] = {}
         for result in results.values():
             launched = set(result.launched_job_uuids)
@@ -364,7 +390,6 @@ class PipelinedCycleDriver:
                 for key, vec in consumed.items():
                     cur = other.delta.get(key)
                     other.delta[key] = vec if cur is None else cur + vec
-        return queues, results
 
     def _make_reconciler(self, entry: _InFlight):
         """The pre-launch re-validation hook handed to _apply_pool: state

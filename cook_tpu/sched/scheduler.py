@@ -135,6 +135,8 @@ class Scheduler:
         # lands here; ``on_fatal`` lets the owning process (daemon.py)
         # turn it into a non-zero exit
         self.fatal_error: Optional[BaseException] = None
+        # wall-clock instant run() started the loop threads (None until then)
+        self.started_s: Optional[float] = None
         self.on_fatal = None
         # fused production cycle driver, created lazily on first step_cycle;
         # _pipeline wraps it when config.pipeline.depth > 0 (the pipelined
@@ -372,8 +374,12 @@ class Scheduler:
         if self._gc_collect_due:
             self._gc_collect_due = False
             import gc
+            t0 = time.perf_counter()
             gc.collect()
             gc.freeze()
+            # a field of the tick: carried on this thread's next record
+            flight_recorder.note_tick(
+                "gc_ms", (time.perf_counter() - t0) * 1000.0)
 
     def _on_tx_events(self, tx_id: int, events) -> None:
         """Kill live instances of jobs that reached completed — covers user
@@ -791,53 +797,62 @@ class Scheduler:
                     rec.jobs_placed = sum(len(r.launched_task_ids)
                                           for r in results.values())
                 return results
-            # direct pools: host rank + backpressure submission
-            for pool in self.store.pools():
-                if pool.state != "active" \
-                        or pool.scheduler is not SchedulerKind.DIRECT:
-                    continue
-                ranked = self._filter_offensive_jobs(
-                    self.ranker.rank_pool(pool.name, pool.dru_mode))
-                queues[pool.name] = ranked
-                results[pool.name] = self._match_direct(pool.name, ranked)
-            # queues were computed pre-launch; prune the jobs this cycle
-            # launched so consumers (rebalancer, /queue, direct pools) see
-            # current state.  Pools whose producer already dropped launches
-            # by exact queue position (fused _apply_pool) are skipped — the
-            # full-queue isin scan is O(T) string work at the 100k+ scale.
-            launched_uuids = set()
-            for pool_name, result in results.items():
-                if result.queue_pruned:
-                    continue
-                launched_uuids.update(result.launched_job_uuids)
-            if launched_uuids:
-                from .ranker import RankedQueue
+            # what step_cycle does after the driver returns (direct pools,
+            # queue prune, autoscale, results): detail_ms.publish
+            with tracing.span("cycle.publish"):
+                # direct pools: host rank + backpressure submission
+                for pool in self.store.pools():
+                    if pool.state != "active" \
+                            or pool.scheduler is not SchedulerKind.DIRECT:
+                        continue
+                    ranked = self._filter_offensive_jobs(
+                        self.ranker.rank_pool(pool.name, pool.dru_mode))
+                    queues[pool.name] = ranked
+                    results[pool.name] = self._match_direct(pool.name, ranked)
+                # queues were computed pre-launch; prune the jobs this cycle
+                # launched so consumers (rebalancer, /queue, direct pools)
+                # see current state.  Pools whose producer already dropped
+                # launches by exact queue position (fused _apply_pool) are
+                # skipped — the full-queue isin scan is O(T) string work at
+                # the 100k+ scale.
+                launched_uuids = set()
+                for pool_name, result in results.items():
+                    if result.queue_pruned:
+                        continue
+                    launched_uuids.update(result.launched_job_uuids)
+                if launched_uuids:
+                    from .ranker import RankedQueue
 
-                def prune(q):
-                    if isinstance(q, RankedQueue):
-                        # columnar: vectorized, no full-queue
-                        # materialization
-                        import numpy as np
-                        return q.filtered(~np.isin(q.uuids,
-                                                   list(launched_uuids)))
-                    return [j for j in q if j.uuid not in launched_uuids]
-                queues = {p: (q if results.get(p) is not None
-                              and results[p].queue_pruned else prune(q))
-                          for p, q in queues.items()}
-            self.pending_queues = queues
-            for pool_name, result in results.items():
-                self._autoscale(pool_name, result)
-            self.last_match_results.update(results)
-            if rec is not None:
-                rec.pools = len(results)
-                rec.jobs_considered = sum(r.considered
+                    def prune(q):
+                        if isinstance(q, RankedQueue):
+                            # columnar: vectorized, no full-queue
+                            # materialization
+                            import numpy as np
+                            return q.filtered(~np.isin(q.uuids,
+                                                       list(launched_uuids)))
+                        return [j for j in q if j.uuid not in launched_uuids]
+                    queues = {p: (q if results.get(p) is not None
+                                  and results[p].queue_pruned else prune(q))
+                              for p, q in queues.items()}
+                self.pending_queues = queues
+                for pool_name, result in results.items():
+                    self._autoscale(pool_name, result)
+                self.last_match_results.update(results)
+                if rec is not None:
+                    rec.pools = len(results)
+                    rec.jobs_considered = sum(r.considered
+                                              for r in results.values())
+                    rec.jobs_placed = sum(len(r.launched_task_ids)
                                           for r in results.values())
-                rec.jobs_placed = sum(len(r.launched_task_ids)
-                                      for r in results.values())
         # once per cycle: journal the trail's pending advisory events so
         # decision context survives a leader failover (utils/audit.py;
         # a no-op without a journal or with nothing pending)
+        # outside the record (duration_ms sums what it always summed);
+        # timed as a field of the tick, carried on the NEXT record
+        t0 = time.perf_counter()
         self.store.flush_audit()
+        flight_recorder.note_tick(
+            "flush_audit_ms", (time.perf_counter() - t0) * 1000.0)
         return results
 
     def step_match(self, pool_name: Optional[str] = None
@@ -1367,6 +1382,21 @@ class Scheduler:
         return True
 
     # ------------------------------------------------------------- wall clock
+    def _background_tick(self, kind: str, fn) -> None:
+        """One run of a background loop (reapers, monitor, rebalance, ...)
+        as Scheduler.run's ``loop`` makes it: a flight record of that
+        kind — a cycle it overlaps reads it back as
+        ``background_ms[kind]`` — and ``cook_background_loop_seconds``.
+        Direct callers of step_reapers / monitor.sweep mint no record."""
+        from ..utils.metrics import registry
+        t0 = time.perf_counter()
+        try:
+            with flight_recorder.cycle(kind=kind):
+                fn()
+        finally:
+            registry.observe("cook_background_loop_seconds",
+                             time.perf_counter() - t0, {"loop": kind})
+
     def run(self) -> None:
         """Start background cycle threads (the chime equivalent)."""
         cfg = self.config
@@ -1395,15 +1425,31 @@ class Scheduler:
                 log.exception("cycle failed")
             return True
 
-        def loop(interval, fn, immediate: bool = False) -> None:
+        def loop(interval, fn, kind: Optional[str] = None,
+                 immediate: bool = False) -> None:
             # interval may be a callable so dynamically-tunable cadences
             # (the rebalancer's no-restart interval-seconds) take effect on
-            # the next tick instead of being frozen at startup
-            if immediate and not self._stop.is_set() and not tick(fn):
+            # the next tick instead of being frozen at startup.
+            # ``kind`` names a BACKGROUND loop: each of its runs is a
+            # flight record of that kind (so /debug/cycles shows the
+            # sweeps beside the cycles they overlap); None is the cycle
+            # thread's own tick, which opens its own records.
+            # a scheduler thread: its spans go on the profiler's clock
+            # too, on a line that carries this thread's name
+            tracing.annotate_spans(
+                thread_name=threading.current_thread().name)
+            run = fn if kind is None else \
+                (lambda: self._background_tick(kind, fn))
+            if immediate and not self._stop.is_set() and not tick(run):
                 return
-            while not self._stop.wait(interval() if callable(interval)
-                                      else interval):
-                if not tick(fn):
+            while True:
+                t0 = time.perf_counter()
+                if self._stop.wait(interval() if callable(interval)
+                                   else interval):
+                    return
+                flight_recorder.note_tick(
+                    "wait_ms", (time.perf_counter() - t0) * 1000.0)
+                if not tick(run):
                     return
 
         if cfg.cycle_mode == "fused" and self.ranker.backend != "cpu":
@@ -1412,21 +1458,27 @@ class Scheduler:
             def fused_tick():
                 self.step_cycle()
                 self.maintain_gc()
-            specs = [(cfg.match_interval_seconds, fused_tick)]
+            specs = [(cfg.match_interval_seconds, fused_tick, None)]
         else:
-            specs = [(cfg.rank_interval_seconds, self.step_rank),
-                     (cfg.match_interval_seconds, self.step_match)]
+            specs = [(cfg.rank_interval_seconds, self.step_rank, None),
+                     (cfg.match_interval_seconds, self.step_match, None)]
         specs += [
             (lambda: self.rebalancer.effective_params().interval_seconds,
-             self.step_rebalance),
-            (cfg.lingering_task_interval_seconds, self.step_reapers),
-            (cfg.monitor_interval_seconds, self.monitor.sweep),
+             self.step_rebalance, "rebalance"),
+            (cfg.lingering_task_interval_seconds, self.step_reapers,
+             "reapers"),
+            (cfg.monitor_interval_seconds, self.monitor.sweep, "monitor"),
         ]
         if cfg.elastic.enabled:
             specs.append((cfg.elastic.resize_interval_seconds,
-                          self.step_resize))
-        for interval, fn in specs:
-            t = threading.Thread(target=loop, args=(interval, fn), daemon=True)
+                          self.step_resize, "resize"))
+        # the instant the loop threads' timers count from (the 30 s
+        # sweeps fall at multiples of their interval after it);
+        # /debug/health serves it as scheduler.started_s
+        self.started_s = time.time()
+        for interval, fn, kind in specs:
+            t = threading.Thread(target=loop, args=(interval, fn, kind),
+                                 name=f"cook-{kind or 'cycle'}", daemon=True)
             t.start()
             self._threads.append(t)
         if cfg.optimizer is not None:
@@ -1435,8 +1487,10 @@ class Scheduler:
             # fix, mirrored here for the scheduler-driven loop)
             t = threading.Thread(
                 target=loop,
-                args=(cfg.optimizer.interval_seconds, self.step_optimize),
-                kwargs={"immediate": True}, daemon=True)
+                args=(cfg.optimizer.interval_seconds, self.step_optimize,
+                      "optimize"),
+                kwargs={"immediate": True}, name="cook-optimize",
+                daemon=True)
             t.start()
             self._threads.append(t)
 

@@ -658,7 +658,8 @@ class Store:
             lambda _tx_id, events: self.audit.on_tx_events(events))
 
     # ------------------------------------------------------------------ txns
-    def transact(self, fn: Callable[[_Txn], Any]) -> Any:
+    def transact(self, fn: Callable[[_Txn], Any], *,
+                 drain_span: bool = False) -> Any:
         """Run ``fn`` transactionally. Its writes are installed atomically on
         normal return; AbortTransaction rolls back and re-raises.
 
@@ -673,7 +674,13 @@ class Store:
         ack round resolves on the shared committer AFTER the lock is
         released — this thread blocks on its waiter and re-raises the
         demuxed outcome, so callers observe the same contract with the
-        expensive tail amortized across concurrent committers."""
+        expensive tail amortized across concurrent committers.
+
+        ``drain_span``: also time the event drain as a span of its own
+        (``store.drain-events``).  Only the cycle's one launch
+        transaction asks for it: a span on every transaction would put a
+        thousand more on a cycle whose backend acknowledges each task
+        through a status transaction."""
         indeterminate: Optional[ReplicationIndeterminate] = None
         waiter: Optional[_CommitWaiter] = None
         with self._lock:
@@ -706,9 +713,22 @@ class Store:
                 self._latches.pop(latch, None)
             if txn.events:
                 self._event_queue.append((self._tx_id, txn.events))
-        self._drain_events()
+        if drain_span and tracing.tracer.current() is not None:
+            with tracing.span("store.drain-events", events=len(txn.events)):
+                self._drain_events()
+        else:
+            self._drain_events()
         if waiter is not None:
+            # the group-commit round this thread blocks on.  A scheduler
+            # cycle's record takes it as blocked_ms.commit_wait and
+            # detail_ms.apply_journal (utils/flight.py) — as a bare
+            # duration, not a span: a backend that acknowledges each
+            # task through a status transaction waits here a thousand
+            # times a cycle
+            t0 = time.perf_counter()
             err = waiter.stage.wait(waiter)
+            tracing.cycle_time("journal.commit-wait",
+                               time.perf_counter() - t0)
             # attribute the SHARED round's cost into this request's own
             # trace/phase breakdown (rest/instrument.py PHASE_SPANS):
             # the committer measured it once; every waiter reports it
@@ -1404,7 +1424,12 @@ class Store:
                 out.append(inst)
             return out, failures
 
-        return self.transact(_launch_all)
+        # guard pass + puts + install, under the store lock; the journal
+        # append, the commit wait and the event drain inside carry spans
+        # of their own and are carved out (flight.DETAIL_BY_SPAN)
+        with (tracing.span("store.launch-txn", entries=len(entries))
+              if tracing.tracer.current() is not None else nullcontext()):
+            return self.transact(_launch_all, drain_span=True)
 
     def update_instance_status(self, task_id: str, new_status: InstanceStatus,
                                reason_code: Optional[int] = None,

@@ -12,10 +12,15 @@ opens a :meth:`FlightRecorder.cycle` context that
      host<->device bytes, device sync-wait time (fed by
      cook_tpu.ops.telemetry), head-of-line skip reasons, preemptions,
      jobs considered/placed;
-  3. on exit harvests the trace's spans into per-phase durations
-     (rank / match / launch / rebalance) and lands the finished record in
-     a fixed-size ring served by ``GET /debug/cycles`` and the
-     ``cook-tpu debug cycles`` CLI.
+  3. receives every span that ENDS inside the cycle (tracing.Tracer
+     hands each finished span to the current record in O(1)) and routes
+     its time to the per-phase durations (rank / match / launch /
+     rebalance), the ``detail_ms`` split (:data:`DETAIL_BY_SPAN`) and
+     the cycle thread's named waits (``blocked_ms``; with ``offcpu_ms``
+     and ``background_ms`` at the end) — the span ring is never
+     scanned — and lands the
+     finished record in a fixed-size ring served by
+     ``GET /debug/cycles`` and the ``cook-tpu debug cycles`` CLI.
 
 This is the repro of the reference's structured match-cycle log documents
 (scheduler.clj match cycle logging + prometheus_metrics.clj with-duration
@@ -27,13 +32,14 @@ field on the slow cycle's record, not a mystery p99 blip.
 from __future__ import annotations
 
 import contextvars
+import gc
 import threading
 import time
 from collections import deque
 from contextlib import contextmanager
 from typing import Any, Dict, List, Optional
 
-from cook_tpu.utils import tracing
+from cook_tpu.utils import locks, tracing
 from cook_tpu.utils.metrics import registry
 
 _DEFAULT_CAPACITY = 512
@@ -51,8 +57,111 @@ PHASE_BY_SPAN = {
     "rebalancer.pool": "rebalance",
 }
 
+# span name -> detail_ms key: THE one table that says which span feeds
+# which key (the per-layer benchmark metrics read these keys by name).
+# A key's value is the time of the spans mapped to that key only: when a
+# mapped span ends inside another mapped span that is not its declared
+# parent (DETAIL_PARENT), its time is carved out of the enclosing key —
+# a journal append inside the cluster-launch span counts as
+# apply_journal, not twice — so the parts of a key never overlap.
+DETAIL_BY_SPAN = {
+    # top level: these partition a fused cycle (with "other")
+    "fused.pools": "pools",
+    "fused.pack": "pack",
+    "fused.stage": "stage",
+    "fused.dispatch": "dispatch",
+    "fused.fetch": "fetch",
+    "cycle.launch": "apply",
+    "pipeline.host": "pipeline",
+    "cycle.publish": "publish",
+    # parts of pack (fused._pack_pool_columnar / _pack_pool_cached)
+    "pack.index": "pack_index",
+    "pack.offers": "pack_offers",
+    "pack.rows": "pack_rows",
+    # parts of apply (fused._apply_pool, matcher._launch, state/store.py)
+    "apply.lookup": "apply_lookup",
+    "launch.prepare": "apply_lookup",
+    "store.launch-txn": "apply_txn",
+    "journal.append": "apply_journal",
+    "journal.commit-wait": "apply_journal",
+    "launch.specs": "apply_cluster",
+    "cluster.launch-tasks": "apply_cluster",
+    "store.clear-intents": "apply_cluster",
+    "apply.audit": "apply_audit",
+    "store.drain-events": "apply_audit",
+}
+#: detail_ms key -> the key it is a part of
+DETAIL_PARENT = {
+    "pack_index": "pack", "pack_offers": "pack", "pack_rows": "pack",
+    "apply_lookup": "apply", "apply_txn": "apply",
+    "apply_journal": "apply", "apply_cluster": "apply",
+    "apply_audit": "apply",
+}
+#: the keys that partition a fused cycle; ``detail_ms.other`` is
+#: duration_ms minus these — what no span covers yet
+DETAIL_TOP_LEVEL = ("pools", "pack", "stage", "dispatch", "fetch", "apply",
+                    "pipeline", "publish")
+
+#: span name -> blocked_ms key: a named wait of the recording thread
+BLOCKED_BY_SPAN = {"journal.commit-wait": "commit_wait"}
+# the three tables above merged, so a finished span costs one lookup:
+# name -> (phase, detail key, the key's declared parent, blocked key)
+_ROUTES = {
+    name: (PHASE_BY_SPAN.get(name), DETAIL_BY_SPAN.get(name),
+           DETAIL_PARENT.get(DETAIL_BY_SPAN.get(name)),
+           BLOCKED_BY_SPAN.get(name))
+    for name in {*PHASE_BY_SPAN, *DETAIL_BY_SPAN, *BLOCKED_BY_SPAN}}
+
+#: record kinds that ARE scheduling cycles; every other kind (reapers,
+#: monitor, rebalance, ...) is a background loop run whose interval is
+#: kept so an overlapping cycle can say which sweep ran beside it
+CYCLE_KINDS = frozenset({"cycle", "fused", "rank", "match"})
+#: background_ms keys every cycle record carries (0.0 = no overlap)
+BACKGROUND_LOOPS = ("reapers", "monitor", "rebalance")
+#: blocked_ms keys every record carries; a contended lock of another
+#: family adds ``<family>_lock`` beside them
+BLOCKED_KEYS = ("store_lock", "commit_wait", "device", "gc")
+
+# the current record IS the tracer's per-cycle span sink: one context
+# variable, set once per cycle (copied contexts — the per-cluster launch
+# threads — keep both the telemetry notes and the spans on the record)
 _current_record: "contextvars.ContextVar[Optional[CycleRecord]]" = \
-    contextvars.ContextVar("cook_cycle_record", default=None)
+    tracing._cycle_var
+
+# ---------------------------------------------------- collector pauses
+# ONE gc.callbacks hook for the process: a collection triggered on any
+# thread stops every thread, so a cycle that overlaps one was blocked
+# for the overlap whichever thread paid for it.  The hook may fire at
+# ANY allocation — inside the metrics registry's own lock included — so
+# it takes no lock and touches no registry: it only appends to deques;
+# FlightRecorder._finish publishes cook_gc_pause_seconds from there.
+_gc_recent: "deque[tuple]" = deque(maxlen=1024)     # (t0, t1, gen, thread)
+_gc_unpublished: "deque[tuple]" = deque(maxlen=4096)  # (gen, seconds)
+_gc_open: List[float] = [0.0]
+
+
+def _on_gc(phase: str, info: Dict[str, Any]) -> None:
+    if phase == "start":
+        _gc_open[0] = time.perf_counter()
+        return
+    t0, t1 = _gc_open[0], time.perf_counter()
+    gen = int(info.get("generation", -1))
+    _gc_recent.append((t0, t1, gen, threading.get_ident()))
+    _gc_unpublished.append((gen, t1 - t0))
+
+
+if _on_gc not in gc.callbacks:
+    gc.callbacks.append(_on_gc)
+
+
+def _publish_gc_pauses(limit: int = 512) -> None:
+    for _ in range(limit):
+        try:
+            gen, seconds = _gc_unpublished.popleft()
+        except IndexError:
+            return
+        registry.observe("cook_gc_pause_seconds", seconds,
+                         {"generation": str(gen)})
 
 # process-wide shard identity (ISSUE 19): a sharded-controller process
 # owns exactly ONE partition shard, so the id is process state, not
@@ -98,7 +207,10 @@ class CycleRecord:
                  "error", "pipeline_depth", "pipeline_inflight",
                  "pipeline_conflicts", "delta_rows", "full_repacks",
                  "audit_events", "kernel_launches", "path", "shard",
-                 "device", "_t0")
+                 "device", "wait_ms", "gc_ms", "flush_audit_ms",
+                 "cpu_ms", "blocked_ms", "lock_holder", "offcpu_ms",
+                 "background_ms", "staged_tx", "pipeline_lag_ms",
+                 "_lock_wait_max", "_thread", "_cpu0", "_t0")
 
     def __init__(self, seq: int, kind: str):
         self.seq = seq
@@ -156,7 +268,61 @@ class CycleRecord:
         # in /debug/cycles and the Perfetto export
         self.kernel_launches = 0
         self.path: Optional[str] = None
+        # the tick around the cycle (Scheduler.run's loop): the interval
+        # wait that preceded it, and the idle-point GC and audit flush
+        # of the tick before — with duration_ms these reproduce the
+        # start-to-start period of consecutive cycles
+        self.wait_ms = 0.0
+        self.gc_ms = 0.0
+        self.flush_audit_ms = 0.0
+        # where the cycle thread's wall time went: on the CPU
+        # (thread_time, collector pauses on this thread excluded),
+        # blocked on a named wait (a contended lock by family, the
+        # group-commit round, the device fetch, a collector pause
+        # anywhere in the process), or neither — runnable but not
+        # running, i.e. waiting for the GIL or a core
+        self.cpu_ms = 0.0
+        self.blocked_ms: Dict[str, float] = dict.fromkeys(BLOCKED_KEYS, 0.0)
+        self.lock_holder: Optional[str] = None
+        self.offcpu_ms = 0.0
+        # overlap with the other loops' runs (reapers / monitor / ...)
+        self.background_ms: Dict[str, float] = {}
+        # the store transaction the applied candidates were staged from
+        # and how long ago that stage began (sched/pipeline.py)
+        self.staged_tx: Optional[int] = None
+        self.pipeline_lag_ms = 0.0
+        self._lock_wait_max = 0.0
+        self._thread = threading.get_ident()
+        self._cpu0 = time.thread_time()
         self._t0 = time.perf_counter()
+
+    def add_span(self, name: str, seconds: float, open_spans) -> None:
+        """A span ended inside this cycle (called by Tracer._record, in
+        the ending thread): route its time to the phase, the detail_ms
+        key and the blocked_ms key its name maps to.  ``open_spans`` is
+        the span stack still open around it, innermost last."""
+        route = _ROUTES.get(name)
+        if route is None:
+            return
+        phase, key, parent, blocked = route
+        ms = seconds * 1000.0
+        if phase is not None:
+            self.phases[phase] = self.phases.get(phase, 0.0) + ms
+        if blocked is not None:
+            self.blocked_ms[blocked] += ms
+        if key is None:
+            return
+        detail = self.detail_ms
+        detail[key] = detail.get(key, 0.0) + ms
+        for outer in reversed(open_spans):
+            outer_route = _ROUTES.get(outer.name)
+            if outer_route is not None and outer_route[1] is not None:
+                if outer_route[1] != parent:
+                    # nested under a sibling (or under its own key):
+                    # carve it out so no time is counted twice
+                    detail[outer_route[1]] = \
+                        detail.get(outer_route[1], 0.0) - ms
+                break
 
     def to_doc(self) -> Dict[str, Any]:
         return {
@@ -184,6 +350,18 @@ class CycleRecord:
             "path": self.path,
             "shard": self.shard,
             "device": self.device,
+            "wait_ms": round(self.wait_ms, 3),
+            "gc_ms": round(self.gc_ms, 3),
+            "flush_audit_ms": round(self.flush_audit_ms, 3),
+            "cpu_ms": round(self.cpu_ms, 3),
+            "blocked_ms": {k: round(v, 3)
+                           for k, v in self.blocked_ms.items()},
+            "lock_holder": self.lock_holder,
+            "offcpu_ms": round(self.offcpu_ms, 3),
+            "background_ms": {k: round(v, 3)
+                              for k, v in self.background_ms.items()},
+            "staged_tx": self.staged_tx,
+            "pipeline_lag_ms": round(self.pipeline_lag_ms, 3),
             "error": self.error,
         }
 
@@ -194,6 +372,13 @@ class FlightRecorder:
         self._ring: "deque[CycleRecord]" = deque(maxlen=capacity)
         self._seq = 0
         self.enabled = True
+        # per-thread notes of the tick BETWEEN records (the interval
+        # wait, the idle-point GC, the audit flush): attached to the
+        # next record opened on the same thread
+        self._tick = threading.local()
+        # [kind, t0, t1-or-None] of recent background loop runs, on the
+        # perf_counter clock (a cycle's overlap is read at its _finish)
+        self._background: "deque[list]" = deque(maxlen=64)
 
     # ------------------------------------------------------------- lifecycle
     @contextmanager
@@ -205,9 +390,18 @@ class FlightRecorder:
         if not self.enabled or cur is not None:
             yield cur
             return
+        run: Optional[list] = None
         with self._lock:
             self._seq += 1
             rec = CycleRecord(self._seq, kind)
+            if kind not in CYCLE_KINDS:
+                run = [kind, rec._t0, None]
+                self._background.append(run)
+        notes, self._tick.notes = getattr(self._tick, "notes", None), None
+        if notes:
+            rec.wait_ms = notes.get("wait_ms", 0.0)
+            rec.gc_ms = notes.get("gc_ms", 0.0)
+            rec.flush_audit_ms = notes.get("flush_audit_ms", 0.0)
         token = _current_record.set(rec)
         try:
             with tracing.span("cycle", kind=kind, seq=rec.seq, **tags) as sp:
@@ -218,18 +412,53 @@ class FlightRecorder:
             raise
         finally:
             _current_record.reset(token)
-            rec.duration_ms = (time.perf_counter() - rec._t0) * 1000.0
-            self._finish(rec)
+            end = time.perf_counter()
+            rec.duration_ms = (end - rec._t0) * 1000.0
+            rec.cpu_ms = (time.thread_time() - rec._cpu0) * 1000.0
+            if run is not None:
+                run[2] = end
+            self._finish(rec, end)
 
-    def _finish(self, rec: CycleRecord) -> None:
-        if rec.trace_id is not None:
-            for doc in tracing.tracer.traces(rec.trace_id):
-                phase = PHASE_BY_SPAN.get(doc["span"])
-                if phase is not None:
-                    rec.phases[phase] = rec.phases.get(phase, 0.0) \
-                        + (doc.get("duration_ms") or 0.0)
+    def _finish(self, rec: CycleRecord, end: float) -> None:
+        # phases_ms and detail_ms were accumulated span by span
+        # (CycleRecord.add_span): the span ring is not touched
+        detail = rec.detail_ms
+        for part, whole in DETAIL_PARENT.items():
+            if whole in detail:
+                # a part no span fed this cycle (nothing launched, so no
+                # journal append) took 0 ms of a whole that ran
+                detail.setdefault(part, 0.0)
+        if any(k in detail for k in DETAIL_TOP_LEVEL):
+            detail["other"] = rec.duration_ms - sum(
+                detail.get(k, 0.0) for k in DETAIL_TOP_LEVEL)
+        blocked = rec.blocked_ms
+        blocked["device"] = rec.sync_wait_ms
+        gc_ms = own_gc_ms = 0.0
+        # a snapshot: the hook appends from whichever thread collects
+        for t0, t1, _gen, thread in reversed(tuple(_gc_recent)):
+            if t1 <= rec._t0:
+                break
+            over = (min(t1, end) - max(t0, rec._t0)) * 1000.0
+            if over > 0:
+                gc_ms += over
+                if thread == rec._thread:
+                    own_gc_ms += over
+        blocked["gc"] = gc_ms
+        # a collection on this very thread is CPU time of this thread:
+        # it counts as the pause it was, once
+        rec.cpu_ms -= own_gc_ms
+        rec.offcpu_ms = rec.duration_ms - rec.cpu_ms - sum(blocked.values())
         with self._lock:
+            if rec.kind in CYCLE_KINDS:
+                rec.background_ms = dict.fromkeys(BACKGROUND_LOOPS, 0.0)
+                for kind, t0, t1 in self._background:
+                    over = (min(end if t1 is None else t1, end)
+                            - max(t0, rec._t0)) * 1000.0
+                    if over > 0:
+                        rec.background_ms[kind] = \
+                            rec.background_ms.get(kind, 0.0) + over
             self._ring.append(rec)
+        _publish_gc_pauses()
         registry.observe("cook_cycle_duration_seconds",
                          rec.duration_ms / 1000.0, {"kind": rec.kind})
         if rec.jobs_considered:
@@ -300,14 +529,40 @@ class FlightRecorder:
             with self._lock:
                 rec.pipeline_conflicts += int(n)
 
-    def note_phase_detail(self, name: str, ms: float) -> None:
-        """Sub-phase duration (pack / stage / apply) summed onto the
-        current record's detail breakdown."""
+    def note_tick(self, field: str, ms: float) -> None:
+        """A reading of the tick BETWEEN cycles on this thread —
+        ``wait_ms`` (the interval wait), ``gc_ms`` (the idle-point
+        collection), ``flush_audit_ms`` — carried onto the next record
+        this thread opens (Scheduler.run's loop and step_cycle call
+        this; a record's callers never do)."""
+        notes = getattr(self._tick, "notes", None)
+        if notes is None:
+            notes = self._tick.notes = {}
+        notes[field] = notes.get(field, 0.0) + float(ms)
+
+    def note_lock_wait(self, lock_name: str, seconds: float,
+                       holder: Optional[str]) -> None:
+        """A CONTENDED named-lock acquisition by the current thread
+        (utils/locks.py reports every one): the wait lands on this
+        thread's record under ``<family>_lock``, and the holder of the
+        longest wait is kept."""
+        rec = _current_record.get()
+        if rec is None:
+            return
+        key = locks.family(lock_name).replace(".", "_") + "_lock"
+        rec.blocked_ms[key] = rec.blocked_ms.get(key, 0.0) \
+            + seconds * 1000.0
+        if seconds > rec._lock_wait_max:
+            rec._lock_wait_max = seconds
+            rec.lock_holder = holder
+
+    def note_staged(self, staged_tx: int, lag_ms: float) -> None:
+        """The store transaction the cycle being applied was staged from,
+        and the time from that stage's start to this apply's start."""
         rec = _current_record.get()
         if rec is not None:
-            with self._lock:
-                rec.detail_ms[name] = rec.detail_ms.get(name, 0.0) \
-                    + float(ms)
+            rec.staged_tx = int(staged_tx)
+            rec.pipeline_lag_ms = float(lag_ms)
 
     def note_delta(self, rows: int) -> None:
         """Delta rows scatter-applied into the device-resident pack this
@@ -473,3 +728,4 @@ class FlightRecorder:
 
 
 recorder = FlightRecorder()
+locks.monitor.contention_sink = recorder.note_lock_wait

@@ -151,6 +151,11 @@ class LockMonitor:
         self.violations: List[Dict[str, Any]] = []
         self.blocking_events: List[Dict[str, Any]] = []
         self.allowed_blocking: Set[Tuple[str, str]] = set(ALLOWED_BLOCKING)
+        # called as (lock name, seconds waited, holder thread name) after
+        # every CONTENDED acquisition, in the thread that waited; the
+        # flight recorder (utils/flight.py) installs itself here so the
+        # wait lands on the waiting thread's CycleRecord
+        self.contention_sink = None
         self._armed = False
         self._originals: Dict[str, Any] = {}
 
@@ -435,10 +440,39 @@ class NamedLock:
 
     def acquire(self, blocking: bool = True, timeout: float = -1) -> bool:
         reentrant = self._monitor._note_acquiring(self)
-        ok = self._lock.acquire(blocking, timeout)
+        # non-blocking first: the uncontended path is the one acquire it
+        # always was; only a lock somebody else holds is timed
+        ok = self._lock.acquire(False)
+        if not ok and blocking:
+            holder = self._holder_name()
+            t0 = time.perf_counter()
+            if timeout is None or timeout < 0:
+                # wake now and then to look at who holds it: the holder
+                # of a long wait is then the one seen during the wait,
+                # not whoever held it at the first attempt
+                while not self._lock.acquire(True, 0.05):
+                    holder = self._holder_name() or holder
+                ok = True
+            else:
+                ok = self._lock.acquire(True, timeout)
+            sink = self._monitor.contention_sink
+            if sink is not None:
+                sink(self.name, time.perf_counter() - t0, holder)
         if ok and not reentrant:
             self._monitor._note_acquired(self)
         return ok
+
+    def _holder_name(self) -> Optional[str]:
+        """Name of the thread holding the lock right now, read off the
+        RLock's own owner field (its repr carries ``owner=<ident>``) so
+        that no acquisition ever pays for holder bookkeeping; None for a
+        plain Lock, or when the holder let go meanwhile."""
+        _head, sep, tail = repr(self._lock).partition("owner=")
+        ident = tail.split(" ", 1)[0] if sep else ""
+        if not ident.isdigit() or ident == "0":
+            return None
+        holder = threading._active.get(int(ident))
+        return holder.name if holder is not None else f"thread-{ident}"
 
     def release(self) -> None:
         self._lock.release()

@@ -32,6 +32,7 @@ from __future__ import annotations
 
 import contextvars
 import logging
+import sys
 import threading
 import time
 import uuid
@@ -57,6 +58,77 @@ _stack_var: "contextvars.ContextVar[tuple]" = contextvars.ContextVar(
 # span ring.  None (the default) costs one contextvar read per span.
 _phases_var: "contextvars.ContextVar[Optional[dict]]" = \
     contextvars.ContextVar("cook_req_phases", default=None)
+
+
+# Per-cycle span sink (utils/flight.py): while a CycleRecord is current,
+# every finished span adds itself to that record in O(1) — the record's
+# phases_ms / detail_ms / blocked_ms are read off this accumulation, never
+# off a scan of the span ring.  The sink is any object with
+# ``add_span(name, seconds, open_ancestors)``; copied contexts (the
+# per-cluster launch threads) keep feeding the owning cycle's record.
+_cycle_var: "contextvars.ContextVar[Optional[Any]]" = \
+    contextvars.ContextVar("cook_cycle_sink", default=None)
+
+# Scheduler threads (the cycle thread and Scheduler.run's loop threads)
+# also put every span on the PROFILER's clock: a jax.profiler
+# TraceAnnotation of the same name, so the program's spans land in the
+# xplane host plane beside the device ops whoever started the trace
+# (POST /debug/profile, a benchmark harness).  REST threads never set
+# the flag: an http.request span costs no annotation.
+_annotate_var: "contextvars.ContextVar[bool]" = \
+    contextvars.ContextVar("cook_span_annotate", default=False)
+_annotation_cls: Any = None
+
+
+def annotate_spans(on: bool = True,
+                   thread_name: Optional[str] = None) -> None:
+    """Mark the calling thread's context as a scheduler thread: spans
+    opened from here on also enter a profiler annotation (a no-op in
+    C++ while no profiler session is active).  ``thread_name`` also
+    names the OS thread (Linux, 15 bytes, best effort): a profiler
+    trace names a line after its OS thread, and Python names none, so
+    without it every Python thread's line reads ""."""
+    _annotate_var.set(bool(on))
+    if thread_name:
+        try:
+            import ctypes
+            ctypes.CDLL(None).prctl(15, thread_name.encode()[:15], 0, 0, 0)
+        except Exception:  # not Linux, no libc: the line stays unnamed
+            pass
+
+
+def _annotation(name: str):
+    """A profiler annotation context for ``name``, or None when this
+    context is not a scheduler thread or the process never imported JAX
+    (a jax-free worker must stay jax-free: the import is never made
+    here)."""
+    global _annotation_cls
+    if not _annotate_var.get():
+        return None
+    cls = _annotation_cls
+    if cls is None:
+        jax = sys.modules.get("jax")
+        if jax is None:
+            return None
+        try:
+            import jax.profiler as _prof
+            cls = _annotation_cls = _prof.TraceAnnotation
+        except Exception:  # pragma: no cover - profiler-less build
+            cls = _annotation_cls = False
+    return cls(name) if cls else None
+
+
+def cycle_time(name: str, seconds: float) -> None:
+    """Hand an already-measured duration to the current cycle's record
+    under a span NAME, without minting a span: for a wait that happens a
+    thousand times a cycle (the group-commit wait of every status
+    transaction), where a span each would cost more than the wait tells.
+    It goes through the same name -> key table as a finished span
+    (flight.DETAIL_BY_SPAN), nesting carve-out included; outside a cycle
+    it is one contextvar read."""
+    sink = _cycle_var.get()
+    if sink is not None:
+        sink.add_span(name, seconds, _stack_var.get())
 
 
 @contextmanager
@@ -219,6 +291,9 @@ class Tracer:
             trace_id, parent_id = uuid.uuid4().hex[:16], None
         sp = Span(name, trace_id, parent_id, tags)
         token = _stack_var.set(_stack_var.get() + (sp,))
+        note = _annotation(name)
+        if note is not None:
+            note.__enter__()
         t0 = time.perf_counter()
         try:
             yield sp
@@ -227,6 +302,8 @@ class Tracer:
             raise
         finally:
             sp.duration_s = time.perf_counter() - t0
+            if note is not None:
+                note.__exit__(None, None, None)
             _stack_var.reset(token)
             self._record(sp)
 
@@ -235,6 +312,11 @@ class Tracer:
         if phases is not None:
             phases[sp.name] = phases.get(sp.name, 0.0) \
                 + (sp.duration_s or 0.0)
+        sink = _cycle_var.get()
+        if sink is not None:
+            # the stack was reset above: what is left is this span's
+            # still-open ancestors, innermost last
+            sink.add_span(sp.name, sp.duration_s or 0.0, _stack_var.get())
         metric_labels = {"span": sp.name}
         for key in ("pool", "cluster"):
             if key in sp.tags:
@@ -460,6 +542,10 @@ def export_fleet_trace(span_docs: List[Dict[str, Any]], trace_id: str,
 
 
 class _NoopSpan:
+    # a disabled tracer measures nothing: readers of a span's duration
+    # (fused._stage_group's stage_ms tag) see None
+    duration_s = None
+
     def set_tag(self, key: str, value: Any) -> None:
         pass
 
