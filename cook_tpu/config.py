@@ -1052,7 +1052,9 @@ class EstimatedCompletionConfig:
 @dataclass
 class Config:
     rank_interval_seconds: float = 5.0         # mesos.clj:108
-    match_interval_seconds: float = 1.0        # target-per-pool-match-interval
+    # target-per-pool-match-interval: the cycle's period start to start,
+    # re-anchored after an overrun (Scheduler.run)
+    match_interval_seconds: float = 1.0
     max_over_quota_jobs: int = 100             # config.clj:413-416
     # "fused": production path — one device dispatch runs rank+admission+
     # match for all pools (sched/fused.py); "split": host-driven per-pool

@@ -1398,10 +1398,13 @@ class Scheduler:
                              time.perf_counter() - t0, {"loop": kind})
 
     def run(self) -> None:
-        """Start background cycle threads (the chime equivalent)."""
+        """Start background cycle threads (the chime equivalent): each
+        loop's interval is its period start to start, re-anchored after
+        an overrun (see ``loop``)."""
         cfg = self.config
 
         import logging
+        from ..utils.metrics import registry
         log = logging.getLogger(__name__)
 
         def tick(fn) -> bool:
@@ -1427,9 +1430,17 @@ class Scheduler:
 
         def loop(interval, fn, kind: Optional[str] = None,
                  immediate: bool = False) -> None:
+            # The interval is the loop's PERIOD, start to start: a tick is
+            # due one interval after the one before was (the first, one
+            # interval after the loop started), so a tick shorter than
+            # the interval is followed by the rest of it and a longer one
+            # by the next tick at once.  A late tick re-anchors the
+            # schedule at its own start: one stalled cycle costs one
+            # immediate tick, never a burst of catch-up ticks.
             # interval may be a callable so dynamically-tunable cadences
             # (the rebalancer's no-restart interval-seconds) take effect on
-            # the next tick instead of being frozen at startup.
+            # the next tick instead of being frozen at startup: it is
+            # read once a turn, before the wait.
             # ``kind`` names a BACKGROUND loop: each of its runs is a
             # flight record of that kind (so /debug/cycles shows the
             # sweeps beside the cycles they overlap); None is the cycle
@@ -1440,15 +1451,25 @@ class Scheduler:
                 thread_name=threading.current_thread().name)
             run = fn if kind is None else \
                 (lambda: self._background_tick(kind, fn))
+            labels = {"loop": kind or "cycle"}
+            anchor = time.perf_counter()
             if immediate and not self._stop.is_set() and not tick(run):
                 return
             while True:
-                t0 = time.perf_counter()
-                if self._stop.wait(interval() if callable(interval)
-                                   else interval):
+                due = anchor + (interval() if callable(interval)
+                                else interval)
+                # read after the last tick's maintain_gc and flush_audit:
+                # they come out of the wait, not on top of the period
+                now = time.perf_counter()
+                if self._stop.wait(max(0.0, due - now)):
                     return
+                late_ms = max(0.0, now - due) * 1000.0
+                if late_ms:
+                    registry.counter_inc("cook_loop_overrun", labels=labels)
+                anchor = max(due, now)
                 flight_recorder.note_tick(
-                    "wait_ms", (time.perf_counter() - t0) * 1000.0)
+                    "wait_ms", (time.perf_counter() - now) * 1000.0)
+                flight_recorder.note_tick("overrun_ms", late_ms)
                 if not tick(run):
                     return
 

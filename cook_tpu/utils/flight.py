@@ -207,9 +207,9 @@ class CycleRecord:
                  "error", "pipeline_depth", "pipeline_inflight",
                  "pipeline_conflicts", "delta_rows", "full_repacks",
                  "audit_events", "kernel_launches", "path", "shard",
-                 "device", "wait_ms", "gc_ms", "flush_audit_ms",
-                 "cpu_ms", "blocked_ms", "lock_holder", "offcpu_ms",
-                 "background_ms", "staged_tx", "pipeline_lag_ms",
+                 "device", "wait_ms", "overrun_ms", "gc_ms",
+                 "flush_audit_ms", "cpu_ms", "blocked_ms", "lock_holder",
+                 "offcpu_ms", "background_ms", "staged_tx", "pipeline_lag_ms",
                  "_lock_wait_max", "_thread", "_cpu0", "_t0")
 
     def __init__(self, seq: int, kind: str):
@@ -268,11 +268,13 @@ class CycleRecord:
         # in /debug/cycles and the Perfetto export
         self.kernel_launches = 0
         self.path: Optional[str] = None
-        # the tick around the cycle (Scheduler.run's loop): the interval
-        # wait that preceded it, and the idle-point GC and audit flush
+        # the tick around the cycle (Scheduler.run's loop): the wait for
+        # its deadline that preceded it, how far past that deadline it
+        # started (0 = on time), and the idle-point GC and audit flush
         # of the tick before — with duration_ms these reproduce the
         # start-to-start period of consecutive cycles
         self.wait_ms = 0.0
+        self.overrun_ms = 0.0
         self.gc_ms = 0.0
         self.flush_audit_ms = 0.0
         # where the cycle thread's wall time went: on the CPU
@@ -351,6 +353,7 @@ class CycleRecord:
             "shard": self.shard,
             "device": self.device,
             "wait_ms": round(self.wait_ms, 3),
+            "overrun_ms": round(self.overrun_ms, 3),
             "gc_ms": round(self.gc_ms, 3),
             "flush_audit_ms": round(self.flush_audit_ms, 3),
             "cpu_ms": round(self.cpu_ms, 3),
@@ -398,10 +401,8 @@ class FlightRecorder:
                 run = [kind, rec._t0, None]
                 self._background.append(run)
         notes, self._tick.notes = getattr(self._tick, "notes", None), None
-        if notes:
-            rec.wait_ms = notes.get("wait_ms", 0.0)
-            rec.gc_ms = notes.get("gc_ms", 0.0)
-            rec.flush_audit_ms = notes.get("flush_audit_ms", 0.0)
+        for field, ms in (notes or {}).items():
+            setattr(rec, field, ms)     # note_tick's fields are the record's
         token = _current_record.set(rec)
         try:
             with tracing.span("cycle", kind=kind, seq=rec.seq, **tags) as sp:
@@ -531,7 +532,8 @@ class FlightRecorder:
 
     def note_tick(self, field: str, ms: float) -> None:
         """A reading of the tick BETWEEN cycles on this thread —
-        ``wait_ms`` (the interval wait), ``gc_ms`` (the idle-point
+        ``wait_ms`` (the wait for the tick's deadline), ``overrun_ms``
+        (how late against it the tick started), ``gc_ms`` (the idle-point
         collection), ``flush_audit_ms`` — carried onto the next record
         this thread opens (Scheduler.run's loop and step_cycle call
         this; a record's callers never do)."""

@@ -361,6 +361,140 @@ class TestCycleThreadWaits:
         assert doc["staged_tx"] is not None and doc["pipeline_lag_ms"] > 0
 
 
+def overruns(loop):
+    from cook_tpu.utils.metrics import registry
+    return sum(v for labels, v in registry.series("cook_loop_overrun")
+               if labels.get("loop") == loop)
+
+
+class VirtualTime:
+    """``time`` as sched/scheduler.py sees it, with a ``perf_counter``
+    that each thread owns: it moves only when that thread waits on the
+    stop event or a fake tick says so.  The loop under test then runs
+    at full speed and to the microsecond, whatever the machine is doing."""
+
+    def __init__(self):
+        self._local = threading.local()
+
+    def __getattr__(self, name):
+        return getattr(time, name)
+
+    def perf_counter(self):
+        return getattr(self._local, "now", 0.0)
+
+    def advance(self, seconds):
+        self._local.now = self.perf_counter() + seconds
+
+
+class VirtualStop:
+    """Scheduler._stop on that clock: a wait shorter than an hour passes
+    in virtual time; the hour-long waits of the loops not under test
+    block until the stop, as on a real Event."""
+
+    def __init__(self, clock):
+        self._clock = clock
+        self._event = threading.Event()
+        self.set = self._event.set
+        self.is_set = self._event.is_set
+
+    def wait(self, timeout=None):
+        if timeout is None or timeout >= 3600.0:
+            return self._event.wait(timeout)
+        self._clock.advance(timeout)
+        return self._event.is_set()
+
+
+class FakeTicks:
+    """A jax-free scheduler whose loop ``loop`` runs a fake tick that
+    takes ``durations[i]`` (the last one from then on) and is a flight
+    record of the loop's kind.  Every other loop's interval is an hour.
+    With a ``monkeypatch`` the loops run on virtual time."""
+
+    INTERVAL_FIELD = {"cycle": "match_interval_seconds",
+                      "reapers": "lingering_task_interval_seconds",
+                      "monitor": "monitor_interval_seconds"}
+
+    def __init__(self, interval, durations, loop="cycle", monkeypatch=None):
+        from cook_tpu.sched import scheduler as scheduler_mod
+        cfg = Config()
+        cfg.cycle_mode = "split"
+        cfg.default_matcher.backend = "cpu"
+        cfg.rank_interval_seconds = 3600.0
+        for field in self.INTERVAL_FIELD.values():
+            setattr(cfg, field, 3600.0)
+        if loop in self.INTERVAL_FIELD:
+            setattr(cfg, self.INTERVAL_FIELD[loop], interval)
+        store = Store()
+        store.put_pool(Pool(name="default"))
+        host = FakeHost(hostname="h0", capacity=Resources(cpus=1.0, mem=64.0))
+        self.sched = Scheduler(store, cfg, [FakeCluster("fake-1", [host])],
+                               rank_backend="cpu")
+        # the rebalancer's interval is the one that is a callable
+        self.params = type("Params", (), {"interval_seconds": 3600.0})()
+        self.sched.rebalancer.effective_params = lambda: self.params
+        self.clock = time
+        if monkeypatch is not None:
+            self.clock = VirtualTime()
+            monkeypatch.setattr(scheduler_mod, "time", self.clock)
+            self.sched._stop = VirtualStop(self.clock)
+        self.loop = loop
+        self.durations = list(durations)
+        self.starts = []
+        self.stop_after = None
+        self.done = threading.Event()
+        self.since = recorder.last_seq()
+        self.overruns_before = overruns(loop)
+        # a background loop's record (kind = the loop's name) is opened
+        # by Scheduler._background_tick around the tick
+        self.kind = "match" if loop == "cycle" else loop
+        if loop == "cycle":
+            self.sched.step_match = self.cycle_tick
+        elif loop == "reapers":
+            self.sched.step_reapers = self.tick
+        elif loop == "monitor":
+            self.sched.monitor.sweep = self.tick
+        elif loop == "rebalance":
+            self.params.interval_seconds = interval
+            self.sched.step_rebalance = self.tick
+        else:       # the optimizer's loop exists only where configured
+            from cook_tpu.sched import OptimizerConfig
+            cfg.optimizer = OptimizerConfig(interval_seconds=interval)
+            self.sched.step_optimize = self.tick
+
+    def tick(self):
+        i = len(self.starts)
+        self.starts.append(self.clock.perf_counter())
+        took = self.durations[min(i, len(self.durations) - 1)]
+        if self.clock is time:
+            time.sleep(took)
+        else:
+            self.clock.advance(took)
+        if len(self.starts) == self.stop_after:
+            self.sched._stop.set()
+            self.done.set()
+
+    def cycle_tick(self):
+        with recorder.cycle(kind=self.kind):
+            self.tick()
+
+    def run(self, ticks):
+        """``ticks`` ticks, the last of which stops the loops; the flight
+        records of this loop's ticks, oldest first."""
+        self.stop_after = ticks
+        self.sched.run()
+        try:
+            assert self.done.wait(20.0)
+        finally:
+            self.sched.shutdown()
+        assert len(self.starts) == ticks
+        assert not any(t.is_alive() for t in self.sched._threads)
+        return [d for d in recorder.recent(512)
+                if d["seq"] > self.since and d["kind"] == self.kind]
+
+    def overruns(self):
+        return overruns(self.loop) - self.overruns_before
+
+
 class TestTheTick:
     def test_run_loop_fills_the_tick_fields_and_they_give_the_period(self):
         _store, sched = build(n_jobs=30, n_hosts=2,
@@ -377,7 +511,7 @@ class TestTheTick:
             deadline = time.time() + 20
             while time.time() < deadline:
                 docs = [d for d in recorder.recent(512) if d["seq"] > since]
-                if len([d for d in docs if d["kind"] == "fused"]) >= 6 \
+                if len([d for d in docs if d["kind"] == "fused"]) >= 8 \
                         and any(d["kind"] == "reapers" for d in docs):
                     break
                 time.sleep(0.05)
@@ -395,17 +529,112 @@ class TestTheTick:
             sched.shutdown()
         docs = [d for d in recorder.recent(512) if d["seq"] > since]
         fused = [d for d in docs if d["kind"] == "fused"]
-        assert len(fused) >= 6
+        assert len(fused) >= 8
+        periods = []
         for prev, cur in zip(fused[1:], fused[2:]):
-            assert cur["wait_ms"] >= 45.0
             period = (cur["start"] - prev["start"]) * 1000.0
             told = prev["duration_ms"] + cur["flush_audit_ms"] \
                 + cur["gc_ms"] + cur["wait_ms"]
             assert abs(period - told) < 10.0, (period, told)
+            if not cur["overrun_ms"]:
+                periods.append(period)
+        # these cycles take a few ms: start to start is the interval
+        assert len(periods) >= 4
+        periods.sort()
+        assert abs(periods[len(periods) // 2] - 50.0) < 10.0, periods
         assert any(d["gc_ms"] > 0 for d in fused)   # the first cycle's
         reap = [d for d in docs if d["kind"] == "reapers"]
         assert reap and reap[0]["wait_ms"] >= 140.0
         assert all(d["kind"] != "monitor" for d in docs)
+
+    def test_a_short_tick_is_followed_by_the_rest_of_the_interval(
+            self, monkeypatch):
+        fake = FakeTicks(1.0, [0.3], monkeypatch=monkeypatch)
+        docs = fake.run(5)
+        # start to start is the interval; interval + tick would be 1.3
+        assert fake.starts == pytest.approx([1.0, 2.0, 3.0, 4.0, 5.0])
+        assert [d["wait_ms"] for d in docs] \
+            == pytest.approx([1000.0] + [700.0] * 4)
+        assert [d["overrun_ms"] for d in docs] == [0.0] * 5
+        assert fake.overruns() == 0
+
+    def test_a_tick_longer_than_the_interval_is_followed_at_once(
+            self, monkeypatch):
+        fake = FakeTicks(1.0, [1.5], monkeypatch=monkeypatch)
+        docs = fake.run(4)
+        assert fake.starts == pytest.approx([1.0, 2.5, 4.0, 5.5])
+        assert [d["wait_ms"] for d in docs] == [1000.0, 0.0, 0.0, 0.0]
+        assert [d["overrun_ms"] for d in docs] \
+            == pytest.approx([0.0, 500.0, 500.0, 500.0])
+        assert fake.overruns() == 3
+
+    @pytest.mark.parametrize("loop", ["cycle", "reapers", "monitor"])
+    def test_one_long_tick_costs_one_immediate_tick_and_no_burst(
+            self, monkeypatch, loop):
+        fake = FakeTicks(1.0, [0.1, 0.1, 2.5, 0.1], loop=loop,
+                         monkeypatch=monkeypatch)
+        docs = fake.run(7)
+        # the tick after the 2.5 s one starts as it ends, 1.5 s late, and
+        # the schedule goes on from THERE: catching up with the old grid
+        # would put ticks at 5.5, 5.6, 5.7 and 6.0
+        assert fake.starts == pytest.approx(
+            [1.0, 2.0, 3.0, 5.5, 6.5, 7.5, 8.5])
+        assert [d["overrun_ms"] for d in docs] \
+            == pytest.approx([0.0, 0.0, 0.0, 1500.0, 0.0, 0.0, 0.0])
+        assert [d["wait_ms"] for d in docs] == pytest.approx(
+            [1000.0, 900.0, 900.0, 0.0, 900.0, 900.0, 900.0])
+        assert fake.overruns() == 1
+
+    def test_a_callable_interval_that_changes_takes_effect_next_turn(
+            self, monkeypatch):
+        fake = FakeTicks(1.0, [0.1], loop="rebalance",
+                         monkeypatch=monkeypatch)
+        tick = fake.sched.step_rebalance
+
+        def rebalance():
+            tick()
+            if len(fake.starts) == 3:
+                fake.params.interval_seconds = 5.0
+        fake.sched.step_rebalance = rebalance
+        fake.run(5)
+        assert fake.starts == pytest.approx([1.0, 2.0, 3.0, 8.0, 13.0])
+
+    def test_an_immediate_loop_ticks_at_once_and_then_on_the_period(
+            self, monkeypatch):
+        fake = FakeTicks(1.0, [0.4], loop="optimize",
+                         monkeypatch=monkeypatch)
+        docs = fake.run(3)
+        assert fake.starts == pytest.approx([0.0, 1.0, 2.0])
+        assert [d["wait_ms"] for d in docs] \
+            == pytest.approx([0.0, 600.0, 600.0])
+
+    @pytest.mark.parametrize("interval, tick_s", [(0.0005, 0.02),
+                                                  (3600.0, 0.0)],
+                             ids=["zero-wait", "long-wait"])
+    def test_shutdown_returns_promptly(self, interval, tick_s):
+        fake = FakeTicks(interval, [tick_s])
+        fake.sched.run()
+        if interval < 1.0:                  # in a tick or a zero wait
+            deadline = time.time() + 20
+            while len(fake.starts) < 3 and time.time() < deadline:
+                time.sleep(0.005)
+            assert len(fake.starts) >= 3
+        else:
+            time.sleep(0.05)                # deep in the first wait
+        t0 = time.perf_counter()
+        fake.sched.shutdown()
+        assert time.perf_counter() - t0 < 2.0
+        assert not any(t.is_alive() for t in fake.sched._threads)
+        n = len(fake.starts)
+        time.sleep(0.05)
+        assert len(fake.starts) == n
+
+    def test_a_background_loops_first_run_is_one_interval_after_start(self):
+        fake = FakeTicks(0.15, [0.0], loop="reapers")
+        docs = fake.run(1)
+        first = (docs[0]["start"] - fake.sched.started_s) * 1000.0
+        assert first >= 145.0, first
+        assert docs[0]["wait_ms"] >= 140.0 and docs[0]["overrun_ms"] == 0.0
 
     def test_health_serves_the_loop_threads_start(self):
         from cook_tpu.rest import ApiServer, CookApi
@@ -573,7 +802,8 @@ def test_names_the_yardstick_reads_are_stable():
     doc = flight.CycleRecord(1, "fused").to_doc()
     for field in ("kind", "start", "duration_ms", "detail_ms",
                   "sync_wait_ms", "h2d_bytes", "path", "error", "faults",
-                  "delta_rows", "wait_ms", "gc_ms", "flush_audit_ms",
+                  "delta_rows", "wait_ms", "overrun_ms", "gc_ms",
+                  "flush_audit_ms",
                   "blocked_ms", "offcpu_ms", "background_ms",
                   "pipeline_lag_ms", "staged_tx", "cpu_ms", "lock_holder"):
         assert field in doc, field
