@@ -519,9 +519,10 @@ class PipelineConfig:
     #: boot-time warmup sweep: pre-compile (and execute once, with
     #: zeroed inputs) the compact fused cycle at the bucket grid implied
     #: by these design points.  0 disables warmup.  ``warmup_tasks`` /
-    #: ``warmup_hosts`` are the expected steady-state maxima (padded up
-    #: to their power-of-two buckets, ops/padding.py); ``warmup_users``
-    #: sizes the per-user table bucket (minimum 8).
+    #: ``warmup_hosts`` are ONE pool's expected steady-state maxima
+    #: (padded up to their power-of-two buckets, ops/padding.py; how
+    #: many pools a dispatch stacks is read off the store at takeover);
+    #: ``warmup_users`` sizes the per-user table bucket (minimum 8).
     warmup_tasks: int = 0
     warmup_hosts: int = 0
     warmup_users: int = 8

@@ -204,6 +204,30 @@ class PackDeltaApplier:
         return self.commit(rows_dev, flags_dev, st)
 
 
+# chunk appends to the base mirror are padded to power-of-two buckets
+# from this floor up (one executable per capacity and bucket)
+APPEND_MIN_BUCKET = 1024
+
+_append_fn = None
+
+
+def append_chunk(base, chunk, off):
+    """Donating chunk append into a base-mirror column: ONE jitted
+    entry point for every mirror of the process (jit caches one
+    executable per shape), so a mirror rebuilt after a device fault —
+    and the boot warm-up — reuse the same executables."""
+    global _append_fn
+    if _append_fn is None:
+        import jax
+        from jax import lax
+        _append_fn = telemetry.instrument_jit(
+            "delta.append", jax.jit(
+                lambda b, c, o: lax.dynamic_update_slice(
+                    b, c, (o,) + (0,) * (c.ndim - 1)),
+                donate_argnums=0))
+    return _append_fn(base, chunk, off)
+
+
 class DeviceBaseMirror:
     """Device-resident mirror of the columnar index's immutable res/disk
     base columns: rows are append-only while the compaction epoch is
@@ -213,28 +237,27 @@ class DeviceBaseMirror:
     the columnar rank path."""
 
     def __init__(self):
+        self._floor = 0                   # least capacity of an upload
+        self.reset()
+
+    def reset(self) -> None:
+        """Let go of the device buffers (the next sync uploads whole);
+        a reserved capacity stays."""
         self._key: Optional[int] = None   # compaction epoch mirrored
         self._n = 0                       # rows synced
         self._cap = 0                     # device buffer capacity
         self._res = None                  # f32[cap, 4] on device
         self._disk = None                 # f32[cap] on device
-        self._append_fn = None            # shared jitted chunk append
-
-    def _append(self, base, chunk, off):
-        """Donating chunk append (jit caches one executable per shape)."""
-        if self._append_fn is None:
-            import jax
-            from jax import lax
-            self._append_fn = telemetry.instrument_jit(
-                "delta.append", jax.jit(
-                    lambda b, c, o: lax.dynamic_update_slice(
-                        b, c, (o,) + (0,) * (c.ndim - 1)),
-                    donate_argnums=0))
-        return self._append_fn(base, chunk, off)
 
     @property
     def capacity(self) -> int:
         return self._cap
+
+    def reserve(self, capacity: int) -> None:
+        """No (re)upload allocates less than ``capacity`` rows from now
+        on: the capacity is a shape of every kernel the mirror feeds,
+        so a warm-up that compiled for one pins it here."""
+        self._floor = max(self._floor, int(capacity))
 
     def sync(self, res_base: np.ndarray, disk_base: np.ndarray,
              compactions: int):
@@ -247,7 +270,7 @@ class DeviceBaseMirror:
         full = (self._key != compactions or n > self._cap)
         if not full and n > self._n:
             k = n - self._n
-            kb = bucket(k, minimum=1024)
+            kb = bucket(k, minimum=APPEND_MIN_BUCKET)
             if self._n + kb > self._cap:
                 full = True  # dynamic_update_slice would clamp, not grow
             else:
@@ -258,12 +281,12 @@ class DeviceBaseMirror:
                 off = jnp.asarray(self._n, dtype=jnp.int32)
                 telemetry.count_transfer("h2d",
                                          chunk.nbytes + dchunk.nbytes)
-                self._res = self._append(self._res, jnp.asarray(chunk), off)
-                self._disk = self._append(self._disk, jnp.asarray(dchunk),
+                self._res = append_chunk(self._res, jnp.asarray(chunk), off)
+                self._disk = append_chunk(self._disk, jnp.asarray(dchunk),
                                           off)
                 self._n = n
         if full:
-            cap = bucket(n, minimum=1024)
+            cap = max(bucket(n, minimum=1024), self._floor)
             res_p = np.zeros((cap, 4), dtype=F32)
             res_p[:n] = res_base
             disk_p = np.zeros(cap, dtype=F32)

@@ -59,6 +59,15 @@ F32 = np.float32
 INF = float("inf")
 
 
+def _count_warmup(kernel: str, runs: int) -> None:
+    """``cook_warmup_executions_total{kernel}``: what the boot warm-up
+    ran, by kernel."""
+    if runs:
+        from ..utils.metrics import registry
+        registry.counter_inc("cook_warmup_executions", float(runs),
+                             {"kernel": kernel})
+
+
 class _PackedPool:
     """Host-side staging for one pool's cycle inputs."""
 
@@ -276,22 +285,52 @@ class FusedCycleDriver:
             self._cycles[key] = fn
         return fn
 
+    # ------------------------------------------------------ dispatch groups
+    def _cycle_pools(self) -> List[Pool]:
+        """The pools a cycle packs: active and not direct."""
+        return [p for p in self.store.pools()
+                if p.state == "active"
+                and p.scheduler is not SchedulerKind.DIRECT]
+
+    def _stacked(self, n_pools: int) -> int:
+        """P of a dispatch that stacks ``n_pools``: padded to a whole
+        number of pools per mesh device."""
+        n_dev = self.mesh().size
+        return max(n_dev, -(-n_pools // n_dev) * n_dev)
+
+    def dispatch_groups(self) -> Dict[bool, int]:
+        """DRU mode (gpu?) -> how many pools one dispatch of that mode
+        stacks when every pool has pending work, by the rule
+        :meth:`stage` groups by.  What the warm-up has to cover."""
+        groups: Dict[bool, int] = {}
+        for p in self._cycle_pools():
+            gm = p.dru_mode is DruMode.GPU
+            groups[gm] = groups.get(gm, 0) + 1
+        return groups
+
     # --------------------------------------------------------------- warmup
     def warmup(self, *, tasks: int, hosts: int, users: int = 8,
                sweep: bool = False, gpu: bool = False) -> int:
         """Boot-time cold-start killer (config.PipelineConfig): compile
-        AND execute once, with zeroed inputs, the compact fused cycle at
-        the bucket grid the configured design point implies, so the
-        first-call compile spikes land at boot — inside
+        AND execute once, with zeroed inputs, what the daemon will really
+        dispatch, so the first-call compile spikes land at boot — inside
         the leader's takeover window — and never inside a live cycle.
         Executing (not just AOT-lowering) populates the jit call cache,
         so steady-state cycles at warmed shapes trace zero times; with
         the persistent compilation cache enabled the XLA compile itself
         is also disk-cached across restarts.
 
+        ``tasks`` / ``hosts`` / ``users`` are the design point of ONE
+        pool.  How many pools a dispatch stacks is read off the store
+        (:meth:`dispatch_groups`; an empty store warms the one default
+        pool to come), and the device base mirror is sized for every
+        pool's rows, so a several-pool deployment warms the stacked
+        [P, T] cycle, its delta scatters and the mirror's chunk appends
+        — not the one-pool shapes it never runs.
+
         ``sweep=True`` warms every (T, H) bucket up to the targets (ramp
         traffic hits warm executables at every scale), else just the
-        target buckets.  Returns the number of warmup executions."""
+        target buckets.  Returns the number of cycle executions."""
         if tasks <= 0 or hosts <= 0:
             return 0
         if not self.config.columnar_index:
@@ -305,10 +344,6 @@ class FusedCycleDriver:
                 "the dense PoolCycleInputs variant, which warmup does "
                 "not cover")
             return 0
-        import jax
-        import jax.numpy as jnp
-
-        from ..parallel.sharded import CompactPoolCycleInputs
 
         def grid(n: int, minimum: int = 64) -> List[int]:
             top = bucket(n, minimum=minimum)
@@ -320,8 +355,40 @@ class FusedCycleDriver:
                 b *= 2
             return out
 
-        P = self.mesh().size
+        groups = self.dispatch_groups() or {False: 1}
+        if gpu:
+            groups.setdefault(True, 1)
+        # the base mirror holds the index's rows of EVERY pool: sized for
+        # the design point of all of them, or the rows the index already
+        # has if those are more; the live mirror keeps this floor, so its
+        # capacity (a shape of the cycle) is the warmed one from the
+        # first cycle on
+        base_rows = self.store.ensure_index().row_count()
+        T_top = bucket(tasks)
+        mir = bucket(max(T_top * sum(groups.values()), base_rows),
+                     minimum=1024)
+        self._mirror.reserve(mir)
         U = bucket(max(users, 1), minimum=8)
+        runs = 0
+        for gm, n_pools in sorted(groups.items()):
+            P = self._stacked(n_pools)
+            for T in grid(tasks):
+                for H in grid(hosts):
+                    runs += self._warm_cycle(gm, P, T, H, U, mir)
+                if self.config.resident_pack:
+                    self._warm_delta_apply(P, T)
+        self._warm_delta_append(mir, room=mir - base_rows, design=T_top)
+        return runs
+
+    def _warm_cycle(self, gm: bool, P: int, T: int, H: int, U: int,
+                    mir: int) -> int:
+        """One zero-world execution of the compact fused cycle (and of
+        the megakernel where a pool pins it) per distinct cap bucket at
+        [P, T] x H; returns the executions."""
+        import jax
+        import jax.numpy as jnp
+
+        from ..parallel.sharded import CompactPoolCycleInputs
         E = 8  # exception bucket floor: no complex jobs in the zero world
         # the dispatch cap is bucket(max matcher cap over the group's
         # pools); pool_matchers overrides can bucket differently from the
@@ -329,134 +396,156 @@ class FusedCycleDriver:
         caps = {bucket(self.config.default_matcher.max_jobs_considered)}
         caps.update(bucket(mc.max_jobs_considered)
                     for _rx, mc in self.config.pool_matchers)
+        caps = sorted({min(c, T) for c in caps})
         f32, i32 = jnp.float32, jnp.int32
         runs = 0
-        for gm in ((False, True) if gpu else (False,)):
-            for T in grid(tasks):
-                # the device base mirror's capacity bucket tracks the
-                # index row count (~T at one pool per index row)
-                mir = bucket(T, minimum=1024)
-                res_base = jnp.zeros((mir, 4), dtype=f32)
-                disk_base = jnp.zeros(mir, dtype=f32)
-                for H in grid(hosts):
-                    inp = CompactPoolCycleInputs(
-                        rows=jnp.zeros((P, T), dtype=i32),
-                        flags=jnp.zeros((P, T), dtype=jnp.uint8),
-                        res_base=res_base,
-                        disk_base=disk_base,
-                        tokens_u=jnp.full((P, U), jnp.inf, dtype=f32),
-                        shares_u=jnp.full((P, U, 3), jnp.inf, dtype=f32),
-                        quota_u=jnp.full((P, U, 4), jnp.inf, dtype=f32),
-                        num_considerable=jnp.zeros((P,), dtype=i32),
-                        pool_quota=jnp.full((P, 4), jnp.inf, dtype=f32),
-                        group_quota=jnp.full((P, 4), jnp.inf, dtype=f32),
-                        group_id=jnp.full((P,), -1, dtype=i32),
-                        host_gpu=jnp.zeros((P, H), dtype=bool),
-                        host_blocked=jnp.ones((P, H), dtype=bool),
-                        exc_rows=jnp.full((P, E), -1, dtype=i32),
-                        exc_mask=jnp.zeros((P, E, H), dtype=bool),
-                        avail=jnp.zeros((P, H, 4), dtype=f32),
-                        capacity=jnp.zeros((P, H, 4), dtype=f32))
-                    for cap in sorted({min(c, T) for c in caps}):
-                        fn = self._cycle_fn(gm, cap, True, compact=True)
-                        jax.block_until_ready(fn(inp).n_queue)
-                        runs += 1
-                    mega_backends = {self.config.default_matcher.backend}
-                    mega_backends.update(
-                        mc.backend for _rx, mc in self.config.pool_matchers)
-                    if self.mesh().size == 1 \
-                            and "tpu-megakernel" in mega_backends:
-                        # warm the MEGAKERNEL executables too (the live
-                        # path for a pinned pool): wide rows for the
-                        # resident wire, i8-delta for the quantized
-                        # rebuild norm.  Residual cold traces remain for
-                        # the first negotiated fixed-point scale tuple
-                        # and the first gang-bearing bucket — sticky
-                        # scales make each a one-time cost.
-                        from ..ops import pallas_cycle
-                        from ..ops import quant as _quant
-                        gang = pallas_cycle.empty_gang_wire(P, T, H)
-                        host_bits = jnp.zeros((P, 2, (H + 7) // 8),
-                                              dtype=jnp.uint8)
-                        codecs = [(jnp.int32, _quant.ROWS_WIDE)]
-                        if self.config.quantized_wire:
-                            codecs.append((jnp.int8, _quant.ROWS_I8))
-                        for rdt, rcodec in codecs:
-                            wire = pallas_cycle.MegaCycleWire(
-                                rows=jnp.zeros((P, T), dtype=rdt),
-                                flags=inp.flags, res_base=inp.res_base,
-                                disk_base=inp.disk_base,
-                                tokens_u=inp.tokens_u,
-                                shares_u=inp.shares_u,
-                                quota_u=inp.quota_u,
-                                num_considerable=inp.num_considerable,
-                                pool_quota=inp.pool_quota,
-                                group_quota=inp.group_quota,
-                                group_id=inp.group_id,
-                                host_bits=host_bits,
-                                exc_rows=inp.exc_rows,
-                                exc_mask=inp.exc_mask,
-                                avail=inp.avail, capacity=inp.capacity,
-                                gang_id=jnp.asarray(gang[0]),
-                                gang_size=jnp.asarray(gang[1]),
-                                gang_attr=jnp.asarray(gang[2]),
-                                host_topo=jnp.asarray(gang[3]))
-                            for cap in sorted({min(c, T) for c in caps}):
-                                jax.block_until_ready(
-                                    pallas_cycle.megacycle(
-                                        wire, gpu_mode=gm,
-                                        max_over_quota_jobs=self.config
-                                        .max_over_quota_jobs,
-                                        considerable_cap=cap,
-                                        rows_codec=rcodec).n_queue)
-                                runs += 1
-                if self.config.resident_pack:
-                    # the resident pack's delta scatter compiles once per
-                    # (buffer shape+sharding, delta bucket): warm every
-                    # bucket up to the buffer size so a steady-state
-                    # delta never traces inside a live cycle (the
-                    # zero-recompile guarantee the warmup assertion
-                    # protects).  The warm buffers must carry the SAME
-                    # placement as the live resident buffers — jit keys
-                    # executables on input sharding, so an unsharded warm
-                    # pass would leave the sharded variant cold
-                    from ..ops.delta import _DELTA_MIN_BUCKET
-                    n_flat = P * T
-                    kbs, k = set(), _DELTA_MIN_BUCKET
-                    while k < n_flat:
-                        kbs.add(k)
-                        k *= 2
-                    kbs.add(n_flat)  # the clamped top bucket
-                    if self.mesh().size > 1:
-                        from ..parallel.mesh import pool_sharding
-                        sh = pool_sharding(self.mesh())
-                        rows_b = jax.device_put(
-                            np.zeros((P, T), dtype=np.int32), sh)
-                        flags_b = jax.device_put(
-                            np.zeros((P, T), dtype=np.uint8), sh)
-                    else:
-                        rows_b = jnp.zeros((P, T), dtype=i32)
-                        flags_b = jnp.zeros((P, T), dtype=jnp.uint8)
-                    for k in sorted(kbs):
-                        idx = np.full(k, n_flat, dtype=np.int32)  # no-op
-                        rows_b, flags_b = self._applier.apply(
-                            rows_b, flags_b, idx,
-                            np.zeros(k, dtype=np.int32),
-                            np.zeros(k, dtype=np.uint8))
-                        if self.config.quantized_wire:
-                            # warm the narrow value codecs too (i8 via
-                            # zero deltas, i16 via an out-of-i8 delta):
-                            # all-sentinel indices make them no-op
-                            # scatters, so the buffers stay zeros
-                            for vals in (np.zeros(k, dtype=np.int32),
-                                         np.full(k, 1000,
-                                                 dtype=np.int32)):
-                                rows_b, flags_b = self._applier.apply(
-                                    rows_b, flags_b, idx, vals,
-                                    np.zeros(k, dtype=np.uint8),
-                                    quantize=True)
-                    jax.block_until_ready(rows_b)
+        with tracing.span("warmup.cycle", P=P, T=T, H=H, gpu=gm) as sp:
+            inp = CompactPoolCycleInputs(
+                rows=jnp.zeros((P, T), dtype=i32),
+                flags=jnp.zeros((P, T), dtype=jnp.uint8),
+                res_base=jnp.zeros((mir, 4), dtype=f32),
+                disk_base=jnp.zeros(mir, dtype=f32),
+                tokens_u=jnp.full((P, U), jnp.inf, dtype=f32),
+                shares_u=jnp.full((P, U, 3), jnp.inf, dtype=f32),
+                quota_u=jnp.full((P, U, 4), jnp.inf, dtype=f32),
+                num_considerable=jnp.zeros((P,), dtype=i32),
+                pool_quota=jnp.full((P, 4), jnp.inf, dtype=f32),
+                group_quota=jnp.full((P, 4), jnp.inf, dtype=f32),
+                group_id=jnp.full((P,), -1, dtype=i32),
+                host_gpu=jnp.zeros((P, H), dtype=bool),
+                host_blocked=jnp.ones((P, H), dtype=bool),
+                exc_rows=jnp.full((P, E), -1, dtype=i32),
+                exc_mask=jnp.zeros((P, E, H), dtype=bool),
+                avail=jnp.zeros((P, H, 4), dtype=f32),
+                capacity=jnp.zeros((P, H, 4), dtype=f32))
+            for cap in caps:
+                fn = self._cycle_fn(gm, cap, True, compact=True)
+                jax.block_until_ready(fn(inp).n_queue)
+                runs += 1
+            _count_warmup("fused.pool_cycle", runs)
+            mega_backends = {self.config.default_matcher.backend}
+            mega_backends.update(
+                mc.backend for _rx, mc in self.config.pool_matchers)
+            if self.mesh().size == 1 and "tpu-megakernel" in mega_backends:
+                # warm the MEGAKERNEL executables too (the live path for
+                # a pinned pool): wide rows for the resident wire,
+                # i8-delta for the quantized rebuild norm.  Residual cold
+                # traces remain for the first negotiated fixed-point
+                # scale tuple and the first gang-bearing bucket — sticky
+                # scales make each a one-time cost.
+                from ..ops import pallas_cycle
+                from ..ops import quant as _quant
+                gang = pallas_cycle.empty_gang_wire(P, T, H)
+                host_bits = jnp.zeros((P, 2, (H + 7) // 8),
+                                      dtype=jnp.uint8)
+                codecs = [(jnp.int32, _quant.ROWS_WIDE)]
+                if self.config.quantized_wire:
+                    codecs.append((jnp.int8, _quant.ROWS_I8))
+                mega_runs = 0
+                for rdt, rcodec in codecs:
+                    wire = pallas_cycle.MegaCycleWire(
+                        rows=jnp.zeros((P, T), dtype=rdt),
+                        flags=inp.flags, res_base=inp.res_base,
+                        disk_base=inp.disk_base, tokens_u=inp.tokens_u,
+                        shares_u=inp.shares_u, quota_u=inp.quota_u,
+                        num_considerable=inp.num_considerable,
+                        pool_quota=inp.pool_quota,
+                        group_quota=inp.group_quota,
+                        group_id=inp.group_id, host_bits=host_bits,
+                        exc_rows=inp.exc_rows, exc_mask=inp.exc_mask,
+                        avail=inp.avail, capacity=inp.capacity,
+                        gang_id=jnp.asarray(gang[0]),
+                        gang_size=jnp.asarray(gang[1]),
+                        gang_attr=jnp.asarray(gang[2]),
+                        host_topo=jnp.asarray(gang[3]))
+                    for cap in caps:
+                        jax.block_until_ready(pallas_cycle.megacycle(
+                            wire, gpu_mode=gm,
+                            max_over_quota_jobs=self.config
+                            .max_over_quota_jobs,
+                            considerable_cap=cap,
+                            rows_codec=rcodec).n_queue)
+                        mega_runs += 1
+                _count_warmup("pallas.megacycle", mega_runs)
+                runs += mega_runs
+            sp.set_tag("runs", runs)
         return runs
+
+    def _warm_delta_apply(self, P: int, T: int) -> None:
+        """The resident pack's delta scatter compiles once per (buffer
+        shape+sharding, delta bucket, value codec): warm every bucket up
+        to the [P, T] buffer's size so a steady-state delta never traces
+        inside a live cycle (the zero-recompile guarantee the warmup
+        assertion protects).  The warm buffers must carry the SAME
+        placement as the live resident buffers — jit keys executables on
+        input sharding, so an unsharded warm pass would leave the
+        sharded variant cold."""
+        import jax
+        import jax.numpy as jnp
+
+        from ..ops.delta import _DELTA_MIN_BUCKET
+        n_flat = P * T
+        kbs, k = set(), _DELTA_MIN_BUCKET
+        while k < n_flat:
+            kbs.add(k)
+            k *= 2
+        kbs.add(n_flat)  # the clamped top bucket
+        with tracing.span("warmup.delta_apply", P=P, T=T) as sp:
+            if self.mesh().size > 1:
+                from ..parallel.mesh import pool_sharding
+                sh = pool_sharding(self.mesh())
+                rows_b = jax.device_put(np.zeros((P, T), dtype=np.int32), sh)
+                flags_b = jax.device_put(np.zeros((P, T), dtype=np.uint8),
+                                         sh)
+            else:
+                rows_b = jnp.zeros((P, T), dtype=jnp.int32)
+                flags_b = jnp.zeros((P, T), dtype=jnp.uint8)
+            # all-sentinel indices make every scatter a no-op, so the
+            # buffers stay zeros; with the quantized wire the narrow
+            # value codecs are warmed too (i8 via zero deltas, i16 via an
+            # out-of-i8 delta)
+            variants = [(0, False)]
+            if self.config.quantized_wire:
+                variants += [(0, True), (1000, True)]
+            runs = 0
+            for k in sorted(kbs):
+                idx = np.full(k, n_flat, dtype=np.int32)
+                for val, quantize in variants:
+                    rows_b, flags_b = self._applier.apply(
+                        rows_b, flags_b, idx,
+                        np.full(k, val, dtype=np.int32),
+                        np.zeros(k, dtype=np.uint8), quantize=quantize)
+                    runs += 1
+            jax.block_until_ready(rows_b)
+            _count_warmup("delta.apply", runs)
+            sp.set_tag("runs", runs)
+
+    def _warm_delta_append(self, mir: int, room: int, design: int) -> None:
+        """Arrivals append rows to the resident base mirror in bucketed
+        chunks (ops/delta.DeviceBaseMirror.sync), one executable per
+        (capacity, chunk bucket) and column: warm every chunk bucket up
+        to one pool's design point (the least bucket at least), as far
+        as the mirror has ``room`` (a chunk beyond it re-uploads
+        instead)."""
+        import jax
+        import jax.numpy as jnp
+
+        from ..ops.delta import APPEND_MIN_BUCKET, append_chunk
+        with tracing.span("warmup.delta_append", rows=mir) as sp:
+            res = jnp.zeros((mir, 4), dtype=jnp.float32)
+            disk = jnp.zeros(mir, dtype=jnp.float32)
+            off = jnp.asarray(0, dtype=jnp.int32)
+            runs, kb = 0, APPEND_MIN_BUCKET
+            while kb <= min(max(design, APPEND_MIN_BUCKET), room):
+                res = append_chunk(
+                    res, jnp.zeros((kb, 4), dtype=jnp.float32), off)
+                disk = append_chunk(
+                    disk, jnp.zeros(kb, dtype=jnp.float32), off)
+                runs += 2
+                kb *= 2
+            jax.block_until_ready((res, disk))
+            _count_warmup("delta.append", runs)
+            sp.set_tag("runs", runs)
 
     # ---------------------------------------------------------- base mirror
     def _sync_base_mirror(self, res_base: np.ndarray, disk_base: np.ndarray,
@@ -477,10 +566,9 @@ class FusedCycleDriver:
         key would otherwise keep handing them out forever.  Safe at any
         time — residency is a pure mirror of what the next full pack
         would build."""
-        from ..ops.delta import DeviceBaseMirror
         self._resident.clear()
         self._pack_cache.clear()
-        self._mirror = DeviceBaseMirror()
+        self._mirror.reset()
 
     def _sync_resident(self, gpu_mode: bool, key: Tuple, rows_p: np.ndarray,
                        flags_p: np.ndarray, epoch: int):
@@ -1355,9 +1443,7 @@ class FusedCycleDriver:
         # has a span (and a detail_ms key, "pools") of its own — outside
         # fused.pack, which times what it always timed
         with tracing.span("fused.pools"):
-            pools = [p for p in self.store.pools()
-                     if p.state == "active"
-                     and p.scheduler is not SchedulerKind.DIRECT]
+            pools = self._cycle_pools()
         packed: List[_PackedPool] = []
         excl = exclude or {}
         tokd = token_delta or {}
@@ -1457,10 +1543,9 @@ class FusedCycleDriver:
             pp.group_id = gids.setdefault(gname, len(gids))
             pp.group_quota = (pp.group_quota
                               - missing_usage(gname)).astype(F32)
-        n_dev = self.mesh().size
         T = bucket(max(pp.n_tasks for pp in group))
         H = bucket(max(max(pp.n_hosts, 1) for pp in group))
-        P = max(n_dev, ((len(group) + n_dev - 1) // n_dev) * n_dev)
+        P = self._stacked(len(group))
 
         def stack(fn, fill=0, dtype=None):
             rows = [fn(pp) for pp in group]

@@ -22,27 +22,39 @@ perf PR is judged against (docs/OBSERVABILITY.md).
 
 from __future__ import annotations
 
+from collections import defaultdict
 from typing import Dict, List, Optional, Set, Tuple
+
+import numpy as np
 
 from ..config import Config, SloConfig
 from ..state.store import Store
+from ..utils import tracing
+from ..utils.pacing import Pacer
 from ..utils.metrics import LATENCY_BUCKETS, MetricsRegistry
 from ..utils.metrics import registry as default_registry
 
 _STAT_DIMS = ("cpus", "mem", "jobs")
 
 
-def _job_stats(jobs_with_user: List[Tuple[str, float, float]]
-               ) -> Dict[str, Dict[str, float]]:
-    """[(user, cpus, mem)] -> user -> {cpus, mem, jobs} (reference:
-    get-job-stats monitor.clj:40-57)."""
-    stats: Dict[str, Dict[str, float]] = {}
-    for user, cpus, mem in jobs_with_user:
-        s = stats.setdefault(user, {"cpus": 0.0, "mem": 0.0, "jobs": 0.0})
-        s["cpus"] += cpus
-        s["mem"] += mem
-        s["jobs"] += 1
-    return stats
+def _job_stats(jobs) -> Dict[str, Dict[str, float]]:
+    """jobs -> user -> {cpus, mem, jobs} (reference: get-job-stats
+    monitor.clj:40-57).  Column by column — one tight pass a field, the
+    sums in numpy — not a Python fold per job: at 400k pending jobs the
+    fold was a second of interpreter time beside the cycle thread."""
+    n = len(jobs)
+    users = [j.user for j in jobs]
+    # first-appearance order, as the fold's setdefault gave
+    code = {u: i for i, u in enumerate(dict.fromkeys(users))}
+    codes = np.fromiter(map(code.__getitem__, users), np.intp, n)
+    res = [j.resources for j in jobs]
+    cpus = np.bincount(codes, np.array([r.cpus for r in res], np.float64),
+                       len(code))
+    mem = np.bincount(codes, np.array([r.mem for r in res], np.float64),
+                      len(code))
+    count = np.bincount(codes, minlength=len(code))
+    return {u: {"cpus": float(cpus[i]), "mem": float(mem[i]),
+                "jobs": float(count[i])} for u, i in code.items()}
 
 
 def _with_aggregate(stats: Dict[str, Dict[str, float]]
@@ -170,8 +182,36 @@ class Monitor:
         self.registry.gauge_clear("cook_user_dru")
         for metric in ("cook_user_resource", "cook_user_dru"):
             self.registry.reset_label_window(metric, "user")
-        for pool in self.store.pools():
-            out[pool.name] = self._sweep_pool(pool)
+        pools = self.store.pools()
+        # ONE read of the store for every pool: the live entities
+        # (clone=False — the sweep only READS user, resources and wait
+        # ages to fold into gauges; cloning 20k+ jobs per sweep was most
+        # of its cost, and a monitor that burns half a core under queue
+        # pressure feeds the very saturation it reports), for which the
+        # store holds its lock a table copy and a chunk of lookups at a
+        # time (Store.jobs_where / running_instances).  A read per pool
+        # walked every job of the store once for each of them.
+        # What was read is folded column by column in tight passes, and
+        # the sweep rests between them (utils/pacing.py): it is
+        # CPU-bound Python beside a CPU-bound cycle thread, and nobody
+        # waits for its gauges.
+        breathe = Pacer().breathe
+        with tracing.span("monitor.copy", pools=len(pools)) as sp:
+            pending: Dict[str, list] = defaultdict(list)
+            running: Dict[str, list] = defaultdict(list)
+            for job in self.store.pending_jobs(clone=False):
+                pending[job.pool].append(job)
+            breathe()
+            for job, _inst in self.store.running_instances(clone=False):
+                running[job.pool].append(job)
+            breathe()
+            sp.set_tag("pending", sum(map(len, pending.values())))
+            sp.set_tag("running", sum(map(len, running.values())))
+        # everything below folds what was read, off the store lock
+        with tracing.span("monitor.fold", pools=len(pools)):
+            for pool in pools:
+                out[pool.name] = self._sweep_pool(
+                    pool, pending[pool.name], running[pool.name], breathe)
         self._sweep_cycle_slo()
         self._sweep_http_slo()
         self._sweep_serving()
@@ -289,23 +329,25 @@ class Monitor:
                     u["pending"] + u["running"],
                     labels={"user": user})
 
-    def _sweep_pool(self, pool) -> Dict[str, int]:
+    def _sweep_pool(self, pool, pending, running,
+                    breathe=lambda: None) -> Dict[str, int]:
+        """One pool's gauges from its pending jobs and the jobs of its
+        running instances (one entry an instance) as :meth:`sweep` read
+        them; ``breathe`` is called between the passes over them (a
+        :class:`Pacer`'s)."""
         from ..state.schema import DruMode
         pool_name = pool.name
-        # clone=False: the sweep only READS (user, resources, wait
-        # ages) to fold into gauges — cloning 20k+ jobs per sweep was
-        # most of the sweep's cost, and a monitor that burns half a
-        # core under queue pressure is feeding the very saturation it
-        # reports (store.jobs_where contract)
-        pending = self.store.pending_jobs(pool_name, clone=False)
-        running = self.store.running_instances(pool_name, clone=False)
-        running_stats = _job_stats([
-            (job.user, job.resources.cpus, job.resources.mem)
-            for job, _inst in running])
-        waiting_stats = _job_stats([
-            (job.user, job.resources.cpus, job.resources.mem)
-            for job in pending])
-        self._sweep_queue_slo(pool_name, pending)
+        running_stats = _job_stats(running)
+        breathe()
+        waiting_stats = _job_stats(pending)
+        breathe()
+        # the age of every pending job's CURRENT wait, once, for the
+        # queue SLO and the wait-phase split alike
+        ages = (self.store.clock() - np.array(
+            [(j.last_waiting_start_ms or j.submit_time_ms)
+             for j in pending], np.float64)) / 1000.0
+        breathe()
+        self._sweep_queue_slo(pool_name, ages)
         # fairness plane (docs/OBSERVABILITY.md): per-user DRU (actual
         # usage normalized by share), published top-K + cached on the
         # audit trail for rank-event context, and the wait-phase split
@@ -316,12 +358,12 @@ class Monitor:
             # gauge must price the same dimension or it diverges from
             # what the rebalancer actually preempts against
             gpu_usage = {}
-            for job, _inst in running:
+            for job in running:
                 gpu_usage[job.user] = \
                     gpu_usage.get(job.user, 0.0) + job.resources.gpus
         dru = self._sweep_user_dru(pool_name, running_stats,
                                    waiting_stats, gpu_usage=gpu_usage)
-        self._sweep_wait_phases(pool_name, pending, dru)
+        self._sweep_wait_phases(pool_name, pending, ages, dru, breathe)
         starved = compute_starved_stats(
             self.store, pool_name, running_stats, waiting_stats)
         under_quota = compute_waiting_under_quota_stats(
@@ -409,8 +451,9 @@ class Monitor:
                 {"pool": pool_name, "user": "other"})
         return dru
 
-    def _sweep_wait_phases(self, pool_name: str, pending,
-                           dru: Dict[str, float]) -> None:
+    def _sweep_wait_phases(self, pool_name: str, pending, ages,
+                           dru: Dict[str, float],
+                           breathe=lambda: None) -> None:
         """Split the pending queue's current waits by WHY (utils/audit.
         wait_phase): ``fairness`` (quota / rate limit / gang admission /
         at-or-over share), ``constraints`` (placement-constraint or
@@ -419,16 +462,26 @@ class Monitor:
         own queue-latency SLO breach ratio, so "users are waiting" pages
         name the mechanism before anyone opens a timeline."""
         from ..utils.audit import wait_phase
-        now_ms = self.store.clock()
         # ONE lock hold for the whole queue's reasons: a per-job
         # last_reason() would pay 100k lock round-trips contending with
         # the scheduler's hot-path record() calls
-        reasons = self.store.audit.last_reasons(
-            [j.uuid for j in pending])
-        by_phase: Dict[str, list] = {
-            "fairness": [], "capacity": [], "constraints": []}
-        for j in pending:
-            reason = reasons.get(j.uuid)
+        reasons = self.store.audit.last_reasons([j.uuid for j in pending])
+        breathe()
+        # Most of a deep queue has never been looked at: no skip reason,
+        # no placement failure, and its phase is its user's side of the
+        # share alone.  That default is a column; only the jobs with a
+        # reason or a failure on record are walked one by one.
+        phases = ("capacity", "fairness", "constraints")
+        over = {u: v >= 1.0 for u, v in dru.items()}.get
+        code = np.array([over(j.user, False) for j in pending], np.int8)
+        breathe()
+        marked = [i for i, j in enumerate(pending)
+                  if j.last_placement_failure
+                  or reasons[j.uuid] is not None]
+        breathe()
+        for i in marked:
+            j = pending[i]
+            reason = reasons[j.uuid]
             # the persisted placement-failure census refines "couldn't
             # place" into constraints-vs-capacity, but it is STICKY
             # (never cleared once set) — a fresher fairness-side skip
@@ -440,21 +493,20 @@ class Monitor:
                 if lpf:
                     reason = ("constraints" if lpf.get("constraints")
                               else "unmatched")
-            phase = wait_phase(reason, dru.get(j.user, 0.0) >= 1.0)
-            age = (now_ms - (j.last_waiting_start_ms
-                             or j.submit_time_ms)) / 1000.0
-            by_phase[phase].append(age)
+            code[i] = phases.index(wait_phase(reason, bool(code[i])))
         obj = self.slo.queue_latency_objective_s
-        for phase, ages in by_phase.items():
+        for k, phase in enumerate(phases):
+            phase_ages = ages[code == k]
             labels = {"pool": pool_name, "phase": phase}
             self.registry.gauge_set("cook_wait_phase_jobs",
-                                    float(len(ages)), labels)
-            self.registry.observe_many("cook_wait_phase_seconds", ages,
-                                       labels, buckets=LATENCY_BUCKETS)
-            breach = sum(1 for a in ages if a > obj)
-            self._publish_slo(f"queue-latency-{phase}", obj,
-                              breach / len(ages) if ages else 0.0,
-                              pool=pool_name)
+                                    float(phase_ages.size), labels)
+            self.registry.observe_many("cook_wait_phase_seconds",
+                                       phase_ages, labels,
+                                       buckets=LATENCY_BUCKETS)
+            self._publish_slo(
+                f"queue-latency-{phase}", obj,
+                float((phase_ages > obj).mean()) if phase_ages.size
+                else 0.0, pool=pool_name)
 
     def _publish_state(self, pool_name: str, state: str,
                        stats: Dict[str, Dict[str, float]]) -> None:
@@ -498,24 +550,21 @@ class Monitor:
         self.registry.gauge_set("cook_slo_burn_rate", breach_ratio / budget,
                                 labels=labels)
 
-    def _sweep_queue_slo(self, pool_name: str, pending) -> None:
+    def _sweep_queue_slo(self, pool_name: str, ages) -> None:
         """Pending-age distribution vs the queue-latency objective.  Ages
-        are sampled at sweep time (a job still waiting counts against the
-        SLO *now*, not only once it finally launches — the launch-time
-        wait histogram is observed separately by the matcher).  The age
-        basis is the CURRENT wait (last_waiting_start_ms, the same basis
-        the store stamps queue_time_ms from): a retried job re-enters the
-        queue with a fresh clock, it does not inherit hours of prior
-        runtime as instant SLO breach."""
-        now_ms = self.store.clock()
-        ages = [(now_ms - (j.last_waiting_start_ms or j.submit_time_ms))
-                / 1000.0 for j in pending]
+        (seconds, an array: one a pending job) are sampled at sweep time
+        (a job still waiting counts against the SLO *now*, not only once
+        it finally launches — the launch-time wait histogram is observed
+        separately by the matcher).  The age basis is the CURRENT wait
+        (last_waiting_start_ms, the same basis the store stamps
+        queue_time_ms from): a retried job re-enters the queue with a
+        fresh clock, it does not inherit hours of prior runtime as
+        instant SLO breach."""
         self.registry.observe_many("cook_queue_age_seconds", ages,
                                    labels={"pool": pool_name},
                                    buckets=LATENCY_BUCKETS)
         obj = self.slo.queue_latency_objective_s
-        breach = sum(1 for a in ages if a > obj)
-        ratio = breach / len(ages) if ages else 0.0
+        ratio = float((ages > obj).mean()) if ages.size else 0.0
         self._publish_slo("queue-latency", obj, ratio, pool=pool_name)
 
     def _sweep_http_slo(self) -> None:

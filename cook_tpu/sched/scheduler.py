@@ -41,6 +41,7 @@ from ..state.schema import (
 from ..state.store import AbortTransaction, Store
 from ..utils import tracing
 from ..utils.flight import recorder as flight_recorder
+from ..utils.pacing import Pacer
 from .matcher import MatchCycleResult, Matcher, _BackoffState
 from .ranker import Ranker
 from .rebalancer import Rebalancer
@@ -696,12 +697,14 @@ class Scheduler:
         return self._pipeline or self._fused
 
     def warmup_kernels(self) -> int:
-        """Boot-time pre-compile of the fused cycle at the configured
-        (T, H) bucket grid (config.PipelineConfig; FusedCycleDriver.
-        warmup): steady-state cycles then trace/compile nothing, so the
-        first-call compile spike can never land inside a live cycle.
-        Returns the number of warmup executions (0 when unconfigured or
-        the device path is unavailable)."""
+        """Boot-time pre-compile of what the fused cycle will dispatch
+        (config.PipelineConfig gives one pool's design point; the pools
+        a dispatch stacks and the base mirror's rows are read off the
+        store — FusedCycleDriver.warmup): steady-state cycles then
+        trace/compile nothing, so the first-call compile spike can never
+        land inside a live cycle.  Returns the number of cycle
+        executions (0 when unconfigured or the device path is
+        unavailable)."""
         pl = self.config.pipeline
         if not (pl.warmup_tasks and pl.warmup_hosts):
             return 0
@@ -711,9 +714,12 @@ class Scheduler:
         # cycle — the boot fails with it (the daemon's failed-takeover
         # path exits non-zero) instead of serving on a cold, broken path
         t0 = time.perf_counter()
-        with tracing.span("fused.warmup", tasks=pl.warmup_tasks,
-                          hosts=pl.warmup_hosts,
-                          sweep=pl.warmup_sweep) as sp:
+        # annotated: a profiler trace taken over the takeover (POST
+        # /debug/profile) shows the warm-up's parts by name
+        with tracing.annotated(), \
+                tracing.span("fused.warmup", tasks=pl.warmup_tasks,
+                             hosts=pl.warmup_hosts,
+                             sweep=pl.warmup_sweep) as sp:
             runs = self._fused.warmup(
                 tasks=pl.warmup_tasks, hosts=pl.warmup_hosts,
                 users=pl.warmup_users, sweep=pl.warmup_sweep,
@@ -1215,24 +1221,32 @@ class Scheduler:
         rule (scheduler.clj:1955-1986)."""
         current = current_ms if current_ms is not None else self.clock()
         killed: List[str] = []
-        # ONE materializing scan shared by every reaper: each
-        # running_instances() call deep-clones the full live set under the
-        # store lock, so repeating it per-reaper at the 100k design point
-        # would stall concurrent transactions
-        running = self.store.running_instances()
-        for job, inst in running:
+        # ONE scan shared by every reaper, of the live entities and not
+        # of clones: the store lock is held for a bounded piece of the
+        # scan at a time (Store.running_instances), not for a deep copy
+        # of the whole running set while a cycle's status transactions
+        # queue for it.  The reapers only read; a task's state is
+        # checked again when it is acted on, by the kill's and the status
+        # update's own transactions (an illegal transition is dropped)
+        with tracing.span("reapers.scan") as sp:
+            running = self.store.running_instances(clone=False)
+            sp.set_tag("pairs", len(running))
+        # the three walks over it are paced (utils/pacing.py)
+        pace = Pacer().over
+        for job, inst in pace(running):
             if job.max_runtime_ms and inst.start_time_ms and \
                     current - inst.start_time_ms > job.max_runtime_ms:
                 self._kill_instance(inst.task_id, Reasons.MAX_RUNTIME_EXCEEDED.code)
                 killed.append(inst.task_id)
-        # the snapshot is shared, so downstream reapers must skip tasks an
+        # the scan is shared, so downstream reapers must skip tasks an
         # earlier reaper already killed this tick (a stale entry would get
         # a duplicate kill RPC and a duplicate task_id in the result)
         done = set(killed)
         killed.extend(self._reap_orphaned_cluster_instances(
-            current, running, skip=done))
+            current, pace(running), skip=done))
         done.update(killed)
-        killed.extend(self._reap_stragglers(current, running, skip=done))
+        killed.extend(self._reap_stragglers(current, pace(running),
+                                            skip=done))
         if self.config.heartbeat_enabled:
             for task_id in self.heartbeats.expired(current):
                 self._kill_instance(task_id, Reasons.HEARTBEAT_LOST.code)
@@ -1255,7 +1269,7 @@ class Scheduler:
         failed: List[str] = []
         live = set()
         if running is None:
-            running = self.store.running_instances()
+            running = self.store.running_instances(clone=False)
         for _job, inst in running:
             if inst.task_id in skip:
                 continue
@@ -1279,7 +1293,7 @@ class Scheduler:
         killed: List[str] = []
         groups: Dict[str, List] = {}
         if running is None:
-            running = self.store.running_instances()
+            running = self.store.running_instances(clone=False)
         for job, inst in running:
             if inst.task_id in skip:
                 continue

@@ -755,6 +755,12 @@ class ColumnarIndex:
                 job_res=job_res, complex_s=self._complex[rows_s],
                 owner_rows=owner_rows, compactions=self.compactions)
 
+    def row_count(self) -> int:
+        """Rows of every pool the base columns hold (the height of
+        ``res_base`` in a snapshot taken now)."""
+        with self._lock:
+            return self._n
+
     def rows_for(self, uuids) -> np.ndarray:
         """Base-row indices for the given job uuids (unknown uuids are
         skipped).  Lets hot-path membership tests run on int64 rows instead

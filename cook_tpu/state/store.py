@@ -283,6 +283,10 @@ class _Txn:
                        new=new_state.value, reason=reason)
 
 
+#: live instances whose jobs one hold of the store lock looks up when a
+#: sweep reads the running set without cloning (running_instances)
+_SCAN_CHUNK = 2048
+
 #: group-commit batch-size histogram bounds (records per durability round)
 _GC_BATCH_BUCKETS = (1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0, 128.0, 256.0,
                      512.0)
@@ -1838,17 +1842,29 @@ class Store:
         ~450 ms of pure cloning at 20k pending jobs — long enough to
         convoy the serving plane it is supposed to protect).  Callers
         must not mutate, and must tolerate fields changing underneath
-        them between reads (gauges do; decision paths must clone)."""
+        them between reads (gauges do; decision paths must clone).
+        The lock is then held only for the copy of the table's
+        references; the walk itself reads entity fields and runs
+        outside it, so a sweep over 400k jobs stalls no transaction."""
+        if not clone:
+            with self._lock:
+                jobs = list(self._jobs.values())
+            return [j for j in jobs if j.committed and pred(j)]
         with self._lock:
-            if clone:
-                return [fast_clone(j) for j in self._jobs.values()
-                        if j.committed and pred(j)]
-            return [j for j in self._jobs.values()
+            return [fast_clone(j) for j in self._jobs.values()
                     if j.committed and pred(j)]
 
     def pending_jobs(self, pool: Optional[str] = None,
                      clone: bool = True) -> List[Job]:
         """Committed waiting jobs (reference: queries.clj get-pending-job-ents)."""
+        if not clone:
+            # jobs_where's walk without a call a job: the monitor's sweep
+            # reads every pending job of the store through here
+            with self._lock:
+                jobs = list(self._jobs.values())
+            waiting = JobState.WAITING
+            return [j for j in jobs if j.state is waiting and j.committed
+                    and (pool is None or j.pool == pool)]
         return self.jobs_where(
             lambda j: j.state is JobState.WAITING and (pool is None or j.pool == pool),
             clone=clone)
@@ -1862,18 +1878,41 @@ class Store:
         """(job, instance) for live instances (reference: tools.clj
         get-running-task-ents — includes unknown + running).
         ``clone=False``: live read-only entities, same contract as
-        :meth:`jobs_where`."""
+        :meth:`jobs_where` — and no snapshot of one instant: the lock is
+        held for the copy of the table's references and then for
+        ``_SCAN_CHUNK`` live instances at a time (the job lookups), so
+        the 30 s sweeps hold it for a bounded piece of work however
+        large the running set is."""
+        live = (InstanceStatus.UNKNOWN, InstanceStatus.RUNNING)
+        if not clone:
+            with self._lock:
+                insts = list(self._instances.values())
+            insts = [i for i in insts if i.status in live]
+            out = []
+            for k in range(0, len(insts), _SCAN_CHUNK):
+                self._live_pairs(insts[k:k + _SCAN_CHUNK], pool, out)
+            return out
         with self._lock:
             out = []
             for inst in self._instances.values():
-                if inst.status not in (InstanceStatus.UNKNOWN, InstanceStatus.RUNNING):
+                if inst.status not in live:
                     continue
                 job = self._jobs.get(inst.job_uuid)
                 if job is None or (pool is not None and job.pool != pool):
                     continue
-                out.append((fast_clone(job), fast_clone(inst)) if clone
-                           else (job, inst))
+                out.append((fast_clone(job), fast_clone(inst)))
             return out
+
+    def _live_pairs(self, insts: List[Instance], pool: Optional[str],
+                    out: List[Tuple[Job, Instance]]) -> None:
+        """(job, instance) of one chunk of instances, appended to
+        ``out``: one hold of the lock."""
+        with self._lock:
+            jobs = self._jobs
+            for inst in insts:
+                job = jobs.get(inst.job_uuid)
+                if job is not None and (pool is None or job.pool == pool):
+                    out.append((job, inst))
 
     def user_summary(self) -> Dict[str, Dict[str, float]]:
         """Bounded per-user summary of this store's committed jobs —

@@ -89,6 +89,11 @@ DETAIL_BY_SPAN = {
     "store.clear-intents": "apply_cluster",
     "apply.audit": "apply_audit",
     "store.drain-events": "apply_audit",
+    # the 30 s sweeps' own records (kind reapers / monitor): which part
+    # reads the store and which folds what was read
+    "reapers.scan": "scan",
+    "monitor.copy": "copy",
+    "monitor.fold": "fold",
 }
 #: detail_ms key -> the key it is a part of
 DETAIL_PARENT = {
@@ -101,6 +106,9 @@ DETAIL_PARENT = {
 #: duration_ms minus these — what no span covers yet
 DETAIL_TOP_LEVEL = ("pools", "pack", "stage", "dispatch", "fetch", "apply",
                     "pipeline", "publish")
+#: the keys of a background loop's own record (kind reapers / monitor):
+#: parts of no fused cycle, so outside the partition above
+DETAIL_SWEEPS = ("scan", "copy", "fold")
 
 #: span name -> blocked_ms key: a named wait of the recording thread
 BLOCKED_BY_SPAN = {"journal.commit-wait": "commit_wait"}

@@ -255,7 +255,8 @@ class MetricsRegistry:
         if _suppressed.get():
             return
         import numpy as np
-        vals = np.asarray(list(values_s), dtype=float)
+        vals = np.asarray(values_s if isinstance(values_s, np.ndarray)
+                          else list(values_s), dtype=float)
         if vals.size == 0:
             return
         key = (name, _labels_key(self._guard_labels(name, labels)))

@@ -97,6 +97,18 @@ def annotate_spans(on: bool = True,
             pass
 
 
+@contextmanager
+def annotated():
+    """Spans opened inside also enter a profiler annotation, on a thread
+    that is not a scheduler thread for good: the takeover thread while
+    it warms the kernels."""
+    token = _annotate_var.set(True)
+    try:
+        yield
+    finally:
+        _annotate_var.reset(token)
+
+
 def _annotation(name: str):
     """A profiler annotation context for ``name``, or None when this
     context is not a scheduler thread or the process never imported JAX

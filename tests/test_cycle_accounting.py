@@ -145,8 +145,10 @@ class TestDetailSplit:
     def test_every_mapped_key_has_one_parent_or_is_top_level(self):
         keys = set(flight.DETAIL_BY_SPAN.values())
         for key in keys:
-            assert (key in flight.DETAIL_TOP_LEVEL) \
+            assert (key in flight.DETAIL_TOP_LEVEL
+                    or key in flight.DETAIL_SWEEPS) \
                 != (key in flight.DETAIL_PARENT), key
+        assert not set(flight.DETAIL_SWEEPS) & set(flight.DETAIL_TOP_LEVEL)
         assert set(flight.DETAIL_PARENT.values()) \
             <= set(flight.DETAIL_TOP_LEVEL)
         assert set(APPLY_PARTS + PACK_PARTS) == set(flight.DETAIL_PARENT)
@@ -726,6 +728,21 @@ class TestProfilerClock:
         import jax
         assert tracing._annotation_cls is jax.profiler.TraceAnnotation
 
+    def test_the_warm_up_annotates_its_parts_and_only_while_it_runs(
+            self, monkeypatch):
+        monkeypatch.setattr(tracing, "_annotation_cls", FakeAnnotation)
+        FakeAnnotation.names = []
+        cfg = Config()
+        cfg.pipeline.warmup_tasks = cfg.pipeline.warmup_hosts = 64
+        # the takeover thread, which is no scheduler thread
+        Scheduler(Store(), config=cfg)
+        assert {"fused.warmup", "warmup.cycle", "warmup.delta_apply",
+                "warmup.delta_append"} <= set(FakeAnnotation.names)
+        FakeAnnotation.names = []
+        with tracing.span("http.request"):
+            pass
+        assert FakeAnnotation.names == []
+
 
 class TestProfileEndpoint:
     @pytest.fixture
@@ -805,7 +822,8 @@ def test_names_the_yardstick_reads_are_stable():
                   "delta_rows", "wait_ms", "overrun_ms", "gc_ms",
                   "flush_audit_ms",
                   "blocked_ms", "offcpu_ms", "background_ms",
-                  "pipeline_lag_ms", "staged_tx", "cpu_ms", "lock_holder"):
+                  "pipeline_lag_ms", "staged_tx", "cpu_ms", "lock_holder",
+                  "pools"):
         assert field in doc, field
     by_key = {}
     for span, key in flight.DETAIL_BY_SPAN.items():

@@ -226,6 +226,117 @@ class TestWarmup:
         assert sched.warmup_kernels() >= 1
 
 
+def build_pools_world(n_pools=8, depth=2, warmup_tasks=0, backend="tpu",
+                      jobs_of=lambda p: 20 + 7 * p, seed=11):
+    """A seeded several-pool world whose arithmetic is exact in float32
+    (sizes and shares are powers of two, so a DRU tie is a tie under any
+    division): pools of UNEQUAL size, so the stacked [P, T] dispatch pads
+    every pool but the largest; capacity binds in every pool (4 hosts x
+    8 cpus against 40+ cpus of demand), so the order decides who runs."""
+    rng = np.random.default_rng(seed)
+    cfg = Config()
+    cfg.pipeline.depth = depth
+    cfg.default_matcher.max_jobs_considered = 16
+    if backend == "cpu":
+        cfg.default_matcher.backend = "cpu"
+    if warmup_tasks:
+        cfg.pipeline.warmup_tasks = warmup_tasks
+        cfg.pipeline.warmup_hosts = 64
+        cfg.pipeline.warmup_users = 8
+    store = Store()
+    pools = [f"pool{i}" for i in range(n_pools)]
+    hosts, jobs = [], []
+    for p, pool in enumerate(pools):
+        store.put_pool(Pool(name=pool))
+        for u in range(5):
+            store.set_share(f"user{u}", pool,
+                            {"cpus": 4.0 * (1 + (u == 0)),
+                             "mem": 4096.0 * (1 + (u == 0))})
+        hosts += [FakeHost(hostname=f"{pool}-h{i}", pool=pool,
+                           capacity=Resources(cpus=8.0, mem=16384.0))
+                  for i in range(4)]
+        for _ in range(jobs_of(p)):
+            i = len(jobs)
+            cpus = float(rng.choice([1, 2, 4]))
+            jobs.append(Job(
+                uuid=f"00000000-0000-0000-0000-{i:012d}",
+                user=f"user{int(rng.integers(0, 5))}", command="true",
+                pool=pool, priority=int(rng.integers(0, 100)),
+                resources=Resources(cpus=cpus,
+                                    mem=cpus * float(rng.choice([512,
+                                                                 1024]))),
+                submit_time_ms=1000 + i))
+    store.create_jobs(jobs)
+    cluster = FakeCluster("fake-1", hosts)
+    sched = Scheduler(store, cfg, [cluster], rank_backend=backend)
+    return store, sched, pools, jobs
+
+
+class TestStackedPools:
+    """Eight pools stacked [8, T] in one dispatch (cook-8pool-50k's shape
+    at a tiny size)."""
+
+    def test_warmed_at_boot_the_first_cycles_compile_nothing(self):
+        """The warm-up reads the stacking off the store: the first three
+        cycles of an eight-pool daemon, WITH arrivals between them (so
+        the base mirror's chunk append and the stacked delta scatter both
+        run), trace and compile nothing."""
+        from cook_tpu.utils.flight import recorder
+        from cook_tpu.utils.metrics import registry
+        # 300 rows a pool land in the 512 bucket the design point names;
+        # 2,400 rows leave the 4,096-row mirror room for a 1,024 chunk
+        store, sched, pools, jobs = build_pools_world(
+            warmup_tasks=512, jobs_of=lambda p: 300)
+        assert sched.device["warmup_runs"] == 1     # P = 8 only, no P = 1
+        launches = lambda kernel: sum(
+            v for labels, v in registry.series("cook_kernel_launches")
+            if labels.get("kernel") == kernel)
+        appends, scatters = launches("delta.append"), launches("delta.apply")
+        seq0 = recorder.last_seq()
+        n = len(jobs)
+        for step in range(3):
+            sched.step_cycle()
+            store.create_jobs([
+                Job(uuid=f"00000000-0000-0000-0001-{n + k:012d}",
+                    user=f"user{k % 5}", command="true", pool=pool,
+                    priority=50, resources=Resources(cpus=1.0, mem=512.0),
+                    submit_time_ms=5000 + n + k)
+                for k, pool in enumerate(pools * 3)])
+            n += len(pools) * 3
+        flight = recorder.summary(since_seq=seq0)
+        assert flight.get("recompiles", {}) == {}, flight["recompiles"]
+        recs = [r for r in recorder.recent(10)
+                if r["seq"] > seq0 and r["kind"] == "fused"]
+        assert [r["pools"] for r in recs] == [8, 8, 8]
+        assert launches("delta.append") > appends
+        assert launches("delta.apply") > scatters
+
+    def test_stacked_decisions_are_the_reference_paths_pool_by_pool(self):
+        """One fused depth-2 step over eight stacked pools of unequal
+        size against the plain split path on the numpy reference
+        (ops/reference_impl.py: rank_by_dru, greedy_match): the same
+        launched set and the same job -> host map in every pool, exactly."""
+        def run(backend):
+            store, sched, pools, jobs = build_pools_world(backend=backend)
+            if backend == "cpu":
+                sched.step_rank()
+                sched.step_match()
+            else:
+                sched.step_cycle()
+            placed = {pool: {} for pool in pools}
+            for j in jobs:
+                for t in store.job(j.uuid).instances:
+                    placed[j.pool][j.uuid] = store.instance(t).hostname
+            return placed
+        ref, got = run("cpu"), run("tpu")
+        for pool in ref:
+            assert 0 < len(ref[pool]) < 16 + 1, pool   # capacity binds
+            assert set(got[pool]) == set(ref[pool]), pool
+            assert got[pool] == ref[pool], pool
+        # the order decided: some pool left considerable jobs unplaced
+        assert any(len(ref[pool]) < 16 for pool in ref)
+
+
 class TestObservability:
     def test_cycle_record_carries_pipeline_fields(self):
         from cook_tpu.utils.flight import recorder
@@ -246,13 +357,26 @@ class TestObservability:
         text = registry.expose()
         assert "cook_pipeline_depth 2.0" in text
 
-    def test_depth0_gauge_reads_zero(self):
+    def test_depth0_gauge_reads_zero(self, monkeypatch):
         """A sync deployment must be distinguishable from a broken
-        scrape: the depth gauge reads 0, it is not absent."""
+        scrape: the depth gauge reads 0, it is not absent.  Read off
+        what THIS thread wrote: the registry is the process's, and under
+        the driver's workers a pipelined scheduler another test left
+        running wrote 2.0 over it between the step and the scrape."""
+        import threading
+
         from cook_tpu.utils.metrics import registry
+        me, wrote, gauge_set = threading.get_ident(), [], registry.gauge_set
+
+        def noting(name, value, labels=None):
+            if threading.get_ident() == me and name == "cook_pipeline_depth":
+                wrote.append(value)
+            gauge_set(name, value, labels)
+        monkeypatch.setattr(registry, "gauge_set", noting)
         _store, sched, _c, _jobs = build_world(depth=0)
         sched.step_cycle()
-        assert "cook_pipeline_depth 0.0" in registry.expose()
+        assert wrote and set(wrote) == {0.0}
+        assert "cook_pipeline_depth " in registry.expose()
 
 
 class TestParityHarness:
