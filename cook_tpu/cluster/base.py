@@ -106,7 +106,10 @@ class ComputeCluster(abc.ABC):
 
     Status updates flow back through ``status_callback(task_id, status,
     reason_code)`` registered at initialization — the moral equivalent of the
-    mesos scheduler callbacks / k8s watch feed.
+    mesos scheduler callbacks / k8s watch feed.  A backend that
+    acknowledges tasks INSIDE ``launch_tasks`` hands the whole call's
+    acknowledgements over at once (:meth:`_emit_statuses`), so the
+    scheduler can apply them as one store transaction.
     """
 
     def __init__(self, name: str):
@@ -114,11 +117,32 @@ class ComputeCluster(abc.ABC):
         self.kill_lock = ReadWriteLock()
         self.state = "running"  # running -> draining -> deleted
         self._status_callback: Optional[Callable] = None
+        self._status_batch_callback: Optional[Callable] = None
 
     # -- lifecycle ----------------------------------------------------------
-    def initialize(self, status_callback: Callable) -> None:
-        """Connect and begin delivering status updates."""
+    def initialize(self, status_callback: Callable,
+                   status_batch_callback: Optional[Callable] = None) -> None:
+        """Connect and begin delivering status updates.
+        ``status_batch_callback(updates)`` takes a list of ``(task_id,
+        status, reason_code, exit_code, preempted, hostname)`` in
+        delivery order; without one a batch is delivered entry by entry
+        through ``status_callback``."""
         self._status_callback = status_callback
+        self._status_batch_callback = status_batch_callback
+
+    def _emit_statuses(self, updates: List[tuple]) -> None:
+        """Deliver several status updates at once, in list order (the
+        acknowledgements of one ``launch_tasks`` call)."""
+        if not updates:
+            return
+        if self._status_batch_callback is not None:
+            self._status_batch_callback(updates)
+        elif self._status_callback is not None:
+            for (task_id, status, reason_code, exit_code, preempted,
+                 hostname) in updates:
+                self._status_callback(task_id, status, reason_code,
+                                      exit_code=exit_code,
+                                      preempted=preempted, hostname=hostname)
 
     # -- scheduling ---------------------------------------------------------
     @abc.abstractmethod

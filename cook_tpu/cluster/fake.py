@@ -195,13 +195,16 @@ class FakeCluster(ComputeCluster):
                 self._consume(spec.hostname, spec.resources, 1.0)
                 self.launched_order.append(spec.task_id)
                 breaker.record_success()
-        for spec in specs:
-            if spec.task_id not in rejected:
-                self._emit(spec.task_id, InstanceStatus.RUNNING, None,
-                           hostname=spec.hostname)
-        for tid in rejected:
-            self._emit(tid, InstanceStatus.FAILED,
-                       Reasons.REASON_POD_SUBMISSION_FAILED.code)
+        # the whole call is acknowledged at once: RUNNING for what was
+        # accepted, then FAILED for what was rejected
+        refused = set(rejected)
+        self._emit_statuses(
+            [(spec.task_id, InstanceStatus.RUNNING, None, None, False,
+              spec.hostname)
+             for spec in specs if spec.task_id not in refused]
+            + [(tid, InstanceStatus.FAILED,
+                Reasons.REASON_POD_SUBMISSION_FAILED.code, None, False, None)
+               for tid in rejected])
 
     def _first_fit(self, pool: str, need: Resources) -> Optional[str]:
         zeros = (0.0, 0.0, 0.0, 0.0)

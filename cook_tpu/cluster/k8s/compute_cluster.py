@@ -76,8 +76,9 @@ class KubernetesCluster(ComputeCluster):
             clock=clock)
 
     # ------------------------------------------------------------- lifecycle
-    def initialize(self, status_callback) -> None:
-        super().initialize(status_callback)
+    def initialize(self, status_callback,
+                   status_batch_callback=None) -> None:
+        super().initialize(status_callback, status_batch_callback)
         if self.store is not None:
             self._reconcile_startup()
         if not self._watch_registered:

@@ -321,8 +321,9 @@ class RemoteComputeCluster(ComputeCluster):
         self._terminal_seen: "OrderedDict[str, None]" = OrderedDict()
 
     # -- lifecycle ----------------------------------------------------------
-    def initialize(self, status_callback: Callable) -> None:
-        super().initialize(status_callback)
+    def initialize(self, status_callback: Callable,
+                   status_batch_callback: Optional[Callable] = None) -> None:
+        super().initialize(status_callback, status_batch_callback)
         for host, port in self._endpoints:
             # one dead node must not prevent scheduling on healthy ones
             try:

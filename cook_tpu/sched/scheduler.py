@@ -234,7 +234,7 @@ class Scheduler:
 
     # ---------------------------------------------------------------- wiring
     def add_cluster(self, cluster: ComputeCluster) -> None:
-        cluster.initialize(self._on_status_update)
+        cluster.initialize(self._on_status_update, self._on_status_updates)
         self.clusters[cluster.name] = cluster
 
     def launchable_clusters(self, pool_name: str) -> List[ComputeCluster]:
@@ -344,13 +344,28 @@ class Scheduler:
         else:
             self._apply_status_payload(task_id, payload)
 
+    def _on_status_updates(self, updates) -> None:
+        """Batch delivery (ComputeCluster._emit_statuses): the
+        acknowledgements of one ``launch_tasks`` call, as ``(task_id,
+        status, reason_code, exit_code, preempted, hostname)`` in
+        delivery order, applied as ONE store transaction.  With a status
+        queue configured they are submitted entry by entry: the
+        hash-sharded ordering contract is that queue's."""
+        if self._status_queue is not None:
+            for task_id, *payload in updates:
+                self._status_queue.submit(task_id, tuple(payload))
+        else:
+            self._apply_statuses(updates)
+
     def _apply_status_payload(self, task_id: str, payload) -> None:
-        status, reason_code, exit_code, preempted, hostname = payload
-        if status is InstanceStatus.RUNNING:
-            self.heartbeats.beat(task_id, self.clock())
-        self.store.update_instance_status(
-            task_id, status, reason_code=reason_code, exit_code=exit_code,
-            preempted=preempted, hostname=hostname)
+        self._apply_statuses([(task_id, *payload)])
+
+    def _apply_statuses(self, updates) -> None:
+        now = self.clock()  # one clock read per batch
+        for update in updates:
+            if update[1] is InstanceStatus.RUNNING:
+                self.heartbeats.beat(update[0], now)
+        self.store.update_instance_statuses(updates)
 
     def heartbeat(self, task_id: str) -> None:
         """Explicit liveness signal from an executor/sidecar (progress

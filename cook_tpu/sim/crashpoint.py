@@ -44,6 +44,12 @@ reduced matrix in tier-1 and the full soak under ``-m slow``):
                     falls back to the previous generation; an injected
                     ``fsatomic.fsync`` failure aborts the checkpoint
                     without losing the live store
+``launch-ack``      the clean run's journal cut between a burst's guard
+                    transaction and its one acknowledgement transaction:
+                    the burst's intents are open after recovery and the
+                    leader-startup sweep reconciles every one (refund
+                    when the backend does not know the task, adopt when
+                    it does)
 ==================  =====================================================
 """
 
@@ -92,11 +98,22 @@ def _make_job(i: int) -> Job:
                max_retries=1)
 
 
+#: the compute cluster the burst is launched on (the launch-ack leg
+#: restarts a scheduler over a backend of this name)
+BURST_CLUSTER = "burst"
+
+#: jobs of the launch burst that ends the script (one guard transaction,
+#: then one status transaction acknowledging all of them)
+BURST = 3
+
+
 def build_ops(n_jobs: int) -> List[Tuple]:
     """The deterministic op script: create / launch / run / finish /
     kill, interleaved so the journal carries every record shape the
     store emits (job create, instance launch, status transitions, kill
-    tombstones, audit piggybacks)."""
+    tombstones, audit piggybacks), ending in a launch BURST the way a
+    match cycle makes one: one guard transaction for several jobs, then
+    one status transaction acknowledging them all."""
     ops: List[Tuple] = []
     for i in range(n_jobs):
         ops.append(("create", i))
@@ -106,6 +123,11 @@ def build_ops(n_jobs: int) -> List[Tuple]:
             ops.append(("status", f"task-{i}", InstanceStatus.SUCCESS))
         elif i % 3 == 1:
             ops.append(("kill", i))
+    burst = tuple(range(n_jobs, n_jobs + BURST))
+    for i in burst:
+        ops.append(("create", i))
+    ops.append(("launch-burst", burst))
+    ops.append(("ack-burst", burst))
     return ops
 
 
@@ -117,6 +139,15 @@ def apply_op(store: Store, op: Tuple) -> None:
         store.launch_instance(_make_job(op[1]).uuid, op[2], op[3])
     elif kind == "status":
         store.update_instance_status(op[1], op[2])
+    elif kind == "launch-burst":
+        store.launch_instances([dict(
+            job_uuid=_make_job(i).uuid, task_id=f"task-{i}",
+            hostname=f"host-{i % 4}", compute_cluster=BURST_CLUSTER)
+            for i in op[1]])
+    elif kind == "ack-burst":
+        store.update_instance_statuses(
+            [(f"task-{i}", InstanceStatus.RUNNING, None, None, False, None)
+             for i in op[1]])
     elif kind == "kill":
         store.kill_job(_make_job(op[1]).uuid)
     else:  # pragma: no cover - script bug surface
@@ -531,6 +562,68 @@ def _leg_checkpoint(res: CrashPointResult, run: _Run, base: str,
         reopened.close()
 
 
+def _leg_launch_ack(res: CrashPointResult, run: _Run, base: str) -> None:
+    """Crash between a burst's guard transaction and its
+    acknowledgement (ONE status transaction for the whole burst, so the
+    window is one record wide): recovery holds the burst's instances
+    UNKNOWN with their intents open, and a leader starting over the
+    recovered store reconciles every intent against the backend —
+    refunded mea-culpa when the backend does not know the task (the
+    dispatch never happened), adopted untouched when it does."""
+    from ..cluster.fake import FakeCluster, FakeHost
+    from ..config import Config
+    from ..sched.scheduler import Scheduler
+    from ..state.schema import JobState, Reasons
+    at = next(i for i, op in enumerate(run.ops) if op[0] == "launch-burst")
+    burst = [f"task-{i}" for i in run.ops[at][1]]
+    cut = run.op_offsets[at]
+    cfg = Config()
+    cfg.cycle_mode = "split"
+    cfg.default_matcher.backend = "cpu"
+    cfg.columnar_index = False
+    for knows in (False, True):
+        res.case("launch-ack")
+        case = "adopt" if knows else "refund"
+        d = os.path.join(base, f"la-{case}")
+        os.makedirs(d)
+        with open(os.path.join(d, "journal.jsonl"), "wb") as f:
+            f.write(run.journal[:cut])
+        store = Store.open(d, fsync=False)
+        if sorted(r["task_id"] for r in store.launch_intents()) \
+                != sorted(burst):
+            res.violate("launch-ack", case,
+                        "the burst's intents are not all open after a "
+                        "crash before its acknowledgement")
+        cluster = FakeCluster(BURST_CLUSTER, [FakeHost(
+            hostname=f"host-{i}", capacity=Resources(cpus=8.0, mem=1024.0))
+            for i in range(4)])
+        if knows:
+            cluster.running_task_ids = lambda: list(burst)
+        Scheduler(store, cfg, [cluster], rank_backend="cpu")
+        if store.launch_intents():
+            res.violate("launch-ack", case,
+                        "the startup sweep left intents open")
+        for tid in burst:
+            inst = store.instance(tid)
+            job = store.job(inst.job_uuid)
+            want = (InstanceStatus.UNKNOWN, JobState.RUNNING) if knows \
+                else (InstanceStatus.FAILED, JobState.WAITING)
+            if (inst.status, job.state) != want or (
+                    not knows and inst.reason_code
+                    != Reasons.CANCELLED_DURING_LAUNCH.code):
+                res.violate("launch-ack", case,
+                            f"{tid}: {inst.status.name}/{job.state.name} "
+                            "after the sweep")
+        expected = state_digest(store)
+        store.close()
+        reopened = Store.open(d, fsync=False)
+        if state_digest(reopened) != expected \
+                or reopened.launch_intents():
+            res.violate("launch-ack", case,
+                        "the sweep's transactions did not replay")
+        reopened.close()
+
+
 # ---------------------------------------------------------------------------
 # driver
 # ---------------------------------------------------------------------------
@@ -575,6 +668,7 @@ def run_crashpoints(n_jobs: int = 4, stride: int = 1,
         _leg_byte_boundary(res, run, base, cuts_per_line)
         _leg_corruption(res, run, base, repl_port)
         _leg_checkpoint(res, run, base, n_jobs)
+        _leg_launch_ack(res, run, base)
     finally:
         injector.clear()
         if server is not None:

@@ -633,6 +633,27 @@ class PartitionedStore:
         return store.update_instance_status(task_id, *a, **kw) \
             if store is not None else False
 
+    def update_instance_statuses(self, updates: Iterable[Tuple]
+                                 ) -> List[bool]:
+        """One status transaction per TOUCHED partition (grouped the way
+        launch_instances groups its entries; list order is kept inside
+        each partition, which is all an instance's order needs — an
+        instance lives in one partition).  An entry whose task no
+        partition holds reads False, as in the single store."""
+        updates = list(updates)
+        by_part: Dict[int, List[int]] = {}
+        for i, u in enumerate(updates):
+            p = self._partition_of_instance(u[0])
+            if p is not None:
+                by_part.setdefault(p, []).append(i)
+        out = [False] * len(updates)
+        for p, idxs in sorted(by_part.items()):
+            oks = self.partitions[p].update_instance_statuses(
+                [updates[i] for i in idxs])
+            for i, ok in zip(idxs, oks):
+                out[i] = ok
+        return out
+
     def update_instance_progress(self, task_id: str, *a, **kw) -> bool:
         store = self._route_instance(task_id)
         return store.update_instance_progress(task_id, *a, **kw) \

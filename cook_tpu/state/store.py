@@ -31,7 +31,7 @@ import time
 from contextlib import nullcontext
 from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
 
-from ..utils import tracing
+from ..utils import flight, tracing
 from ..utils.locks import named_lock, named_rlock
 from ..utils.metrics import registry as _metrics
 from . import machines
@@ -267,25 +267,35 @@ class _Txn:
         return [j.uuid for j in jobs]
 
     # -- composite ops shared by several public store methods ---------------
-    def recompute_job_state(self, job: Job) -> None:
-        """Re-derive job state from instances; emits job-state event on change
-        (reference: :job/update-state side of :instance/update-state)."""
-        # next_job_state only READS the instances — the non-cloning view
-        # saves one Instance clone per live attempt on every status update
+    def recompute_job_state(self, uuid: str) -> None:
+        """Re-derive a job's state from its instances; emits job-state event
+        on change (reference: :job/update-state side of
+        :instance/update-state).  Decided on non-cloning reads — the job as
+        this txn sees it, its own write intent included: the Job is cloned
+        and written (and so journaled) only when its state moves."""
+        job = self._get("jobs", uuid, for_write=False, clone=False)
+        if job is None:
+            return
         new_state, reason = machines.next_job_state(
             job, self.peek_instances_of(job))
-        if new_state is not job.state:
-            old = job.state
-            job.state = new_state
-            if new_state is JobState.WAITING:
-                job.last_waiting_start_ms = self._store.clock()
-            self.event("job-state", uuid=job.uuid, old=old.value,
-                       new=new_state.value, reason=reason)
+        if new_state is job.state:
+            return
+        job = self.job_w(uuid)
+        old = job.state
+        job.state = new_state
+        if new_state is JobState.WAITING:
+            job.last_waiting_start_ms = self._store.clock()
+        self.event("job-state", uuid=uuid, old=old.value,
+                   new=new_state.value, reason=reason)
 
 
 #: live instances whose jobs one hold of the store lock looks up when a
 #: sweep reads the running set without cloning (running_instances)
 _SCAN_CHUNK = 2048
+
+#: status-transaction batch-size histogram bounds (entries a transaction:
+#: 1 = a status on its own, the cap = one launch burst acknowledged)
+_STATUS_BATCH_BUCKETS = (1.0, 2.0, 4.0, 16.0, 64.0, 256.0, 1024.0, 4096.0)
 
 #: group-commit batch-size histogram bounds (records per durability round)
 _GC_BATCH_BUCKETS = (1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0, 128.0, 256.0,
@@ -682,9 +692,9 @@ class Store:
 
         ``drain_span``: also time the event drain as a span of its own
         (``store.drain-events``).  Only the cycle's one launch
-        transaction asks for it: a span on every transaction would put a
-        thousand more on a cycle whose backend acknowledges each task
-        through a status transaction."""
+        transaction asks for it; the status transaction that
+        acknowledges the burst (:meth:`update_instance_statuses`) is
+        inside ``cluster.launch-tasks`` and its drain is counted there."""
         indeterminate: Optional[ReplicationIndeterminate] = None
         waiter: Optional[_CommitWaiter] = None
         with self._lock:
@@ -726,9 +736,9 @@ class Store:
             # the group-commit round this thread blocks on.  A scheduler
             # cycle's record takes it as blocked_ms.commit_wait and
             # detail_ms.apply_journal (utils/flight.py) — as a bare
-            # duration, not a span: a backend that acknowledges each
-            # task through a status transaction waits here a thousand
-            # times a cycle
+            # duration, not a span: every transaction of every thread
+            # passes here, and a cycle waits twice a pool (the launch
+            # transaction, the burst's one status transaction)
             t0 = time.perf_counter()
             err = waiter.stage.wait(waiter)
             tracing.cycle_time("journal.commit-wait",
@@ -1442,49 +1452,97 @@ class Store:
                                hostname: Optional[str] = None) -> bool:
         """Instance state machine + job writeback (reference:
         :instance/update-state schema.clj:1242-1308). Returns False when the
-        transition is illegal (stale status updates are dropped, not errors)."""
+        transition is illegal (stale status updates are dropped, not errors).
+        Single-entry form of :meth:`update_instance_statuses` (one body,
+        one invariant)."""
+        return self.update_instance_statuses([(
+            task_id, new_status, reason_code, exit_code, preempted,
+            hostname)])[0]
 
-        def _update(txn: _Txn) -> bool:
-            inst = txn.instance_w(task_id)
-            if inst is None:
-                return False
-            # any backend status proves the dispatch reached the cluster:
-            # the launch intent has served its purpose (guarded so the
-            # common no-intent case journals nothing extra)
-            if task_id in self._intents:
-                txn.delete("intents", task_id)
-            if inst.status is new_status:
-                # Redelivered status (k8s watch replays, mesos re-sends): a
-                # pure no-op — must not overwrite end_time/reason/exit_code.
-                return True
-            if not machines.instance_transition_allowed(inst.status, new_status):
-                return False
-            old = inst.status
-            inst.status = new_status
-            if hostname:
-                # direct-mode backends report placement with the first status
-                inst.hostname = hostname
-                if not inst.slave_id:
-                    inst.slave_id = hostname
-            if reason_code is not None:
-                inst.reason_code = reason_code
-            if exit_code is not None:
-                inst.exit_code = exit_code
-            if preempted:
-                inst.preempted = True
-            if new_status in (InstanceStatus.SUCCESS, InstanceStatus.FAILED):
-                inst.end_time_ms = self.clock()
-            if new_status is InstanceStatus.RUNNING and inst.mesos_start_time_ms is None:
-                inst.mesos_start_time_ms = self.clock()
-            if old is not new_status:
-                txn.event("instance-status", task_id=task_id, job=inst.job_uuid,
-                          old=old.value, new=new_status.value, reason=reason_code)
-            job = txn.job_w(inst.job_uuid)
-            if job is not None:
-                txn.recompute_job_state(job)
-            return True
+    def update_instance_statuses(
+            self, updates: Iterable[Tuple]) -> List[bool]:
+        """Batched status writeback: ONE transaction — one journal record,
+        one event drain, one commit wait — for a list of ``(task_id,
+        new_status, reason_code, exit_code, preempted, hostname)``, the
+        shape in which a backend acknowledges a whole ``launch_tasks``
+        call.  Entries apply in list order, each seeing the writes of the
+        ones before it, and each is decided by the instance state machine
+        on its own: an unknown task or an illegal (stale) transition
+        returns False for THAT entry and aborts nothing else — a status
+        batch is not all-or-nothing, unlike a gang's launch guard.
 
-        return self.transact(_update)
+        The transaction writes, and so journals, only what it changed: an
+        instance whose status moved, and its Job only when the job's
+        state moves with it (UNKNOWN -> RUNNING after a launch never
+        does: the launch transaction already set the job RUNNING).  A
+        redelivered status writes nothing.  Replay of the leaner record
+        rebuilds the same store."""
+        updates = list(updates)
+
+        def _update_all(txn: _Txn) -> List[bool]:
+            intents = self._intents
+            out: List[bool] = []
+            for (task_id, new_status, reason_code, exit_code, preempted,
+                 hostname) in updates:
+                # decide on a non-cloning read (as the launch guard does):
+                # write intent is taken only for what really changes.  Not
+                # txn.peek: its __debug__ fingerprint (two reprs an entity)
+                # would cost a status on its own a fifth of its time, and
+                # nothing below is handed the live entity to mutate
+                inst = txn._get("instances", task_id, for_write=False,
+                                clone=False)
+                if inst is None:
+                    out.append(False)
+                    continue
+                # any backend status proves the dispatch reached the
+                # cluster: the launch intent has served its purpose
+                # (guarded so the common no-intent case journals nothing)
+                if task_id in intents:
+                    txn.delete("intents", task_id)
+                old = inst.status
+                if old is new_status:
+                    # Redelivered status (k8s watch replays, mesos
+                    # re-sends): a pure no-op — must not overwrite
+                    # end_time/reason/exit_code.
+                    out.append(True)
+                    continue
+                if not machines.instance_transition_allowed(old, new_status):
+                    out.append(False)
+                    continue
+                inst = txn.instance_w(task_id)
+                inst.status = new_status
+                if hostname:
+                    # direct-mode backends report placement with the
+                    # first status
+                    inst.hostname = hostname
+                    if not inst.slave_id:
+                        inst.slave_id = hostname
+                if reason_code is not None:
+                    inst.reason_code = reason_code
+                if exit_code is not None:
+                    inst.exit_code = exit_code
+                if preempted:
+                    inst.preempted = True
+                if new_status in (InstanceStatus.SUCCESS,
+                                  InstanceStatus.FAILED):
+                    inst.end_time_ms = self.clock()
+                if new_status is InstanceStatus.RUNNING \
+                        and inst.mesos_start_time_ms is None:
+                    inst.mesos_start_time_ms = self.clock()
+                txn.event("instance-status", task_id=task_id,
+                          job=inst.job_uuid, old=old.value,
+                          new=new_status.value, reason=reason_code)
+                # job writeback: the Job is written only if its state moves
+                txn.recompute_job_state(inst.job_uuid)
+                out.append(True)
+            return out
+
+        n = len(updates)
+        _metrics.counter_inc("cook_status_txn")
+        _metrics.observe("cook_status_batch_size", float(n),
+                         buckets=_STATUS_BATCH_BUCKETS)
+        flight.recorder.note_status_txn(n)
+        return self.transact(_update_all)
 
     def clear_launch_intents(self, task_ids: List[str]) -> int:
         """Confirm backend dispatch: drop the launch intents for
@@ -1609,7 +1667,7 @@ class Store:
             if job.state is JobState.COMPLETED:
                 return True
             job.user_killed = True
-            txn.recompute_job_state(job)
+            txn.recompute_job_state(job_uuid)
             return True
 
         return self.transact(_kill)

@@ -62,8 +62,9 @@ PHASE_BY_SPAN = {
 # A key's value is the time of the spans mapped to that key only: when a
 # mapped span ends inside another mapped span that is not its declared
 # parent (DETAIL_PARENT), its time is carved out of the enclosing key —
-# a journal append inside the cluster-launch span counts as
-# apply_journal, not twice — so the parts of a key never overlap.
+# the journal append of the burst's one status transaction, inside the
+# cluster-launch span, counts as apply_journal, not twice — so the parts
+# of a key never overlap.
 DETAIL_BY_SPAN = {
     # top level: these partition a fused cycle (with "other")
     "fused.pools": "pools",
@@ -218,6 +219,7 @@ class CycleRecord:
                  "device", "wait_ms", "overrun_ms", "gc_ms",
                  "flush_audit_ms", "cpu_ms", "blocked_ms", "lock_holder",
                  "offcpu_ms", "background_ms", "staged_tx", "pipeline_lag_ms",
+                 "status_txns", "status_updates",
                  "_lock_wait_max", "_thread", "_cpu0", "_t0")
 
     def __init__(self, seq: int, kind: str):
@@ -301,6 +303,11 @@ class CycleRecord:
         # and how long ago that stage began (sched/pipeline.py)
         self.staged_tx: Optional[int] = None
         self.pipeline_lag_ms = 0.0
+        # status transactions committed from inside this record, and the
+        # entries they carried (Store.update_instance_statuses): a launch
+        # burst acknowledged in one transaction reads 1 and the burst
+        self.status_txns = 0
+        self.status_updates = 0
         self._lock_wait_max = 0.0
         self._thread = threading.get_ident()
         self._cpu0 = time.thread_time()
@@ -373,6 +380,8 @@ class CycleRecord:
                               for k, v in self.background_ms.items()},
             "staged_tx": self.staged_tx,
             "pipeline_lag_ms": round(self.pipeline_lag_ms, 3),
+            "status_txns": self.status_txns,
+            "status_updates": self.status_updates,
             "error": self.error,
         }
 
@@ -573,6 +582,16 @@ class FlightRecorder:
         if rec is not None:
             rec.staged_tx = int(staged_tx)
             rec.pipeline_lag_ms = float(lag_ms)
+
+    def note_status_txn(self, entries: int) -> None:
+        """One status transaction of ``entries`` updates committed from
+        inside the current record (state/store.py); outside a record it
+        is one contextvar read."""
+        rec = _current_record.get()
+        if rec is not None:
+            with self._lock:
+                rec.status_txns += 1
+                rec.status_updates += int(entries)
 
     def note_delta(self, rows: int) -> None:
         """Delta rows scatter-applied into the device-resident pack this

@@ -132,9 +132,9 @@ def _annotation(name: str):
 
 def cycle_time(name: str, seconds: float) -> None:
     """Hand an already-measured duration to the current cycle's record
-    under a span NAME, without minting a span: for a wait that happens a
-    thousand times a cycle (the group-commit wait of every status
-    transaction), where a span each would cost more than the wait tells.
+    under a span NAME, without minting a span: for a wait every
+    transaction of every thread passes through (the group-commit wait),
+    where a span each would cost more than the wait tells.
     It goes through the same name -> key table as a finished span
     (flight.DETAIL_BY_SPAN), nesting carve-out included; outside a cycle
     it is one contextvar read."""

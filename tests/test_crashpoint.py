@@ -35,6 +35,7 @@ class TestSmoke:
         assert legs["byte-boundary"] > 0
         assert legs["corruption"] > 0
         assert legs["checkpoint"] >= 3
+        assert legs["launch-ack"] == 2      # refund and adopt
 
     def test_workload_script_is_deterministic(self):
         assert build_ops(3) == build_ops(3)

@@ -823,7 +823,7 @@ def test_names_the_yardstick_reads_are_stable():
                   "flush_audit_ms",
                   "blocked_ms", "offcpu_ms", "background_ms",
                   "pipeline_lag_ms", "staged_tx", "cpu_ms", "lock_holder",
-                  "pools"):
+                  "pools", "status_txns", "status_updates"):
         assert field in doc, field
     by_key = {}
     for span, key in flight.DETAIL_BY_SPAN.items():

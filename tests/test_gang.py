@@ -947,17 +947,21 @@ class TestGangStatus:
         store, cluster, sched = make_system(n_hosts=2)
         make_gang(store, size=2)
         held = []
-        orig = FakeCluster._emit
+        # the launch's acknowledgements arrive as one batch: hold m1's
+        orig = FakeCluster._emit_statuses
 
-        def hold_m1_running(self, task_id, status, reason_code, **kw):
-            inst = store.instance(task_id)
-            if inst is not None and inst.job_uuid == "g1-m1" \
-                    and status is InstanceStatus.RUNNING:
-                held.append((task_id, status, reason_code, kw))
-                return
-            orig(self, task_id, status, reason_code, **kw)
+        def hold_m1_running(self, updates):
+            passed = []
+            for u in updates:
+                inst = store.instance(u[0])
+                if inst is not None and inst.job_uuid == "g1-m1" \
+                        and u[1] is InstanceStatus.RUNNING:
+                    held.append(u)
+                else:
+                    passed.append(u)
+            orig(self, passed)
 
-        cluster._emit = hold_m1_running.__get__(cluster)
+        cluster._emit_statuses = hold_m1_running.__get__(cluster)
         try:
             r = step(sched)["default"]
             assert len(r.launched_task_ids) == 2
@@ -968,11 +972,11 @@ class TestGangStatus:
             assert store.job("g1-m0").state is JobState.COMPLETED
             assert not sched._gang_barrier["g1"]["released"]
             # the held member finally reaches RUNNING
-            for task_id, status, reason_code, kw in held:
-                orig(cluster, task_id, status, reason_code, **kw)
+            assert len(held) == 1
+            orig(cluster, held)
             sched.flush_status_updates()
         finally:
-            del cluster._emit
+            del cluster._emit_statuses
         assert sched._gang_barrier["g1"]["released"]
         assert gang_status(store, store.group("g1"))["barrier"] \
             == "released"
