@@ -582,8 +582,7 @@ def drive_daemon(args, probe: dict, workdir: str) -> dict:
         checks["health_device_is_probe_platform"] = (
             (health.get("device") or {}).get("platform")
             == probe["platform"])
-        # "auto" resolves to the fused XLA cycle (the megakernel does
-        # not lower on the chip; CHANGES.md PR 21)
+        # "auto" resolves to the fused XLA cycle
         checks["every_cycle_path_fused"] = paths == ["fused"]
         checks["no_cycle_faults"] = all(
             not c["faults"] and not c["error"] for c in cycles)

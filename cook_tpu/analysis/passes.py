@@ -449,9 +449,9 @@ def pallas_module_constants(path: Path, relpath: str, tree: ast.Module,
     references it — it pins a device buffer for the process lifetime,
     breaks interpret/compiled parity across backends, and (on TPU) is
     constant-folded into the Mosaic binary where a python literal would
-    have stayed a scalar.  ops/pallas_match.py documents the pitfall by
-    hand (`_BIG = 2**31 - 1  # python literal ...`); this pass enforces
-    it for every pallas module (ISSUE 14 satellite)."""
+    have stayed a scalar (`_BIG = 2**31 - 1`).  The tree holds no
+    pallas module today; the pass costs nothing at run time and guards
+    the next one (ISSUE 14 satellite)."""
     name = Path(relpath).name
     if not (relpath.startswith("ops/") and name.startswith("pallas_")
             and name.endswith(".py")):

@@ -24,12 +24,7 @@ class MatcherConfig:
     # large-J backend selection is automatic per pool size);
     # "tpu-greedy" = bit-exact greedy scan kernel; "tpu-auction" = top-K
     # adaptive auction + waterfill tail; "tpu-waterfill" = prefix-packing
-    # kernel with no J x H work at all; "cpu" = numpy fallback;
-    # "tpu-megakernel" = single-launch Pallas fused cycle (rank +
-    # admission + match + gang reduce in one kernel, ops/pallas_cycle.py;
-    # interpret-mode on CPU — bit-identical to the fused XLA driver).
-    # An explicit pin only: "auto" never selects it, and a pin whose
-    # kernel does not lower on the device raises instead of degrading.
+    # kernel with no J x H work at all; "cpu" = numpy fallback.
     backend: str = "auto"
     auto_large_j_threshold: int = 2000
     # what "auto" optimizes for ABOVE the threshold
@@ -90,9 +85,11 @@ class MatcherConfig:
                 labels={"knob": "matcher.backend",
                         "value": "tpu-auction-pallas"})
             self.backend = "tpu-auction"
-        if self.backend not in ("auto", "tpu-greedy", "tpu-auction",
-                                "tpu-waterfill", "tpu-megakernel", "cpu"):
-            raise ValueError(f"unknown matcher backend {self.backend!r}")
+        backends = ("auto", "tpu-greedy", "tpu-auction", "tpu-waterfill",
+                    "cpu")
+        if self.backend not in backends:
+            raise ValueError(f"unknown matcher backend {self.backend!r} "
+                             f"({'|'.join(backends)})")
         if self.auto_packing not in ("throughput", "tight"):
             raise ValueError(f"unknown auto_packing "
                              f"{self.auto_packing!r} (throughput|tight)")
@@ -1073,13 +1070,11 @@ class Config:
     # faults.  Decision-identical to the rebuild path; only engages with
     # columnar_index=True (the compact wire form).
     resident_pack: bool = True
-    # quantized compact wire (ops/quant.py; docs/PERFORMANCE.md wire
-    # negotiation table): narrow each per-cycle h2d field to the
-    # smallest dtype its domain admits THIS cycle — delta-coded i8/i16
-    # rows, u16 fixed-point host stacks, bitpacked host flags — but only
-    # where the round trip is bit-exact; overflowing domains ship wide
-    # automatically.  Engages on the megakernel dispatch path and the
-    # delta feed's scatter values; never changes a decision.
+    # value codec of the resident pack's delta scatter (ops/delta.py
+    # PackDeltaApplier.stage): row values ship as i8 / i16 deltas against
+    # their target position where every delta of the batch fits, else
+    # wide i32 (counted, cook_quant_wide_fallback_total); lossless, never
+    # changes a decision.  False ships wide always.
     quantized_wire: bool = True
     default_pool: str = "default"
     # pool-regex -> matcher config, first match wins (config.clj:798)

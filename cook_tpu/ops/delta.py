@@ -31,6 +31,7 @@ from typing import Dict, Optional, Tuple
 
 import numpy as np
 
+from ..utils.metrics import registry
 from . import telemetry
 from .padding import bucket
 
@@ -47,6 +48,18 @@ FLAG_USER_FIRST = 16   # first row of a user segment
 # executable is reused across cycles (min floor keeps tiny deltas from
 # compiling log2(min) variants)
 _DELTA_MIN_BUCKET = 256
+
+# value codec tags of the delta scatter (static in the executable's key)
+ROWS_WIDE = 0    # i32 absolute rows, no transform
+ROWS_I16 = 1     # int16 delta vs position
+ROWS_I8 = 2      # int8 delta vs position
+
+
+def note_wide(field: str) -> None:
+    """Count one lossless-narrow negotiation that fell back to the wide
+    form (the contract: quantization is lossless-or-wide, and wide is
+    always COUNTED so an operator can see it never engaging)."""
+    registry.counter_inc("cook_quant_wide_fallback", labels={"field": field})
 
 
 def pack_flags(pending: np.ndarray, valid: np.ndarray,
@@ -101,10 +114,9 @@ class PackDeltaApplier:
     memory so the resident pack never doubles its footprint during the
     update.
 
-    The scatter's value payload rides the quantized wire's rows codec
-    (ops/quant.py): with ``quantize=True`` row values are coded as
-    deltas against their own target position, so a steady-state scatter
-    row costs 4 (idx) + 1-2 (value) + 1 (flag) bytes instead of 9 —
+    With ``quantize=True`` (``Config.quantized_wire``) row values are
+    coded as deltas against their own target position, so a steady-state
+    scatter row costs 4 (idx) + 1-2 (value) + 1 (flag) bytes instead of 9 —
     losslessly, with automatic wide fallback when a batch's deltas
     overflow the narrow width."""
 
@@ -118,7 +130,6 @@ class PackDeltaApplier:
         if fn is None:
             import jax
             import jax.numpy as jnp
-            from .quant import ROWS_WIDE
             if self._donate is None:
                 self._donate = _donate_default()
             T = shape[-1]
@@ -152,7 +163,6 @@ class PackDeltaApplier:
         pipelined driver's stage-(k+1) h2d overlaps cycle k's in-flight
         kernel (the double-buffering half of ISSUE 14's wire work)."""
         import jax.numpy as jnp
-        from . import quant as _q
         n_flat = int(np.prod(shape))
         T = int(shape[-1])
         k = int(idx.size)
@@ -161,20 +171,20 @@ class PackDeltaApplier:
             raise ValueError(f"delta larger than buffer ({k} > {n_flat})")
         idx_p = np.full(kb, n_flat, dtype=np.int32)  # OOB sentinel pad
         idx_p[:k] = idx
-        codec = _q.ROWS_WIDE
+        codec = ROWS_WIDE
         if quantize and k:
             delta = rows_vals.astype(np.int64) - (idx.astype(np.int64) % T)
             lo, hi = int(delta.min()), int(delta.max())
             if -128 <= lo and hi <= 127:
-                codec, dt = _q.ROWS_I8, np.int8
+                codec, dt = ROWS_I8, np.int8
             elif -32768 <= lo and hi <= 32767:
-                codec, dt = _q.ROWS_I16, np.int16
+                codec, dt = ROWS_I16, np.int16
             else:
                 # the lossless-or-wide contract counts EVERY wide
                 # fallback (an operator must be able to see the narrow
                 # path never engaging)
-                _q.note_wide("delta")
-        if codec != _q.ROWS_WIDE:
+                note_wide("delta")
+        if codec != ROWS_WIDE:
             rows_p = np.zeros(kb, dtype=dt)
             rows_p[:k] = delta.astype(dt)
         else:

@@ -38,7 +38,7 @@ and this pass only enforces the invariant.
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Callable, Dict, List, NamedTuple, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -86,11 +86,10 @@ class GangStats:
 
 def _topology_table(topo_names: List[Optional[str]], offers
                     ) -> Tuple[Dict[str, int], np.ndarray]:
-    """Topology code table shared by the host pack and the megakernel
-    wire: one row per distinct requested attribute, row 0 reserved for
-    "no topology request" (all zeros, never read through a required
-    gang).  Code assignment is offer-order deterministic, so the two
-    builders can never disagree on a domain's code."""
+    """Topology code table of the gang pack: one row per distinct
+    requested attribute, row 0 reserved for "no topology request" (all
+    zeros, never read through a required gang).  Code assignment is
+    offer-order deterministic."""
     attrs = sorted({a for a in topo_names if a})
     attr_row = {a: i + 1 for i, a in enumerate(attrs)}
     H = max(len(offers), 1)
@@ -103,56 +102,6 @@ def _topology_table(topo_names: List[Optional[str]], offers
             if v is not None:
                 host_topo[row, h] = codes.setdefault(v, len(codes))
     return attr_row, host_topo
-
-
-class GangWire(NamedTuple):
-    """Per-pool gang arrays staged PRE-dispatch for the megakernel's
-    fused gang_min-gated segment reduction (ops/pallas_cycle.py): the
-    same reduction inputs :class:`GangPack` carries, but keyed by TASK
-    ROW (sorted pack position) instead of candidate index, because the
-    kernel maps candidates to rows itself.  Satisfied elastic gangs'
-    grow members are excluded exactly as in :func:`build_gang_pack`
-    (gang_id -1 — the grow path places like singles)."""
-
-    gang_id: np.ndarray   # i32[T] by sorted pack position, -1 = none
-    gang_size: np.ndarray  # i32[G] reduction threshold (gang_min)
-    gang_attr: np.ndarray  # i32[G] row into host_topo, 0 = none
-    host_topo: np.ndarray  # i32[A, H]
-    uuids: List[str]       # gang segment -> group uuid
-
-
-def build_gang_wire(T: int, members_by_gang: Dict[str, List],
-                    groups_ctx: Dict[str, object], offers,
-                    satisfied=None) -> Optional[GangWire]:
-    """Gang wire for one packed pool (sched/fused._pack_pool_columnar's
-    ``members_by_gang``: group uuid -> [(task_row, job)]), or None when
-    the pool stages no reducible gang members this cycle."""
-    rows_by_gang = {
-        guuid: members for guuid, members in members_by_gang.items()
-        if getattr(groups_ctx.get(guuid), "gang", False)
-        and not (satisfied and guuid in satisfied)}
-    if not rows_by_gang:
-        return None
-    from ..state.schema import gang_bounds
-    gang_id = np.full(T, -1, dtype=np.int32)
-    uuids: List[str] = []
-    sizes: List[int] = []
-    topo_names: List[Optional[str]] = []
-    for guuid, members in rows_by_gang.items():
-        g = groups_ctx[guuid]
-        k = len(uuids)
-        uuids.append(guuid)
-        sizes.append(gang_bounds(g)[0])
-        topo_names.append(getattr(g, "gang_topology", None) or None)
-        for row, _job in members:
-            gang_id[row] = k
-    attr_row, host_topo = _topology_table(topo_names, offers)
-    gang_attr = np.array([attr_row.get(a, 0) if a else 0
-                          for a in topo_names], dtype=np.int32)
-    return GangWire(gang_id=gang_id,
-                    gang_size=np.array(sizes, dtype=np.int32),
-                    gang_attr=gang_attr, host_topo=host_topo,
-                    uuids=uuids)
 
 
 def build_gang_pack(jobs, groups: Dict[str, object], offers,
@@ -208,11 +157,9 @@ def build_gang_pack(jobs, groups: Dict[str, object], offers,
 
 # ------------------------------------------------------------------ device
 def gang_reduce_body(assign, gang_id, gang_size, gang_attr, host_topo):
-    """The pure (jit/pallas-composable) gang_min-gated segment
-    reduction: ONE home for the decision math, shared by the standalone
-    jitted kernel below AND the megakernel's fused gang stage
-    (ops/pallas_cycle.py) — the two paths must never drift (their
-    parity is test-asserted against reference_impl.gang_reduce)."""
+    """The pure gang_min-gated segment reduction the jitted kernel below
+    is built from (parity test-asserted against
+    reference_impl.gang_reduce)."""
     import jax
     import jax.numpy as jnp
     G = gang_size.shape[0]
@@ -287,8 +234,6 @@ def apply_gang_cycle(jobs, assign: np.ndarray, offers,
                      audit_trail=None,
                      audit_pool: Optional[str] = None,
                      satisfied=None,
-                     precomputed: Optional[Tuple[np.ndarray,
-                                                 np.ndarray]] = None,
                      ) -> Tuple[np.ndarray, Optional[GangStats]]:
     """The full per-cycle gang pass: reduce partial gangs to nothing and
     refill the freed capacity with still-unmatched group-less jobs.
@@ -303,25 +248,14 @@ def apply_gang_cycle(jobs, assign: np.ndarray, offers,
     gang_min — their waiting members bypass the reduction (grow path)
     and join the refill pool like group-less jobs (docs/GANG.md
     elasticity).
-
-    ``precomputed``: an ``(out, dropped)`` pair the megakernel's fused
-    gang stage already reduced on device (ops/pallas_cycle.py), aligned
-    with ``jobs``.  The reduction is skipped — it would recompute the
-    identical result (same math, parity-asserted) — while the rescue /
-    refill passes and stats run unchanged.  Callers pass it ONLY when
-    the candidate set the kernel saw is intact (no vanished jobs, no
-    reconcile drops, no group-placement resets since dispatch).
     """
     pack = build_gang_pack(jobs, groups, offers, satisfied=satisfied)
     if pack is None:
         return assign, None
     assign = np.asarray(assign, dtype=np.int32)
     with tracing.span("gang.reduce", gangs=len(pack.uuids),
-                      jobs=len(jobs), fused=precomputed is not None):
-        if precomputed is not None:
-            out = np.asarray(precomputed[0], dtype=np.int32).copy()
-            dropped = np.asarray(precomputed[1], dtype=bool).copy()
-        elif device:
+                      jobs=len(jobs)):
+        if device:
             try:
                 out, dropped = gang_reduce_kernel(assign, pack)
             except telemetry.KernelBuildError:

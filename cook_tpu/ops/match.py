@@ -229,16 +229,6 @@ def auction_match_kernel(inp: MatchInputs, *, num_prefs: int = 16,
     return assign, avail
 
 
-# auction_match_pallas (a dense-mask auction whose preference build ran
-# as a blockwise Pallas kernel) was REMOVED in round 5: across three
-# rounds of on-chip measurement it never beat the XLA auction at any
-# scale that fits a dense mask (r4 capture: 295 ms vs 50 ms p50 at
-# 1k x 50k; 2550 ms vs 736 ms compiled at 10k x 50k) and its ~20 s
-# first-call compile burned bench deadline every round.  The regime a
-# dense kernel cannot reach at all (structured masks at 100k-1M jobs)
-# is served by pallas_match.topk_prefs_structured, which stays.
-
-
 def _auction_rounds(inp: MatchInputs, pref_fit: jax.Array,
                     pref_host: jax.Array, num_rounds: int,
                     assign: jax.Array, avail: jax.Array

@@ -2909,10 +2909,10 @@ class _Handler(BaseHTTPRequestHandler):
         least one token — so answer the 429 before the body is parsed.
         A stampeding client then costs the server one header parse and
         a raw body drain, not a JSON decode + validation pass; the
-        saved CPU is exactly the goodput retained under overload
-        (bench.py ``overload`` leg).  Behavior-equivalent to the
-        ``_admit_submission`` bucket check, just earlier and cheaper:
-        a non-empty bucket falls through to the full front door."""
+        saved CPU is exactly the goodput retained under overload.
+        Behavior-equivalent to the ``_admit_submission`` bucket check,
+        just earlier and cheaper: a non-empty bucket falls through to
+        the full front door."""
         rl = self.api.rate_limits.job_submission
         if not getattr(rl, "enforce", False):
             return False

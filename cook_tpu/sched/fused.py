@@ -122,12 +122,6 @@ class _PackedPool:
         self.offensive: List[Job] = []
         self.n_tasks = 0
         self.n_hosts = 0
-        # megakernel gang wire (ops/gang.build_gang_wire): per-task gang
-        # segments staged PRE-dispatch so the kernel's fused gang stage
-        # reduces in-launch; plus the pack-time satisfied-elastic set the
-        # apply path compares against before trusting the fused verdicts
-        self.gang_wire = None
-        self.gang_satisfied: frozenset = frozenset()
 
 
 class _StagedCycle:
@@ -149,10 +143,10 @@ class _StagedGroup:
     upload bytes)."""
 
     __slots__ = ("gpu_mode", "group", "inp", "structured", "cap", "T", "H",
-                 "stage_ms", "resident", "mega", "mega_fallback")
+                 "stage_ms", "resident")
 
     def __init__(self, *, gpu_mode, group, inp, structured, cap, T, H,
-                 stage_ms, resident=False, mega=None):
+                 stage_ms, resident=False):
         self.gpu_mode = gpu_mode
         self.group = group
         self.inp = inp
@@ -162,13 +156,6 @@ class _StagedGroup:
         self.H = H
         self.stage_ms = stage_ms
         self.resident = resident
-        # megakernel dispatch payload (ops/pallas_cycle.MegaCycleWire +
-        # negotiated codec tags + the wire-rebuild thunk the fused-XLA
-        # fallback uses); None = XLA cycle.  ``mega_fallback`` marks a
-        # group re-dispatched after a Pallas failure (its h2d was
-        # already charged for the wire)
-        self.mega = mega
-        self.mega_fallback = False
 
 
 class _GroupDispatch:
@@ -241,11 +228,6 @@ class FusedCycleDriver:
         # cached [T]-sized arrays wholesale instead of rebuilding them
         self._delta_cid: Optional[int] = None
         self._pack_cache: Dict[str, Dict] = {}
-        # sticky quantized-wire scales (ops/quant.py): the negotiated
-        # fixed-point scale tuples are STATIC jit keys of the
-        # megakernel, so they persist across cycles while they still
-        # round-trip (renegotiation only on an exactness miss)
-        self._mega_scales: Dict[str, tuple] = {}
 
     # ------------------------------------------------------------------ mesh
     def mesh(self):
@@ -382,9 +364,8 @@ class FusedCycleDriver:
 
     def _warm_cycle(self, gm: bool, P: int, T: int, H: int, U: int,
                     mir: int) -> int:
-        """One zero-world execution of the compact fused cycle (and of
-        the megakernel where a pool pins it) per distinct cap bucket at
-        [P, T] x H; returns the executions."""
+        """One zero-world execution of the compact fused cycle per distinct
+        cap bucket at [P, T] x H; returns the executions."""
         import jax
         import jax.numpy as jnp
 
@@ -423,51 +404,6 @@ class FusedCycleDriver:
                 jax.block_until_ready(fn(inp).n_queue)
                 runs += 1
             _count_warmup("fused.pool_cycle", runs)
-            mega_backends = {self.config.default_matcher.backend}
-            mega_backends.update(
-                mc.backend for _rx, mc in self.config.pool_matchers)
-            if self.mesh().size == 1 and "tpu-megakernel" in mega_backends:
-                # warm the MEGAKERNEL executables too (the live path for
-                # a pinned pool): wide rows for the resident wire,
-                # i8-delta for the quantized rebuild norm.  Residual cold
-                # traces remain for the first negotiated fixed-point
-                # scale tuple and the first gang-bearing bucket — sticky
-                # scales make each a one-time cost.
-                from ..ops import pallas_cycle
-                from ..ops import quant as _quant
-                gang = pallas_cycle.empty_gang_wire(P, T, H)
-                host_bits = jnp.zeros((P, 2, (H + 7) // 8),
-                                      dtype=jnp.uint8)
-                codecs = [(jnp.int32, _quant.ROWS_WIDE)]
-                if self.config.quantized_wire:
-                    codecs.append((jnp.int8, _quant.ROWS_I8))
-                mega_runs = 0
-                for rdt, rcodec in codecs:
-                    wire = pallas_cycle.MegaCycleWire(
-                        rows=jnp.zeros((P, T), dtype=rdt),
-                        flags=inp.flags, res_base=inp.res_base,
-                        disk_base=inp.disk_base, tokens_u=inp.tokens_u,
-                        shares_u=inp.shares_u, quota_u=inp.quota_u,
-                        num_considerable=inp.num_considerable,
-                        pool_quota=inp.pool_quota,
-                        group_quota=inp.group_quota,
-                        group_id=inp.group_id, host_bits=host_bits,
-                        exc_rows=inp.exc_rows, exc_mask=inp.exc_mask,
-                        avail=inp.avail, capacity=inp.capacity,
-                        gang_id=jnp.asarray(gang[0]),
-                        gang_size=jnp.asarray(gang[1]),
-                        gang_attr=jnp.asarray(gang[2]),
-                        host_topo=jnp.asarray(gang[3]))
-                    for cap in caps:
-                        jax.block_until_ready(pallas_cycle.megacycle(
-                            wire, gpu_mode=gm,
-                            max_over_quota_jobs=self.config
-                            .max_over_quota_jobs,
-                            considerable_cap=cap,
-                            rows_codec=rcodec).n_queue)
-                        mega_runs += 1
-                _count_warmup("pallas.megacycle", mega_runs)
-                runs += mega_runs
             sp.set_tag("runs", runs)
         return runs
 
@@ -892,25 +828,12 @@ class FusedCycleDriver:
                     gang_members.setdefault(job.group, []).append(
                         (int(i), job))
         tok_by_user = dict(zip(users, pp.tokens_u.tolist()))
-        satisfied = self._gang_cohort_admission(
+        self._gang_cohort_admission(
             pool, pp.ctx.groups if pp.ctx is not None else {},
             gang_members, launch_ok,
             (lambda u: tok_by_user.get(u, 0.0))
             if launch_rl.enforce else None,
             spec_masked=spec_masked)
-        if gang_members and self._pool_mega_candidate(pool.name):
-            # megakernel gang wire: the same membership the host pass
-            # would derive from the candidates, staged pre-dispatch so
-            # the fused gang stage reduces in-launch (ops/pallas_cycle).
-            # Built only when this pool can actually dispatch mega —
-            # the O(T) wire would otherwise be allocated every cycle
-            # just to be dropped
-            from ..ops.gang import build_gang_wire
-            pp.gang_wire = build_gang_wire(
-                T, gang_members,
-                pp.ctx.groups if pp.ctx is not None else {}, pp.offers,
-                satisfied=satisfied)
-            pp.gang_satisfied = frozenset(satisfied or ())
 
         # the admission bools + user-segment boundaries, packed into one
         # wire byte per task (user_rank/first_idx re-derive on device)
@@ -1112,7 +1035,7 @@ class FusedCycleDriver:
     def _gang_cohort_admission(self, pool: Pool, groups_ctx: Dict,
                                members_by_gang: Dict,
                                launch_ok: np.ndarray,
-                               net_tokens, spec_masked=None) -> set:
+                               net_tokens, spec_masked=None) -> None:
         """Host-side gang-cohort admission for the fused pack paths
         (mirrors Matcher.considerable_jobs, docs/GANG.md): a gang that
         cannot clear this cycle's throttles WHOLE is withheld whole by
@@ -1129,9 +1052,7 @@ class FusedCycleDriver:
         ``members_by_gang``: group uuid -> [(task_row, job)] for the
         pack's pending gang members; ``net_tokens``: user -> launch
         tokens net of the pipeline's token_delta, or None when the
-        limiter is off.  Returns the pack-time SATISFIED elastic-gang
-        set (the megakernel gang wire excludes those gangs exactly like
-        the host reduction does)."""
+        limiter is off."""
         deferred_why: Dict[str, Dict] = {}
         skipped: List = []
         satisfied = set()
@@ -1251,7 +1172,6 @@ class FusedCycleDriver:
         if skipped:
             _audit.note_skips(self.store.audit,
                               {"gang-deferred": skipped}, pool=pool.name)
-        return satisfied
 
     def _pack_caps(self, pp: _PackedPool, pool: Pool) -> None:
         """Backoff cap + pool/quota-group caps (shared by both pack paths)."""
@@ -1626,54 +1546,31 @@ class FusedCycleDriver:
                     shares_u_p[i, :pp.shares_u.shape[0]] = pp.shares_u
                     quota_u_p[i, :pp.quota_u.shape[0]] = pp.quota_u
                     tokens_u_p[i, :pp.tokens_u.shape[0]] = pp.tokens_u
-                mega = None
-                use_mega = self._megakernel_selected(group)
                 if self.config.resident_pack:
                     # DEVICE-RESIDENT wire arrays: steady state ships only
                     # the scatter delta, not the [P, T] world (ISSUE 7)
                     key = (tuple(pp.pool.name for pp in group), P, T)
                     rows_dev, flags_dev = self._sync_resident(
                         gpu_mode, key, rows_p, flags_p, epoch)
-                    resident = True
-                elif use_mega and self.config.quantized_wire:
-                    # the quantized wire carries rows/flags narrow; no wide
-                    # upload happens at all on this path
-                    rows_dev = flags_dev = None
-                    resident = False
                 else:  # rebuild mode: dispatch_group accounts the upload
                     rows_dev = jnp.asarray(rows_p)
                     flags_dev = jnp.asarray(flags_p)
-                    resident = False
-                if use_mega:
-                    inp = None
-                    mega = self._stage_mega(
-                        group, rows_p=rows_p, flags_p=flags_p,
-                        rows_dev=rows_dev, flags_dev=flags_dev,
-                        mir_res=mir_res, mir_disk=mir_disk,
-                        tokens_u_p=tokens_u_p, shares_u_p=shares_u_p,
-                        quota_u_p=quota_u_p, scalars=scalars,
-                        host_gpu_p=host_gpu_p, host_blocked_p=host_blocked_p,
-                        exc_rows_p=exc_rows_p, exc_mask_p=exc_mask_p,
-                        avail_p=avail_p, cap_p=cap_p, T=T, H=H, P=P,
-                        resident=resident)
-                else:
-                    inp = CompactPoolCycleInputs(
-                        rows=rows_dev,
-                        flags=flags_dev,
-                        res_base=mir_res,
-                        disk_base=mir_disk,
-                        tokens_u=jnp.asarray(tokens_u_p),
-                        shares_u=jnp.asarray(shares_u_p),
-                        quota_u=jnp.asarray(quota_u_p),
-                        **scalars,
-                        host_gpu=jnp.asarray(host_gpu_p),
-                        host_blocked=jnp.asarray(host_blocked_p),
-                        exc_rows=jnp.asarray(exc_rows_p),
-                        exc_mask=jnp.asarray(exc_mask_p),
-                        avail=jnp.asarray(avail_p),
-                        capacity=jnp.asarray(cap_p))
+                inp = CompactPoolCycleInputs(
+                    rows=rows_dev,
+                    flags=flags_dev,
+                    res_base=mir_res,
+                    disk_base=mir_disk,
+                    tokens_u=jnp.asarray(tokens_u_p),
+                    shares_u=jnp.asarray(shares_u_p),
+                    quota_u=jnp.asarray(quota_u_p),
+                    **scalars,
+                    host_gpu=jnp.asarray(host_gpu_p),
+                    host_blocked=jnp.asarray(host_blocked_p),
+                    exc_rows=jnp.asarray(exc_rows_p),
+                    exc_mask=jnp.asarray(exc_mask_p),
+                    avail=jnp.asarray(avail_p),
+                    capacity=jnp.asarray(cap_p))
             else:
-                mega = None
                 cmask_p = np.zeros((P, T, H), dtype=bool)
                 for i, pp in enumerate(group):
                     cmask_p[i, :pp.n_tasks, :pp.cmask.shape[1]] = pp.cmask
@@ -1710,242 +1607,7 @@ class FusedCycleDriver:
                             structured=structured, cap=cap, T=T, H=H,
                             stage_ms=stage_ms,
                             resident=structured and bool(
-                                self.config.resident_pack),
-                            mega=mega)
-
-    # ------------------------------------------------------------ megakernel
-    def _megakernel_selected(self, group: List[_PackedPool]) -> bool:
-        """Route this dispatch group through the single-launch Pallas
-        megakernel (ops/pallas_cycle.py)?  Only on an explicit
-        ``tpu-megakernel`` pin: a pin on ANY pool takes the whole group
-        there (co-grouped ``auto`` pools ride along, decisions are
-        parity-identical).  ``auto`` never selects it — the kernel has
-        only ever run interpreted and Mosaic refuses it on the v5e
-        (CHANGES.md PR 21), so the fused XLA cycle is what ``auto``
-        means on every platform until ROADMAP S3 lands a kernel that
-        compiles.  The kernel serves the compact structured wire on a
-        single-device mesh."""
-        if not self.config.columnar_index or self.mesh().size != 1:
-            return False
-        backends = {self.config.matcher_for_pool(pp.pool.name).backend
-                    for pp in group}
-        return "tpu-megakernel" in backends \
-            and backends <= {"auto", "tpu-megakernel"}
-
-    def _pool_mega_candidate(self, pool_name: str) -> bool:
-        """Pack-time gate for the gang-wire build: could this pool's
-        dispatch group take the megakernel path?  A cheap per-pool
-        approximation of :meth:`_megakernel_selected` — an ``auto`` pool
-        riding a pinned group dispatches mega WITHOUT its own wire and
-        simply keeps the host gang reduction (the apply path requires
-        ``pp.gang_wire`` before trusting fused verdicts).  The converse
-        imprecision is accepted too: a pinned pool co-grouped with a
-        non-mega pool (mixed explicit backends, exotic) stages a wire
-        its group never dispatches — wasted staging, never a wrong
-        decision; group composition is a DRU-mode fact this pack-time
-        gate cannot see."""
-        if not self.config.columnar_index or self.mesh().size != 1:
-            return False
-        return self.config.matcher_for_pool(pool_name).backend \
-            == "tpu-megakernel"
-
-    def _stage_mega(self, group, *, rows_p, flags_p, rows_dev, flags_dev,
-                    mir_res, mir_disk, tokens_u_p, shares_u_p, quota_u_p,
-                    scalars, host_gpu_p, host_blocked_p, exc_rows_p,
-                    exc_mask_p, avail_p, cap_p, T, H, P, resident):
-        """Build the megakernel dispatch payload for one staged group:
-        the negotiated (quantized or wide) wire, the padded gang arrays,
-        the h2d byte account, and a thunk that rebuilds the fused-XLA
-        CompactPoolCycleInputs if the Pallas dispatch fails."""
-        import jax.numpy as jnp
-        from ..ops import pallas_cycle, quant
-        from ..ops.padding import bucket as _bucket
-        quantize = bool(self.config.quantized_wire)
-        h2d = 0
-        # rows/flags: device-resident buffers cost nothing this cycle;
-        # rebuild mode ships them — delta-coded narrow when they fit
-        rows_codec = quant.ROWS_WIDE
-        if resident:
-            w_rows, w_flags = rows_dev, flags_dev
-        elif quantize:
-            # negotiate over an IDENTITY-padded copy: rows_p zero-pads
-            # its bucket tail, and a zero at position t would read as
-            # delta -t — blowing the narrow range for any pool not
-            # exactly filling its bucket.  Padding rows are fully
-            # masked downstream (flags 0, and every consumer multiplies
-            # by valid/pending), so the decoded identity values are
-            # inert and their deltas are 0: the REAL rows decide the
-            # width.
-            rows_q = rows_p.copy()
-            iota = np.arange(T, dtype=rows_q.dtype)
-            for i in range(P):
-                n = group[i].n_tasks if i < len(group) else 0
-                rows_q[i, n:] = iota[n:]
-            qr = quant.quantize_rows(rows_q)
-            rows_codec = qr.codec
-            w_rows = jnp.asarray(qr.data)
-            w_flags = jnp.asarray(flags_p)
-            h2d += qr.nbytes + flags_p.nbytes
-        else:
-            w_rows = (jnp.asarray(rows_p) if rows_dev is None else rows_dev)
-            w_flags = (jnp.asarray(flags_p) if flags_dev is None
-                       else flags_dev)
-            h2d += rows_p.nbytes + flags_p.nbytes
-        avail_scale = cap_scale = 0.0
-        if quantize:
-            # STICKY scales: the tuple is a static jit key of the
-            # megakernel, so reuse the last negotiated scale while it
-            # still round-trips — renegotiating to the finest exact
-            # scale every cycle would retrace on every domain shift
-            qa = quant.quantize_fixed(
-                avail_p, "avail", prefer=self._mega_scales.get("avail"))
-            qc = quant.quantize_fixed(
-                cap_p, "capacity",
-                prefer=self._mega_scales.get("capacity"))
-            avail_scale, cap_scale = qa.scale, qc.scale
-            if qa.scale != 0.0:
-                self._mega_scales["avail"] = qa.scale
-            if qc.scale != 0.0:
-                self._mega_scales["capacity"] = qc.scale
-            w_avail, w_cap = jnp.asarray(qa.data), jnp.asarray(qc.data)
-            h2d += qa.nbytes + qc.nbytes
-        else:
-            w_avail, w_cap = jnp.asarray(avail_p), jnp.asarray(cap_p)
-            h2d += avail_p.nbytes + cap_p.nbytes
-        host_bits = np.stack([quant.pack_bits(host_gpu_p),
-                              quant.pack_bits(host_blocked_p)], axis=1)
-        h2d += (host_bits.nbytes + exc_rows_p.nbytes + exc_mask_p.nbytes
-                + tokens_u_p.nbytes + shares_u_p.nbytes + quota_u_p.nbytes)
-        # gang wire, padded across the group (structural no-op rows for
-        # gang-free pools: id -1 everywhere, unreachable padding sizes)
-        wires = [pp.gang_wire for pp in group]
-        if any(w is not None for w in wires):
-            G = _bucket(max(len(w.gang_size) for w in wires
-                            if w is not None), minimum=8)
-            A = _bucket(max(w.host_topo.shape[0] for w in wires
-                            if w is not None), minimum=1)
-            gang_id_p = np.full((P, T), -1, dtype=np.int32)
-            gang_size_p = np.full((P, G), 2 ** 30, dtype=np.int32)
-            gang_attr_p = np.zeros((P, G), dtype=np.int32)
-            host_topo_p = np.full((P, A, H), -1, dtype=np.int32)
-            host_topo_p[:, 0, :] = 0
-            for i, w in enumerate(wires):
-                if w is None:
-                    continue
-                gang_id_p[i, :w.gang_id.shape[0]] = w.gang_id
-                gang_size_p[i, :w.gang_size.shape[0]] = w.gang_size
-                gang_attr_p[i, :w.gang_attr.shape[0]] = w.gang_attr
-                a, hh = w.host_topo.shape
-                host_topo_p[i, :a, :hh] = w.host_topo
-        else:
-            gang_id_p, gang_size_p, gang_attr_p, host_topo_p = \
-                pallas_cycle.empty_gang_wire(P, T, H)
-        h2d += (gang_id_p.nbytes + gang_size_p.nbytes
-                + gang_attr_p.nbytes + host_topo_p.nbytes)
-        wire = pallas_cycle.MegaCycleWire(
-            rows=w_rows, flags=w_flags, res_base=mir_res,
-            disk_base=mir_disk, tokens_u=jnp.asarray(tokens_u_p),
-            shares_u=jnp.asarray(shares_u_p),
-            quota_u=jnp.asarray(quota_u_p),
-            num_considerable=scalars["num_considerable"],
-            pool_quota=scalars["pool_quota"],
-            group_quota=scalars["group_quota"],
-            group_id=scalars["group_id"],
-            host_bits=jnp.asarray(host_bits),
-            exc_rows=jnp.asarray(exc_rows_p),
-            exc_mask=jnp.asarray(exc_mask_p),
-            avail=w_avail, capacity=w_cap,
-            gang_id=jnp.asarray(gang_id_p),
-            gang_size=jnp.asarray(gang_size_p),
-            gang_attr=jnp.asarray(gang_attr_p),
-            host_topo=jnp.asarray(host_topo_p))
-
-        def build_fused_inp():
-            # reconstruct the fused-XLA inputs FROM THE WIRE (every
-            # codec is lossless by contract), not from captured host
-            # staging arrays: the closure would otherwise pin tens of
-            # MB of [P,T]/[P,E,H] host memory for the staged group's
-            # whole lifetime to serve a fallback that runs only on a
-            # Pallas dispatch failure.  Rows decode identity-padded
-            # (vs the original zero padding) — padding rows are
-            # flag-masked in expand_compact exactly as in the kernel,
-            # so decisions are unchanged.
-            from ..parallel.sharded import CompactPoolCycleInputs
-            if resident:
-                rd, fd = rows_dev, flags_dev
-            else:
-                rd = jnp.asarray(quant.expand_rows(quant.QuantizedRows(
-                    rows_codec, np.asarray(wire.rows))))
-                fd = wire.flags
-            bits = np.asarray(wire.host_bits)
-            avail_f = (jnp.asarray(quant.expand_fixed(quant.QuantizedFixed(
-                avail_scale, np.asarray(wire.avail))))
-                if avail_scale != 0.0 else wire.avail)
-            cap_f = (jnp.asarray(quant.expand_fixed(quant.QuantizedFixed(
-                cap_scale, np.asarray(wire.capacity))))
-                if cap_scale != 0.0 else wire.capacity)
-            return CompactPoolCycleInputs(
-                rows=rd, flags=fd, res_base=mir_res, disk_base=mir_disk,
-                tokens_u=wire.tokens_u, shares_u=wire.shares_u,
-                quota_u=wire.quota_u, **scalars,
-                host_gpu=jnp.asarray(quant.unpack_bits(bits[:, 0], H)),
-                host_blocked=jnp.asarray(
-                    quant.unpack_bits(bits[:, 1], H)),
-                exc_rows=wire.exc_rows, exc_mask=wire.exc_mask,
-                avail=avail_f, capacity=cap_f)
-
-        return {"wire": wire, "rows_codec": rows_codec,
-                "avail_scale": avail_scale, "cap_scale": cap_scale,
-                "h2d_bytes": int(h2d), "build_fused_inp": build_fused_inp}
-
-    def _dispatch_mega(self, sg: "_StagedGroup") -> "_GroupDispatch":
-        """Single-launch dispatch of a megakernel-staged group; a RUNTIME
-        fault on an executable that has run before (device loss,
-        injected fault) degrades to the fused XLA cycle rebuilt from the
-        same staged arrays (docs/ROBUSTNESS.md).  A trace, lowering or
-        compile error raises (ops/telemetry.KernelBuildError)."""
-        from ..ops import pallas_cycle
-        from ..utils.metrics import registry
-        m = sg.mega
-        telemetry.count_transfer("h2d", m["h2d_bytes"])
-        try:
-            with tracing.span("fused.dispatch", pools=len(sg.group),
-                              tasks=sg.T, hosts=sg.H, gpu=sg.gpu_mode,
-                              stage_ms=sg.stage_ms, megakernel=True):
-                res = pallas_cycle.megacycle(
-                    m["wire"], gpu_mode=sg.gpu_mode,
-                    max_over_quota_jobs=self.config.max_over_quota_jobs,
-                    considerable_cap=min(sg.cap, sg.T),
-                    rows_codec=m["rows_codec"],
-                    avail_scale=m["avail_scale"],
-                    cap_scale=m["cap_scale"])
-        except telemetry.KernelBuildError:
-            # the pinned kernel does not trace/lower/compile here: it
-            # would fail identically every cycle, so the pin raises
-            # instead of re-tracing behind the fallback counter forever
-            raise
-        except Exception:
-            import logging
-            logging.getLogger(__name__).exception(
-                "megakernel dispatch failed; fused XLA cycle fallback")
-            registry.counter_inc("cook_kernel_fallback",
-                                 labels={"kernel": "pallas.megacycle"})
-            _flight.note_fault("kernel.dispatch-fallback")
-            sg.inp = m["build_fused_inp"]()
-            sg.mega = None
-            # the wire's h2d was already charged above and the rebuilt
-            # inputs reuse its device arrays — the re-dispatch must not
-            # re-count the whole input as a second upload
-            sg.mega_fallback = True
-            return self.dispatch_group(sg)
-        _flight.note_path("megakernel")
-        outs = (res.cand_row, res.cand_assign, res.cand_qpos,
-                res.n_queue, res.cand_gang, res.cand_dropped)
-        for out_arr in outs:
-            copy_async = getattr(out_arr, "copy_to_host_async", None)
-            if copy_async is not None:
-                copy_async()
-        return _GroupDispatch(sg, res, outs)
+                                self.config.resident_pack))
 
     def dispatch_group(self, sg: "_StagedGroup") -> "_GroupDispatch":
         """Phase 2: upload one staged group's inputs and dispatch the
@@ -1953,8 +1615,6 @@ class FusedCycleDriver:
         outputs so a later :meth:`fetch_group` overlaps the transfer with
         whatever the host does in between (the pipelined driver's whole
         point)."""
-        if sg.mega is not None:
-            return self._dispatch_mega(sg)
         # staged wire bytes this dispatch: the device-resident base
         # mirror fields are never re-uploaded per cycle (the mirror sync
         # accounts its own transfers), and in resident-pack mode the
@@ -1963,11 +1623,6 @@ class FusedCycleDriver:
         skip = {"res_base", "disk_base"}
         if sg.resident:
             skip |= {"rows", "flags"}
-        if sg.mega_fallback:
-            # rebuilt from the already-uploaded wire: the bytes crossed
-            # the bus once, charged by _dispatch_mega (the few decoded
-            # arrays are a slight undercount, never a double count)
-            skip = set(type(sg.inp)._fields)
         telemetry.count_transfer("h2d", sum(
             getattr(a, "nbytes", 0)
             for name, a in zip(type(sg.inp)._fields, sg.inp)
@@ -2015,19 +1670,13 @@ class FusedCycleDriver:
         run the transactional launch path per pool.  ``reconciler`` is the
         pipelined driver's pre-launch re-validation hook (see
         :meth:`_apply_pool`)."""
-        cand_row, cand_assign, cand_qpos, n_queue = gd.fetched[:4]
-        # megakernel dispatches also fetched the fused gang stage's
-        # verdicts (post-reduction assignment + drop mask per slot)
-        gang_fetched = gd.fetched[4:6] if len(gd.fetched) >= 6 else None
+        cand_row, cand_assign, cand_qpos, n_queue = gd.fetched
         with tracing.span("cycle.launch", pools=len(gd.sg.group)):
             for i, pp in enumerate(gd.sg.group):
-                gang_pre = (None if gang_fetched is None else
-                            (gang_fetched[0][i], gang_fetched[1][i]))
                 self._apply_pool(scheduler, pp, cand_row[i],
                                  cand_assign[i], cand_qpos[i],
                                  int(n_queue[i]), gd.res.queue_rows, i,
-                                 queues, results, reconciler=reconciler,
-                                 gang_pre=gang_pre)
+                                 queues, results, reconciler=reconciler)
 
     def step(self, scheduler) -> Tuple[Dict[str, List[Job]],
                                        Dict[str, MatchCycleResult]]:
@@ -2054,8 +1703,7 @@ class FusedCycleDriver:
     # ----------------------------------------------------------------- apply
     def _apply_pool(self, scheduler, pp: _PackedPool, cand_row, cand_assign,
                     cand_qpos, n_queue: int, queue_rows_dev, pool_slot: int,
-                    queues, results, reconciler=None,
-                    gang_pre=None) -> None:
+                    queues, results, reconciler=None) -> None:
         """Map one pool's COMPACT kernel outputs back to entities: queue
         refresh, within-batch group validation, backoff bookkeeping,
         transactional launch.
@@ -2126,15 +1774,6 @@ class FusedCycleDriver:
             result = MatchCycleResult()
             slots = np.flatnonzero(cand_row >= 0)
             result.considered = len(slots)
-            # fused gang verdicts (megakernel dispatch): usable only while
-            # the candidate view the kernel reduced over stays INTACT — any
-            # vanished job, reconcile drop, clip, or group-placement reset
-            # below invalidates them and the host reduction recomputes
-            # (identical math, ops/gang.py; parity-asserted).  The pool
-            # must also have STAGED its gang wire: an auto pool riding a
-            # pinned group on CPU dispatches mega without one, and its
-            # all -1 gang ids would read as "nothing dropped"
-            gang_ok = gang_pre is not None and pp.gang_wire is not None
             if pp.columnar:
                 uuid_prefix = pp.uuid_base[pp.rows_s[cand_row[slots]]]
                 fetched = self.store.jobs_bulk([str(u) for u in uuid_prefix])
@@ -2143,8 +1782,6 @@ class FusedCycleDriver:
                     if job is not None:
                         cand_jobs.append(job)
                         cand_keep.append(s)
-                if len(cand_keep) != len(slots):
-                    gang_ok = False
                 slots = np.array(cand_keep, dtype=np.int64)
             else:
                 cand_jobs = [pp.id2job[pp.task_ids[r]]
@@ -2175,7 +1812,6 @@ class FusedCycleDriver:
             clipped = cand_host >= len(pp.offers)
             if clipped.any():
                 cand_host[clipped] = -1
-                gang_ok = False
             conflict_qpos = None
             res_conflict = None
             dropped_head_matched = False
@@ -2189,8 +1825,6 @@ class FusedCycleDriver:
                 dropped_head_matched = bool(
                     (state_drop[0] or res_drop[0]) and cand_host[0] >= 0) \
                     if len(slots) else False
-                if state_drop.any() or res_drop.any():
-                    gang_ok = False
                 if res_drop.any():
                     cand_host[res_drop] = -1
                 if state_drop.any():
@@ -2211,13 +1845,8 @@ class FusedCycleDriver:
                         and len(conflict_qpos) > 0
                     results[pool_name] = result
                     return
-            pre_validate = cand_host.copy()
             cand_host = validate_group_placement(
                 cand_jobs, cand_host, pp.offers, pp.ctx)
-            if gang_ok and (cand_host != pre_validate).any():
-                # a within-batch placement rule reset an assignment after
-                # the kernel's gang stage saw it: the fused verdict is stale
-                gang_ok = False
             # gang all-or-nothing over the fetched candidates (ops/gang.py,
             # docs/GANG.md): partial gangs reset to unmatched with their
             # capacity refilled to group-less candidates in the SAME cycle.
@@ -2235,22 +1864,6 @@ class FusedCycleDriver:
                     [[j.resources.cpus, j.resources.mem, j.resources.gpus,
                       j.resources.disk] for j in cand_jobs], dtype=F32)
                 satisfied = satisfied_gangs(self.store, groups_ctx)
-                if gang_ok:
-                    # the fused gang stage's membership is pack-time state:
-                    # a satisfied-set flip since staging (member failure,
-                    # grace shrink landing mid-cycle) changes who the
-                    # reduction even counts — recompute on host then
-                    wire_gangs = (frozenset(pp.gang_wire.uuids)
-                                  if pp.gang_wire is not None else frozenset())
-                    now_satisfied = frozenset(
-                        u for u in (satisfied or ())
-                        if u in wire_gangs or u in pp.gang_satisfied)
-                    if now_satisfied != pp.gang_satisfied:
-                        gang_ok = False
-                precomputed = None
-                if gang_ok:
-                    precomputed = (np.asarray(gang_pre[0])[slots],
-                                   np.asarray(gang_pre[1])[slots].astype(bool))
                 cand_host, gstats = apply_gang_cycle(
                     cand_jobs, cand_host, pp.offers, groups_ctx,
                     job_res=cand_res,
@@ -2267,8 +1880,7 @@ class FusedCycleDriver:
                     refill_ok=(~res_conflict if res_conflict is not None
                                else None),
                     audit_trail=self.store.audit, audit_pool=pool_name,
-                    satisfied=satisfied,
-                    precomputed=precomputed)
+                    satisfied=satisfied)
                 if gstats is not None:
                     result.gang_partial = gstats.partial
         with tracing.span("apply.audit"):

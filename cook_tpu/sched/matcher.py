@@ -587,13 +587,6 @@ class Matcher:
         # (MatcherConfig.__post_init__); this stays a pure lookup
         if mc.backend == "tpu-auction-pallas":  # mutated post-init
             return "tpu-auction"
-        if mc.backend == "tpu-megakernel":
-            # the megakernel is a CYCLE backend (sched/fused.py routes
-            # dispatch_group through ops/pallas_cycle); when the SPLIT
-            # path runs (degraded cycle, step_match tests) the match
-            # stage falls back to the bit-exact greedy scan — the same
-            # assignment math the megakernel fuses
-            return "tpu-greedy"
         if mc.backend != "auto":
             return mc.backend
         if num_jobs <= mc.auto_large_j_threshold:

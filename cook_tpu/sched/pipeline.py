@@ -261,9 +261,7 @@ class PipelinedCycleDriver:
         the other isn't holding: a permanent ping-pong livelock).  Only
         COMPLETE gang cohorts enter the exclusion/consumption footprint."""
         for gd in entry.dispatches:
-            # megakernel dispatches carry two extra gang-verdict arrays
-            # past the four compact outputs (sched/fused.apply_group)
-            cand_row, cand_assign, _qpos, _nq = gd.fetched[:4]
+            cand_row, cand_assign, _qpos, _nq = gd.fetched
             for i, pp in enumerate(gd.sg.group):
                 sel = np.flatnonzero((cand_row[i] >= 0)
                                      & (cand_assign[i] >= 0))

@@ -2,18 +2,18 @@
 shard (ISSUE 19).
 
 PR 12 partitioned the write plane (per-partition journal, fsync stream,
-group-commit stage, lease, replication slot) and PR 14 made the cycle a
-per-pool single-launch megakernel — but every partition still ran inside
-ONE Python process.  This module is the scale-out step: a shard WORKER
-process owns one contiguous partition block end-to-end —
+group-commit stage, lease, replication slot) — but every partition
+still ran inside ONE Python process.  This module is the scale-out
+step: a shard WORKER process owns one contiguous partition block
+end-to-end —
 
 - its pools' write plane: the partition Store (own journal + group
   commit), fenced by the partition lease it acquires at boot
   (:func:`~cook_tpu.sched.election.acquire_shard_lease` — process death
   releases the flock, which is what the PR 3 candidate-ranking failover
   keys on);
-- its resident entity pack and fused/megakernel cycle launches: the
-  scheduler it builds sees only its partition's pools (PR 14's cycle is
+- its resident entity pack and fused cycle launches: the
+  scheduler it builds sees only its partition's pools (the cycle is
   per-pool by construction, so it shards for free), and the resident
   buffers it commits live in THIS process
   (``parallel.mesh.pool_sharding``'s owner-local contract, now across
@@ -698,7 +698,7 @@ class ShardSupervisor:
     # ------------------------------------------------------------ stitches
     def collect_decisions(self) -> Dict[str, Tuple[str, Tuple[str, ...]]]:
         """The union launched set across shards, in the parity-matrix
-        shape of tests/test_megakernel.decisions()."""
+        shape of tests/test_cycle_parity.decisions()."""
         merged: Dict[str, Tuple[str, Tuple[str, ...]]] = {}
         for resp in self.broadcast({"cmd": "decisions"}):
             for uuid, (state, hosts) in resp["decisions"].items():

@@ -24,8 +24,7 @@ the brownout ladder actually did:
   attributable reason, never accepted-then-dropped.
 
 Run it: ``python -m cook_tpu.sim --overload [--overload-multiple N]``;
-asserted by tests/test_overload.py and benched by the ``overload`` leg
-in bench.py (docs/BENCH_CPU_r17_overload.json).
+asserted by tests/test_overload.py.
 """
 
 from __future__ import annotations

@@ -272,10 +272,9 @@ class CycleRecord:
         # device kernel dispatches inside this cycle (ISSUE 14: every
         # InstrumentedJit call counts one) and the cycle path that made
         # them: "split" (per-stage XLA launches), "fused" (one XLA pool
-        # cycle), "megakernel" (single Pallas launch), or "mixed" when
-        # one cycle's dispatch groups took different paths — a path
-        # regression (megakernel silently degrading to fused) is visible
-        # in /debug/cycles and the Perfetto export
+        # cycle), or "mixed" when one cycle's dispatch groups took
+        # different paths — a path regression (a fused cycle degrading
+        # to split) is visible in /debug/cycles and the Perfetto export
         self.kernel_launches = 0
         self.path: Optional[str] = None
         # the tick around the cycle (Scheduler.run's loop): the wait for
@@ -619,15 +618,14 @@ class FlightRecorder:
 
     def note_kernel_launch(self, kernel: str, n: int = 1) -> None:
         """One device kernel dispatch attributed to the current cycle
-        (counted by InstrumentedJit on every call — the megakernel's
-        headline is this number going to 1)."""
+        (counted by InstrumentedJit on every call)."""
         rec = _current_record.get()
         if rec is not None and n:
             with self._lock:
                 rec.kernel_launches += int(n)
 
     def note_path(self, path: str) -> None:
-        """The cycle's dispatch path (split | fused | megakernel); two
+        """The cycle's dispatch path (split | fused); two
         different notes inside one cycle record as "mixed".  Also tagged
         onto the live cycle span so the Perfetto export carries it."""
         rec = _current_record.get()

@@ -317,6 +317,34 @@ class TestPartitionIsolationPass:
         assert r.findings == []
 
 
+class TestPallasModuleConstantPass:
+    def test_module_level_jnp_constant_fires(self, tmp_path):
+        r = lint_snippet(tmp_path, """
+            import jax.numpy as jnp
+            NEG = jnp.float32(-1e30)
+            def kernel(ref):
+                return ref[...] + NEG
+        """, name="ops/pallas_thing.py")
+        assert "pallas-module-constant" in checks(r), r.findings
+
+    def test_python_literal_and_inner_jnp_clean(self, tmp_path):
+        r = lint_snippet(tmp_path, """
+            import jax.numpy as jnp
+            BIG = 2**31 - 1
+            def kernel(ref):
+                neg = jnp.float32(-1e30)
+                return ref[...] + neg + BIG
+        """, name="ops/pallas_thing.py")
+        assert "pallas-module-constant" not in checks(r), r.findings
+
+    def test_non_pallas_module_exempt(self, tmp_path):
+        r = lint_snippet(tmp_path, """
+            import jax.numpy as jnp
+            NEG = jnp.float32(-1e30)
+        """, name="ops/dru_like.py")
+        assert "pallas-module-constant" not in checks(r)
+
+
 class TestEngineMechanics:
     def test_pragma_suppression(self, tmp_path):
         r = lint_snippet(tmp_path, """

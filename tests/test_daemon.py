@@ -306,6 +306,25 @@ def test_build_scheduler_config_validates_matcher_knobs():
             "auto_paking": "tight"}})
 
 
+def test_auction_pallas_deprecation_logged_and_counted(caplog):
+    """The alias is a promise to operators: constructing it warns, counts
+    ``cook_config_deprecated_total`` and rewrites to ``tpu-auction``."""
+    import logging
+    from cook_tpu.config import MatcherConfig
+    from cook_tpu.utils.metrics import registry
+
+    def count():
+        return sum(v for lbl, v in registry.series("cook_config_deprecated")
+                   if lbl.get("knob") == "matcher.backend"
+                   and lbl.get("value") == "tpu-auction-pallas")
+    n0 = count()
+    with caplog.at_level(logging.WARNING):
+        mc = MatcherConfig(backend="tpu-auction-pallas")
+    assert mc.backend == "tpu-auction"
+    assert any("DEPRECATED" in r.message for r in caplog.records)
+    assert count() == n0 + 1
+
+
 def test_build_scheduler_config_validates_storage_section():
     """The storage-integrity plane's conf section (docs/ROBUSTNESS.md
     "WAL v2") is boot-validated like the sections above: typo'd keys,

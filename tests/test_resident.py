@@ -515,3 +515,27 @@ class TestDeltaKernel:
         wr, wf = reference_impl.apply_pack_delta(rows, flags, idx, rv, fv)
         np.testing.assert_array_equal(np.asarray(jax.device_get(dr)), wr)
         np.testing.assert_array_equal(np.asarray(jax.device_get(df)), wf)
+
+    def test_delta_scatter_quantized_matches_wide(self):
+        import jax.numpy as jnp
+        from cook_tpu.ops.delta import ROWS_WIDE, PackDeltaApplier
+        rng = np.random.default_rng(3)
+        P, T = 2, 512
+        rows0 = np.zeros((P, T), dtype=np.int32)
+        flags0 = np.zeros((P, T), dtype=np.uint8)
+        idx = np.sort(rng.choice(P * T, 64, replace=False)).astype(
+            np.int32)
+        vals = ((idx % T) + rng.integers(-100, 100, 64)).astype(np.int32)
+        fvals = rng.integers(0, 32, 64).astype(np.uint8)
+        ap = PackDeltaApplier(donate=False)
+        rw, fw = ap.apply(jnp.asarray(rows0), jnp.asarray(flags0),
+                          idx, vals, fvals, quantize=False)
+        rq, fq = ap.apply(jnp.asarray(rows0), jnp.asarray(flags0),
+                          idx, vals, fvals, quantize=True)
+        assert (np.asarray(rw) == np.asarray(rq)).all()
+        assert (np.asarray(fw) == np.asarray(fq)).all()
+        # and the staged narrow batch was genuinely smaller
+        st_w = ap.stage((P, T), idx, vals, fvals, quantize=False)
+        st_q = ap.stage((P, T), idx, vals, fvals, quantize=True)
+        assert st_q.codec != ROWS_WIDE
+        assert st_q.nbytes < st_w.nbytes

@@ -282,7 +282,7 @@ class TestShardedTopology:
         """The tentpole parity contract: the SAME fixed-seed world
         through a single process and through 2 shard processes launches
         the bit-identical job set (states + sorted hostnames), extending
-        the test_megakernel parity matrix across process boundaries."""
+        the test_cycle_parity matrix across process boundaries."""
         from cook_tpu.sched.shard import sched_topology
         sup1 = sched_topology(1, POOLS, WORLD, cfg=CPU_CFG,
                               root=str(tmp_path / "topo1"))
