@@ -496,14 +496,18 @@ class PipelineConfig:
     ReplicationConfig — a silently-defaulted depth would let an operator
     believe the sync path is pinned when it isn't (or vice versa)."""
 
-    #: cycles in flight concurrently.  0 = strictly synchronous
-    #: FusedCycleDriver (today's pre-pipeline behavior, bit-for-bit);
-    #: 2 = the production default: while cycle k's launches are applied
-    #: on host, cycle k+1's fused kernel is already computing on device
-    #: against the pre-apply snapshot (Omega-style optimistic cycles,
-    #: reconciled host-side before launch).  >2 is allowed but adds
-    #: speculation: intermediate unfetched cycles' candidates can't be
-    #: masked out of later stages, so the conflict-drop rate rises.
+    #: the CAP on cycles in flight.  0 = strictly synchronous
+    #: FusedCycleDriver (the pre-pipeline behavior, bit-for-bit: the
+    #: cycle thread blocks on the device's fetch); 2 = the production
+    #: default: the kernel runs in a wait the cycle thread has anyway,
+    #: and which one is read off every tick (sched/pipeline.py) — with
+    #: slack before the deadline ONE cycle is in flight, staged a lead
+    #: before the deadline and applied at it; without, while cycle k's
+    #: launches are applied on host, cycle k+1's kernel is already
+    #: computing against the pre-apply snapshot (Omega-style optimistic
+    #: cycles, reconciled host-side before launch).  >2 is allowed but
+    #: adds speculation: intermediate unfetched cycles' candidates can't
+    #: be masked out of later stages, so the conflict-drop rate rises.
     depth: int = 2
     #: JAX persistent compilation cache directory: fused cycle
     #: executables survive process restarts, so a failover or rolling

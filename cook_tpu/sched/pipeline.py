@@ -5,10 +5,26 @@ cycle kernel).
 The synchronous driver (sched/fused.py) serializes every cycle:
 pack -> upload -> dispatch -> BLOCKING fetch -> transactional launch.  The
 blocking fetch pays the full device sync every cycle, and the device sits
-idle while the host runs the launch path.  This module overlaps the two
-(what it buys on the chip: not measured, ROADMAP S4).
+idle while the host runs the launch path.  This module keeps the cycle
+thread off the device's clock: the kernel runs inside a wait the thread
+has anyway, and WHICH wait is read off the tick (PERF.md has what each
+order costs on the chip; ROADMAP S1).
 
-One :meth:`PipelinedCycleDriver.step` at depth 2:
+**A tick with slack** (``step(..., apply_at=due)``, the cycle thread's own
+tick under ``Scheduler.run``): nothing is in flight and the deadline is
+more than a lead away, so the thread has slept to ``due - lead``; the
+cycle is staged and dispatched off the store as it is then — every
+earlier cycle is applied, so there is nothing to mask or reconcile
+against — the thread sleeps the rest of the way to ``due`` while the
+device computes, then fetches and applies the SAME cycle.  The lead is
+observed (:meth:`PipelinedCycleDriver.lead_s`): the last stage's wall
+plus the dispatch->ready time of the last fetch that had to wait, with a
+margin.  A cycle still in flight from the overlapped order is applied at
+its tick and nothing is staged behind it; the tick after is staged late.
+
+**A tick without slack** (the last one overran, or ended with less than
+a lead to spare) and every direct caller of ``step_cycle()`` keep the
+overlapped order — one :meth:`PipelinedCycleDriver.step` at depth 2:
 
 1. **fetch** the in-flight cycle *k* — its compact outputs have been
    copying device->host asynchronously since last step, so the sync wait
@@ -42,7 +58,9 @@ double-launch, it can only waste a guard denial.
 
 ``pipeline_depth=0`` (config.PipelineConfig) never constructs this class:
 the scheduler drives the synchronous FusedCycleDriver bit-for-bit as
-before.  Depths above 2 are allowed but add speculation: intermediate
+before, the blocking fetch on the cycle thread's clock.  Depth is the cap
+on cycles in flight in the overlapped order; a tick with slack has one.
+Depths above 2 are allowed but add speculation: intermediate
 cycles are dispatched before their predecessors are fetched, so their
 candidates can't be masked and the conflict-drop rate rises —
 reconciliation absorbs it, throughput pays for it.
@@ -71,18 +89,22 @@ class _InFlight:
 
     __slots__ = ("id", "staged", "dispatches", "fetched", "exclude",
                  "consumed", "tokens_spent", "delta", "knows", "staged_tx",
-                 "staged_at")
+                 "staged_at", "dispatched_at", "late")
 
     def __init__(self, id_: int, staged: _StagedCycle,
                  dispatches: List[_GroupDispatch], staged_tx: int = -1,
-                 staged_at: float = 0.0):
+                 staged_at: float = 0.0, late: bool = False):
         self.id = id_
         self.staged = staged
         self.dispatches = dispatches
         self.staged_tx = staged_tx
         # perf_counter at the start of the stage that produced this
-        # entry's candidates (CycleRecord.pipeline_lag_ms)
+        # entry's candidates (CycleRecord.pipeline_lag_ms) and when its
+        # last group had been dispatched
         self.staged_at = staged_at
+        self.dispatched_at = time.perf_counter()
+        # staged a lead before its tick's deadline, nothing in flight
+        self.late = late
         self.fetched = False
         # computed at fetch: per-pool candidate footprint for masking the
         # NEXT stage -- pool name -> ("rows"|"uuids", epoch, ids) -- and
@@ -103,12 +125,18 @@ class _InFlight:
         self.knows: set = set()
 
 
+#: room over the observed stage wall and dispatch->ready time
+LEAD_MARGIN = 1.25
+
+
 class PipelinedCycleDriver:
     """Drives FusedCycleDriver's stage/dispatch/fetch/apply phases as a
     depth-k pipeline.  ``step(scheduler)`` has the same signature and
     return contract as ``FusedCycleDriver.step``; the first call behaves
     exactly like the sync driver (stage, dispatch, fetch, apply the same
-    cycle) and additionally leaves the next cycle's dispatch in flight."""
+    cycle) and additionally leaves the next cycle's dispatch in flight.
+    ``step(scheduler, apply_at=due, wait=...)`` is a tick with slack
+    (module docstring)."""
 
     def __init__(self, fused: FusedCycleDriver,
                  config: Optional[PipelineConfig] = None):
@@ -120,6 +148,11 @@ class PipelinedCycleDriver:
         # lifetime conflict counters (the bench section reads these)
         self.conflicts_state = 0
         self.conflicts_resources = 0
+        # what the lead is made of: the wall of the last stage (stage
+        # start -> last group dispatched) and the dispatch -> outputs-on-
+        # the-host time of the last fetch that had to wait for them
+        self._stage_s: Optional[float] = None
+        self._ready_s: Optional[float] = None
 
     # ------------------------------------------------------------- lifecycle
     def reset(self) -> None:
@@ -131,10 +164,52 @@ class PipelinedCycleDriver:
     def inflight(self) -> int:
         return len(self._inflight)
 
+    # ------------------------------------------------------------------ lead
+    def lead_s(self) -> Optional[float]:
+        """How long before its apply a cycle has to be staged for the
+        fetch not to wait: the last stage's wall (the pack moves between
+        regimes with the churn and stays in one for many cycles, so the
+        last is the best guess at the next) plus the last observed
+        dispatch->ready time, with a margin.  None until both have been
+        observed (the first call's blocking fetch gives the second)."""
+        if self._stage_s is None or self._ready_s is None:
+            return None
+        return (self._stage_s + self._ready_s) * LEAD_MARGIN
+
+    def stage_lead(self, slack_s: float) -> Optional[float]:
+        """What the tick whose deadline is ``slack_s`` away does
+        (Scheduler.run's loop asks before it waits): None — no slack,
+        ``step(scheduler)`` at the deadline, the overlapped order; a
+        lead in seconds — wake that long before the deadline and
+        ``step(scheduler, apply_at=deadline)``; 0.0 — slack, but a cycle
+        of the overlapped order is in flight: it is applied at the
+        deadline and nothing is staged behind it."""
+        lead = self.lead_s()
+        if lead is None or slack_s <= lead:
+            return None
+        return 0.0 if self._inflight else lead
+
     # ------------------------------------------------------------------ step
-    def step(self, scheduler) -> Tuple[Dict[str, List[Job]],
+    def step(self, scheduler, apply_at: Optional[float] = None,
+             wait=time.sleep) -> Tuple[Dict[str, List[Job]],
                                        Dict[str, MatchCycleResult]]:
+        """One cycle applied.  ``apply_at`` (a ``perf_counter`` instant,
+        with ``wait(seconds)`` to sleep towards it; a true return = stop
+        waiting) makes it a tick with slack."""
         registry.gauge_set("cook_pipeline_depth", float(self.depth))
+        if apply_at is not None:
+            if self._inflight:
+                entry = self._inflight.popleft()
+                # dispatched inside the record before this one, which
+                # makes no dispatch of its own to say which path it took
+                _flight.note_path("fused")
+            else:
+                entry = self._stage_dispatch(scheduler, late=True)
+                with _flight.idle():
+                    wait(max(0.0, apply_at - time.perf_counter()))
+            self._fetch(entry, footprint=False)
+            _flight.note_pipeline(self.depth, len(self._inflight))
+            return self._apply(scheduler, entry)
         if not self._inflight:
             entry = self._stage_dispatch(scheduler)
             self._inflight.append(entry)
@@ -177,10 +252,13 @@ class PipelinedCycleDriver:
 
     # ----------------------------------------------------------------- stage
     def _stage_dispatch(self, scheduler,
-                        after: Optional[List[_InFlight]] = None) -> _InFlight:
+                        after: Optional[List[_InFlight]] = None,
+                        late: bool = False) -> _InFlight:
         """Stage a cycle off the current store, masked by the candidate
         footprints of every fetched-but-unapplied entry in ``after``, and
         dispatch all its groups (async output copies start rolling)."""
+        registry.counter_inc("cook_cycle_stage", labels={
+            "mode": "late" if late else "overlapped"})
         staged_at = time.perf_counter()
         with tracing.span("pipeline.host", step="footprints"):
             exclude, avail_delta, token_delta, knows = \
@@ -195,8 +273,10 @@ class PipelinedCycleDriver:
                               tasks=sg.T, hosts=sg.H, gpu=sg.gpu_mode):
                 dispatches.append(self.fused.dispatch_group(sg))
         entry = _InFlight(next(self._ids), staged, dispatches,
-                          staged_tx=staged_tx, staged_at=staged_at)
+                          staged_tx=staged_tx, staged_at=staged_at,
+                          late=late)
         entry.knows = knows
+        self._stage_s = entry.dispatched_at - staged_at
         return entry
 
     @staticmethod
@@ -236,17 +316,28 @@ class PipelinedCycleDriver:
         return exclude, avail_delta, token_delta, knows
 
     # ----------------------------------------------------------------- fetch
-    def _fetch(self, entry: _InFlight) -> None:
+    def _fetch(self, entry: _InFlight, footprint: bool = True) -> None:
+        """Fetch the entry's outputs and, for the stage that will be
+        masked by them (``footprint``), work out what they hold."""
         if entry.fetched:
             return
+        # asked BEFORE the fetch: a fetch of outputs that are there can
+        # still take milliseconds to get the GIL back
+        waits = not all(out.is_ready() for gd in entry.dispatches
+                        for out in gd.outs if hasattr(out, "is_ready"))
         for gd in entry.dispatches:
             with tracing.span("cycle.match", pools=len(gd.sg.group),
                               tasks=gd.sg.T, hosts=gd.sg.H,
                               gpu=gd.sg.gpu_mode):
                 self.fused.fetch_group(gd)
         entry.fetched = True
-        with tracing.span("pipeline.host", step="candidate-footprint"):
-            self._candidate_footprint(entry)
+        if waits or self._ready_s is None:
+            # the outputs arrived under this fetch: dispatch -> ready,
+            # measured (a later lead gives them that long, and room)
+            self._ready_s = time.perf_counter() - entry.dispatched_at
+        if footprint:
+            with tracing.span("pipeline.host", step="candidate-footprint"):
+                self._candidate_footprint(entry)
 
     def _candidate_footprint(self, entry: _InFlight) -> None:
         """From the fetched outputs, the footprint the NEXT stage must
@@ -356,7 +447,8 @@ class PipelinedCycleDriver:
         reconciler = self._make_reconciler(entry)
         _flight.note_staged(
             entry.staged_tx,
-            (time.perf_counter() - entry.staged_at) * 1000.0)
+            (time.perf_counter() - entry.staged_at) * 1000.0,
+            late=entry.late)
         for gd in entry.dispatches:
             self.fused.apply_group(scheduler, gd, queues, results,
                                    reconciler=reconciler)
@@ -369,6 +461,8 @@ class PipelinedCycleDriver:
         """Propagate this entry's ACTUAL launch consumption to in-flight
         entries that did not already subtract its candidate footprint
         at stage time (depth > 2, or a stage that raced this apply)."""
+        if not self._inflight:
+            return
         consumed: Dict[tuple, np.ndarray] = {}
         for result in results.values():
             launched = set(result.launched_job_uuids)

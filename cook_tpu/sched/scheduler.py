@@ -746,7 +746,8 @@ class Scheduler:
         self.device["warmup_s"] = round(time.perf_counter() - t0, 3)
         return runs
 
-    def step_cycle(self) -> Dict[str, MatchCycleResult]:
+    def step_cycle(self, apply_at: Optional[float] = None
+                   ) -> Dict[str, MatchCycleResult]:
         """PRODUCTION cycle: rank + admission + match for every active
         non-direct pool in ONE fused device dispatch
         (sched/fused.FusedCycleDriver over parallel/sharded.make_pool_cycle),
@@ -757,6 +758,10 @@ class Scheduler:
         (sched/pipeline.py): while this cycle's launches are applied, the
         next cycle's kernel is already computing on device against an
         optimistically-stale snapshot, reconciled host-side before launch.
+        ``apply_at`` is the cycle thread's own (``run``'s loop, for a tick
+        with slack): the deadline, a ``perf_counter`` instant, a lead
+        before which this call was made — the cycle is staged now, waits
+        for the deadline inside its record and is applied at it.
 
         Replaces the reference's per-pool handler round-robin
         (scheduler.clj:2398-2517) with a single dispatch; step_rank/
@@ -771,7 +776,11 @@ class Scheduler:
             degraded = False
             try:
                 with tracing.span("fused.cycle"):
-                    queues, results = driver.step(self)
+                    if apply_at is None:
+                        queues, results = driver.step(self)
+                    else:
+                        queues, results = driver.step(
+                            self, apply_at=apply_at, wait=self._stop.wait)
             except telemetry.KernelBuildError:
                 # a kernel that never built fails the same way every
                 # cycle: surface it, never degrade around it
@@ -1458,7 +1467,7 @@ class Scheduler:
             return True
 
         def loop(interval, fn, kind: Optional[str] = None,
-                 immediate: bool = False) -> None:
+                 immediate: bool = False, stage_lead=None) -> None:
             # The interval is the loop's PERIOD, start to start: a tick is
             # due one interval after the one before was (the first, one
             # interval after the loop started), so a tick shorter than
@@ -1474,6 +1483,13 @@ class Scheduler:
             # flight record of that kind (so /debug/cycles shows the
             # sweeps beside the cycles they overlap); None is the cycle
             # thread's own tick, which opens its own records.
+            # ``stage_lead`` is that thread's too (sched/pipeline.py): asked
+            # with the time left to the deadline, it says how long before
+            # it the tick is to start — a tick with slack stages its cycle
+            # a lead before the deadline and is handed the deadline to
+            # apply it at, so that the kernel runs in the tail of this
+            # wait; None = no slack (or no such driver): tick at the
+            # deadline, as every other loop does.
             # a scheduler thread: its spans go on the profiler's clock
             # too, on a line that carries this thread's name
             tracing.annotate_spans(
@@ -1490,7 +1506,8 @@ class Scheduler:
                 # read after the last tick's maintain_gc and flush_audit:
                 # they come out of the wait, not on top of the period
                 now = time.perf_counter()
-                if self._stop.wait(max(0.0, due - now)):
+                lead = None if stage_lead is None else stage_lead(due - now)
+                if self._stop.wait(max(0.0, due - (lead or 0.0) - now)):
                     return
                 late_ms = max(0.0, now - due) * 1000.0
                 if late_ms:
@@ -1499,15 +1516,22 @@ class Scheduler:
                 flight_recorder.note_tick(
                     "wait_ms", (time.perf_counter() - now) * 1000.0)
                 flight_recorder.note_tick("overrun_ms", late_ms)
-                if not tick(run):
+                if not tick(run if lead is None else (lambda: fn(due))):
                     return
 
+        stage_lead = None
         if cfg.cycle_mode == "fused" and self.ranker.backend != "cpu":
             # production path: one fused rank+match dispatch per cycle,
             # followed by the idle-point GC maintenance (gc_discipline)
-            def fused_tick():
-                self.step_cycle()
+            def fused_tick(*apply_at):
+                self.step_cycle(*apply_at)
                 self.maintain_gc()
+
+            def stage_lead(slack_s):
+                # the pipelined driver's reading of the tick; the sync
+                # driver (depth 0) blocks on its fetch whenever it runs
+                return None if self._pipeline is None \
+                    else self._pipeline.stage_lead(slack_s)
             specs = [(cfg.match_interval_seconds, fused_tick, None)]
         else:
             specs = [(cfg.rank_interval_seconds, self.step_rank, None),
@@ -1528,6 +1552,8 @@ class Scheduler:
         self.started_s = time.time()
         for interval, fn, kind in specs:
             t = threading.Thread(target=loop, args=(interval, fn, kind),
+                                 kwargs={"stage_lead": stage_lead
+                                         if kind is None else None},
                                  name=f"cook-{kind or 'cycle'}", daemon=True)
             t.start()
             self._threads.append(t)

@@ -219,8 +219,8 @@ class CycleRecord:
                  "device", "wait_ms", "overrun_ms", "gc_ms",
                  "flush_audit_ms", "cpu_ms", "blocked_ms", "lock_holder",
                  "offcpu_ms", "background_ms", "staged_tx", "pipeline_lag_ms",
-                 "status_txns", "status_updates",
-                 "_lock_wait_max", "_thread", "_cpu0", "_t0")
+                 "status_txns", "status_updates", "staged_late",
+                 "_lock_wait_max", "_thread", "_cpu0", "_t0", "_idle")
 
     def __init__(self, seq: int, kind: str):
         self.seq = seq
@@ -278,10 +278,13 @@ class CycleRecord:
         self.kernel_launches = 0
         self.path: Optional[str] = None
         # the tick around the cycle (Scheduler.run's loop): the wait for
-        # its deadline that preceded it, how far past that deadline it
-        # started (0 = on time), and the idle-point GC and audit flush
-        # of the tick before — with duration_ms these reproduce the
-        # start-to-start period of consecutive cycles
+        # its deadline — the part that preceded the record and, where
+        # the cycle was staged a lead before the deadline, the rest of
+        # it, spent INSIDE the record between dispatch and apply
+        # (FlightRecorder.idle; not in duration_ms) — how far past that
+        # deadline the tick started (0 = on time), and the idle-point GC
+        # and audit flush of the tick before: with duration_ms these
+        # reproduce the start-to-start period of consecutive cycles
         self.wait_ms = 0.0
         self.overrun_ms = 0.0
         self.gc_ms = 0.0
@@ -302,6 +305,11 @@ class CycleRecord:
         # and how long ago that stage began (sched/pipeline.py)
         self.staged_tx: Optional[int] = None
         self.pipeline_lag_ms = 0.0
+        # 1 = staged off a store with every earlier cycle applied, a lead
+        # before the tick's deadline, and applied at it: pipeline_lag_ms
+        # is then that lead, and the document says so as lead_ms too
+        # (absent otherwise)
+        self.staged_late = 0
         # status transactions committed from inside this record, and the
         # entries they carried (Store.update_instance_statuses): a launch
         # burst acknowledged in one transaction reads 1 and the burst
@@ -311,6 +319,17 @@ class CycleRecord:
         self._thread = threading.get_ident()
         self._cpu0 = time.thread_time()
         self._t0 = time.perf_counter()
+        # (t0, t1) of the waits for a deadline inside the record
+        self._idle: List[tuple] = []
+
+    def _active_ms(self, t0: float, t1: float, end: float) -> float:
+        """How much of [t0, t1] fell inside this record, which ends at
+        ``end``, while it was not waiting for its deadline."""
+        lo, hi = max(t0, self._t0), min(t1, end)
+        over = hi - lo
+        for i0, i1 in self._idle:
+            over -= max(0.0, min(hi, i1) - max(lo, i0))
+        return max(0.0, over) * 1000.0
 
     def add_span(self, name: str, seconds: float, open_spans) -> None:
         """A span ended inside this cycle (called by Tracer._record, in
@@ -341,7 +360,7 @@ class CycleRecord:
                 break
 
     def to_doc(self) -> Dict[str, Any]:
-        return {
+        doc = {
             "seq": self.seq, "kind": self.kind, "trace_id": self.trace_id,
             "start": self.start_s, "duration_ms": round(self.duration_ms, 3),
             "phases_ms": {k: round(v, 3) for k, v in self.phases.items()},
@@ -381,8 +400,12 @@ class CycleRecord:
             "pipeline_lag_ms": round(self.pipeline_lag_ms, 3),
             "status_txns": self.status_txns,
             "status_updates": self.status_updates,
+            "staged_late": self.staged_late,
             "error": self.error,
         }
+        if self.staged_late:
+            doc["lead_ms"] = doc["pipeline_lag_ms"]
+        return doc
 
 
 class FlightRecorder:
@@ -430,11 +453,30 @@ class FlightRecorder:
         finally:
             _current_record.reset(token)
             end = time.perf_counter()
-            rec.duration_ms = (end - rec._t0) * 1000.0
+            rec.duration_ms = rec._active_ms(rec._t0, end, end)
             rec.cpu_ms = (time.thread_time() - rec._cpu0) * 1000.0
             if run is not None:
                 run[2] = end
             self._finish(rec, end)
+
+    @contextmanager
+    def idle(self):
+        """The recording thread waits for its tick's deadline INSIDE the
+        open record: a cycle staged a lead before the deadline sleeps
+        the rest of the way to it between dispatch and apply
+        (sched/pipeline.py).  That is interval wait like the part of it
+        before the record — idle, the GIL released — and is counted
+        where that is, in ``wait_ms``, and in no time of the cycle's
+        (``duration_ms``, the overlaps with collections and sweeps)."""
+        rec = _current_record.get()
+        t0 = time.perf_counter()
+        try:
+            yield
+        finally:
+            if rec is not None:
+                t1 = time.perf_counter()
+                rec._idle.append((t0, t1))
+                rec.wait_ms += (t1 - t0) * 1000.0
 
     def _finish(self, rec: CycleRecord, end: float) -> None:
         # phases_ms and detail_ms were accumulated span by span
@@ -455,7 +497,7 @@ class FlightRecorder:
         for t0, t1, _gen, thread in reversed(tuple(_gc_recent)):
             if t1 <= rec._t0:
                 break
-            over = (min(t1, end) - max(t0, rec._t0)) * 1000.0
+            over = rec._active_ms(t0, t1, end)
             if over > 0:
                 gc_ms += over
                 if thread == rec._thread:
@@ -469,8 +511,8 @@ class FlightRecorder:
             if rec.kind in CYCLE_KINDS:
                 rec.background_ms = dict.fromkeys(BACKGROUND_LOOPS, 0.0)
                 for kind, t0, t1 in self._background:
-                    over = (min(end if t1 is None else t1, end)
-                            - max(t0, rec._t0)) * 1000.0
+                    over = rec._active_ms(
+                        t0, end if t1 is None else t1, end)
                     if over > 0:
                         rec.background_ms[kind] = \
                             rec.background_ms.get(kind, 0.0) + over
@@ -574,13 +616,17 @@ class FlightRecorder:
             rec._lock_wait_max = seconds
             rec.lock_holder = holder
 
-    def note_staged(self, staged_tx: int, lag_ms: float) -> None:
+    def note_staged(self, staged_tx: int, lag_ms: float,
+                    late: bool = False) -> None:
         """The store transaction the cycle being applied was staged from,
-        and the time from that stage's start to this apply's start."""
+        and the time from that stage's start to this apply's start;
+        ``late`` = it was staged a lead before its tick's deadline with
+        nothing in flight (``staged_late`` 1, that time is the lead)."""
         rec = _current_record.get()
         if rec is not None:
             rec.staged_tx = int(staged_tx)
             rec.pipeline_lag_ms = float(lag_ms)
+            rec.staged_late = int(late)
 
     def note_status_txn(self, entries: int) -> None:
         """One status transaction of ``entries`` updates committed from

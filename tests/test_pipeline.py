@@ -1,8 +1,13 @@
 """Pipelined optimistic match cycles (sched/pipeline.py): depth-0
 sync-path preservation, conflict-injection reconciliation (no double
 launch, queue stays consistent), boot-warmup zero-recompile steady state,
-and the deterministic pipelined-vs-sync parity harness — including the
-chaos run with pipeline_depth=2 (zero duplicate live instances)."""
+the deterministic pipelined-vs-sync parity harness — including the
+chaos run with pipeline_depth=2 (zero duplicate live instances) — and
+the cycle thread's tick with slack (a cycle staged a lead before the
+deadline and applied at it)."""
+
+import threading
+import time
 
 import numpy as np
 import pytest
@@ -21,10 +26,12 @@ from cook_tpu.state import (
 
 
 def build_world(n_jobs=10, n_hosts=4, depth=2, host_cpus=16.0,
-                warmup=False, seed=5):
+                warmup=False, seed=5, considerable=None):
     rng = np.random.default_rng(seed)
     cfg = Config()
     cfg.pipeline.depth = depth
+    if considerable is not None:
+        cfg.default_matcher.max_jobs_considered = considerable
     if warmup:
         cfg.pipeline.warmup_tasks = 64
         cfg.pipeline.warmup_hosts = 64
@@ -335,6 +342,392 @@ class TestStackedPools:
             assert got[pool] == ref[pool], pool
         # the order decided: some pool left considerable jobs unplaced
         assert any(len(ref[pool]) < 16 for pool in ref)
+
+
+class Phases:
+    """The four phase calls of ONE scheduler's fused driver in the order
+    its threads made them — ("stage", n, masks), ("dispatch", n),
+    ("fetch", n), ("apply", n), n the cycle's number by stage — with the
+    instant each began on ``clock`` and, by cycle, the CycleRecord it
+    was applied inside.  ``cost(phase, n)`` seconds pass on a virtual
+    clock for each call."""
+
+    def __init__(self, sched, clock=time, cost=None):
+        sched._ensure_fused()
+        fused = self.fused = sched._fused
+        self.log, self.at, self.records = [], [], {}
+        self.dispatched_at = {}
+        self._cycle_of, self._keep = {}, []
+        self.clock, self.cost = clock, cost
+        stage, dispatch = fused.stage, fused.dispatch_group
+        fetch, apply_ = fused.fetch_group, fused.apply_group
+
+        def staged(scheduler, **kw):
+            n = len(self._keep)
+            self._note(("stage", n, sorted(k for k, v in kw.items() if v)))
+            out = stage(scheduler, **kw)
+            self._keep.append(out)
+            for sg in out.groups:
+                self._cycle_of[id(sg)] = n
+            self._spend("stage", n)
+            return out
+
+        def dispatched(sg):
+            n = self._cycle_of[id(sg)]
+            self._note(("dispatch", n))
+            self.dispatched_at[n] = clock.perf_counter()
+            gd = dispatch(sg)
+            if cost is not None:
+                # on a virtual clock the device's time is the cost of
+                # the fetch; the real outputs are there before any asks
+                import jax
+                jax.block_until_ready(gd.outs)
+            return gd
+
+        def fetched(gd):
+            n = self._cycle_of[id(gd.sg)]
+            self._note(("fetch", n))
+            out = fetch(gd)
+            self._spend("fetch", n)
+            return out
+
+        def applied(scheduler, gd, queues, results, **kw):
+            from cook_tpu.utils.flight import recorder
+            n = self._cycle_of[id(gd.sg)]
+            self._note(("apply", n))
+            self.records[n] = recorder.current()
+            apply_(scheduler, gd, queues, results, **kw)
+            self._spend("apply", n)
+
+        fused.stage, fused.dispatch_group = staged, dispatched
+        fused.fetch_group, fused.apply_group = fetched, applied
+
+    def _note(self, event):
+        self.log.append(event)
+        self.at.append(self.clock.perf_counter())
+
+    def _spend(self, phase, n):
+        if self.cost is not None:
+            self.clock.advance(self.cost(phase, n))
+
+    def order(self):
+        return [(e[0], e[1]) for e in self.log]
+
+    def when(self, phase, n):
+        return self.at[self.order().index((phase, n))]
+
+
+def arrivals(tick, n=3):
+    return [Job(uuid=f"00000000-0000-0000-{tick + 1:04d}-{i:012d}",
+                user=f"user{i % 3}", command="true", pool="default",
+                priority=50, resources=Resources(cpus=1.0, mem=128.0),
+                submit_time_ms=5000 + 10 * tick + i)
+            for i in range(n)]
+
+
+def launched_by(results):
+    return sorted((job.uuid, offer.hostname)
+                  for r in results.values() for job, offer in r.matched
+                  if job.uuid in set(r.launched_job_uuids))
+
+
+class TestLateStage:
+    """A tick with slack (``step_cycle(apply_at=...)``, Scheduler.run's
+    own): ONE cycle staged with nothing in flight and applied at the
+    deadline.  Everything read here is what this test's scheduler (or
+    thread) wrote."""
+
+    def churn_and_step(self, store, sched, cluster, tick, step):
+        """Between two ticks: every running task ends, three jobs
+        arrive; then the tick."""
+        for tid in cluster.running_task_ids():
+            cluster.complete_task(tid)
+        sched.flush_status_updates()
+        store.create_jobs(arrivals(tick))
+        return launched_by(step())
+
+    def test_with_slack_the_same_cycle_is_staged_unmasked_fetched_and_applied(
+            self, monkeypatch):
+        from cook_tpu.utils.metrics import registry
+        me, modes, counter_inc = threading.get_ident(), [], \
+            registry.counter_inc
+
+        def noting(name, value=1.0, labels=None):
+            if threading.get_ident() == me and name == "cook_cycle_stage":
+                modes.append(labels["mode"])
+            counter_inc(name, value, labels)
+        monkeypatch.setattr(registry, "counter_inc", noting)
+        # 8 slots, 40 pending and arrivals: every cycle has to choose
+        late = build_world(n_jobs=40, n_hosts=4, host_cpus=2.0)
+        sync = build_world(n_jobs=40, n_hosts=4, host_cpus=2.0, depth=0)
+        phases = Phases(late[1])
+        for tick in range(5):
+            got = self.churn_and_step(
+                *late[:3], tick,
+                lambda: late[1].step_cycle(apply_at=time.perf_counter()))
+            want = self.churn_and_step(*sync[:3], tick, sync[1].step_cycle)
+            assert got == want and len(got) == 8, tick
+        assert phases.log == [
+            event for n in range(5) for event in
+            (("stage", n, []), ("dispatch", n), ("fetch", n), ("apply", n))]
+        assert modes == ["late"] * 5
+        drv = late[1]._pipeline
+        assert drv.inflight() == 0
+        assert drv.conflicts_state == drv.conflicts_resources == 0
+        for n in range(5):
+            doc = phases.records[n].to_doc()
+            assert doc["staged_late"] == 1 and doc["lead_ms"] > 0
+            assert doc["lead_ms"] == doc["pipeline_lag_ms"]
+            assert doc["pipeline_conflicts"] == 0
+            assert doc["pipeline_inflight"] == 0
+        assert max(live_counts(late[0]).values()) == 1
+
+    def test_a_direct_caller_keeps_the_overlapped_order(self):
+        # room for every cycle's four: no cycle comes back empty-handed
+        store, sched, _c, _jobs = build_world(
+            n_jobs=40, n_hosts=4, host_cpus=8.0, considerable=4)
+        phases = Phases(sched)
+        sched.step_cycle(apply_at=time.perf_counter())
+        assert sched._pipeline.inflight() == 0
+        sched.step_cycle()
+        sched.step_cycle()
+        assert phases.order() == [
+            ("stage", 0), ("dispatch", 0), ("fetch", 0), ("apply", 0),
+            # nothing in flight: stage, dispatch, fetch, then the next
+            # cycle is left in flight behind the apply
+            ("stage", 1), ("dispatch", 1), ("fetch", 1),
+            ("stage", 2), ("dispatch", 2), ("apply", 1),
+            ("fetch", 2), ("stage", 3), ("dispatch", 3), ("apply", 2)]
+        assert sched._pipeline.inflight() == 1
+        masks = {e[1]: e[2] for e in phases.log if e[0] == "stage"}
+        assert masks[0] == masks[1] == []
+        assert "exclude" in masks[2] and "exclude" in masks[3]
+        for n in (1, 2):
+            doc = phases.records[n].to_doc()
+            assert doc["staged_late"] == 0 and "lead_ms" not in doc
+            assert doc["pipeline_lag_ms"] > 0
+        assert max(live_counts(store).values()) == 1
+
+    def test_the_record_counts_the_lead_sleep_as_wait_not_as_cycle(self):
+        from cook_tpu.utils.flight import recorder
+        _store, sched, _c, _jobs = build_world(
+            n_jobs=40, n_hosts=4, host_cpus=2.0)
+        phases = Phases(sched)
+        sched.step_cycle(apply_at=time.perf_counter())      # compiles
+        slept = []
+
+        class Stop:
+            def wait(self, timeout):
+                slept.append(timeout)
+                time.sleep(0.3)
+                return False
+        sched._stop = Stop()
+        # what the loop notes of the sleep BEFORE the record
+        recorder.note_tick("wait_ms", 700.0)
+        t0 = time.perf_counter()
+        sched.step_cycle(apply_at=t0 + 0.3)
+        wall_ms = (time.perf_counter() - t0) * 1000.0
+        assert len(slept) == 1 and 0.0 < slept[0] <= 0.3
+        rec = phases.records[1]
+        assert rec.staged_late == 1 and rec.to_doc()["lead_ms"] >= 300.0
+        # both sleeps, and only they
+        assert 1000.0 <= rec.wait_ms <= 700.0 + wall_ms
+        idle_ms = rec.wait_ms - 700.0
+        assert rec.duration_ms <= wall_ms - idle_ms + 1.0
+        assert rec.duration_ms + idle_ms >= wall_ms - 50.0
+        # no part of the cycle holds the sleep
+        detail = rec.detail_ms
+        parts = sum(detail[k] for k in detail
+                    if k in ("pools", "pack", "stage", "dispatch", "fetch",
+                             "apply", "pipeline", "publish", "other"))
+        assert parts == pytest.approx(rec.duration_ms)
+        assert detail["other"] < 150.0
+        assert rec.cpu_ms <= rec.duration_ms + 50.0
+        assert rec.blocked_ms["gc"] + sum(rec.background_ms.values()) \
+            <= rec.duration_ms
+
+    def test_the_lead_is_what_was_observed_and_the_tick_reads_it(self):
+        _store, sched, _c, _jobs = build_world(
+            n_jobs=40, n_hosts=4, host_cpus=2.0)
+        sched._ensure_fused()
+        drv = sched._pipeline
+        # nothing observed yet: no lead, the overlapped order
+        assert drv.lead_s() is None and drv.stage_lead(10.0) is None
+        sched.step_cycle()      # the first call's blocking fetch
+        lead = drv.lead_s()
+        assert lead is not None and lead > 0
+        assert drv.inflight() == 1
+        # slack, but a cycle in flight: applied at the deadline
+        assert drv.stage_lead(lead + 1.0) == 0.0
+        sched.step_cycle(apply_at=time.perf_counter())
+        assert drv.inflight() == 0
+        lead = drv.lead_s()
+        assert drv.stage_lead(lead + 1.0) == lead
+        # the last tick overran, or ended with less than a lead to spare
+        assert drv.stage_lead(-0.5) is None
+        assert drv.stage_lead(lead) is None
+        # made of the last stage's wall and the last dispatch->ready seen
+        drv._stage_s, drv._ready_s = 0.120, 0.045
+        assert drv.lead_s() == pytest.approx((0.120 + 0.045) * 1.25)
+        # only a fetch that finds its outputs not ready measures again
+        from types import SimpleNamespace as NS
+        sg = NS(group=[], T=0, H=0, gpu_mode=False)
+        monkey = sched._fused.fetch_group
+        sched._fused.fetch_group = lambda gd: None
+        try:
+            for ready, want in ((True, 0.045), (False, 0.5)):
+                entry = NS(fetched=False, dispatched_at=time.perf_counter()
+                           - 0.5, dispatches=[NS(
+                               sg=sg, outs=[NS(is_ready=lambda: ready)])])
+                drv._fetch(entry, footprint=False)
+                assert drv._ready_s == pytest.approx(want, abs=0.05)
+        finally:
+            sched._fused.fetch_group = monkey
+
+
+def virtual_loop(monkeypatch, apply_s, stop_at_wait=None):
+    """A fused scheduler whose cycle thread runs ``Scheduler.run``'s loop
+    on the virtual clock of tests/test_cycle_accounting.py at a 1 s
+    interval: a stage takes 60 ms, the device 40 ms from the dispatch,
+    cycle n's apply ``apply_s(n)``.  Every other loop's interval is an
+    hour.  ``stop_at_wait``: the cycle thread's k-th wait (1-based) is
+    the one a shutdown lands in."""
+    from test_cycle_accounting import VirtualStop, VirtualTime
+
+    from cook_tpu.sched import pipeline as pipeline_mod
+    from cook_tpu.sched import scheduler as scheduler_mod
+    store, sched, _cluster, _jobs = build_world(
+        n_jobs=60, n_hosts=4, host_cpus=16.0, considerable=4)
+    cfg = sched.config
+    cfg.match_interval_seconds = 1.0
+    cfg.lingering_task_interval_seconds = 3600.0
+    cfg.monitor_interval_seconds = 3600.0
+    cfg.elastic.resize_interval_seconds = 3600.0
+    params = type("Params", (), {"interval_seconds": 3600.0})()
+    sched.rebalancer.effective_params = lambda: params
+    sched.step_cycle(apply_at=time.perf_counter())   # compile, real clock
+    drv = sched._pipeline      # ... whose observations are not this clock's
+    drv._stage_s = drv._ready_s = None
+    clock = VirtualTime()
+    monkeypatch.setattr(scheduler_mod, "time", clock)
+    monkeypatch.setattr(pipeline_mod, "time", clock)
+
+    class Stop(VirtualStop):
+        waits = 0
+
+        def wait(self, timeout=None):
+            if timeout is not None and timeout < 3600.0:
+                self.waits += 1
+                if self.waits == stop_at_wait:
+                    self.set()
+            return super().wait(timeout)
+    sched._stop = Stop(clock)
+    phases = None
+
+    def cost(phase, n):
+        if phase == "stage":
+            return 0.060
+        if phase == "fetch":     # blocks until the device is done
+            return max(0.0, phases.dispatched_at[n] + 0.040
+                       - clock.perf_counter())
+        return apply_s(n)
+    phases = Phases(sched, clock=clock, cost=cost)
+    return store, sched, phases
+
+
+class TestLateStageUnderTheLoop:
+    def run_until(self, sched, phases, last):
+        """Until cycle ``last`` (by stage) has been applied."""
+        done = threading.Event()
+        spend = phases._spend
+
+        def spend_and_stop(phase, n):
+            spend(phase, n)
+            if (phase, n) == ("apply", last):
+                sched._stop.set()
+                done.set()
+        phases._spend = spend_and_stop
+        sched.run()
+        try:
+            assert done.wait(60.0)
+        finally:
+            sched.shutdown()
+        assert not any(t.is_alive() for t in sched._threads)
+
+    def test_both_changes_of_order_cost_one_cycle_and_launch_nothing_twice(
+            self, monkeypatch):
+        # cycle 3's apply outlasts the interval
+        store, sched, phases = virtual_loop(
+            monkeypatch, lambda n: 1.5 if n == 3 else 0.2)
+        self.run_until(sched, phases, last=6)
+        assert phases.order() == [
+            # tick 1: nothing observed yet, the overlapped order's first
+            # call — one blocking fetch, which is the observation
+            ("stage", 0), ("dispatch", 0), ("fetch", 0),
+            ("stage", 1), ("dispatch", 1), ("apply", 0),
+            # tick 2 has slack: the cycle in flight is applied at its
+            # deadline, a period after it was staged, and not restaged
+            ("fetch", 1), ("apply", 1),
+            # ticks 3 and 4: staged a lead before the deadline
+            ("stage", 2), ("dispatch", 2), ("fetch", 2), ("apply", 2),
+            ("stage", 3), ("dispatch", 3), ("fetch", 3), ("apply", 3),
+            # cycle 3 was applied for 1.5 s: tick 5 has no slack and
+            # takes the overlapped order again, one unhidden kernel
+            ("stage", 4), ("dispatch", 4), ("fetch", 4),
+            ("stage", 5), ("dispatch", 5), ("apply", 4),
+            ("fetch", 5), ("apply", 5),
+            ("stage", 6), ("dispatch", 6), ("fetch", 6), ("apply", 6)]
+        late = {n: phases.records[n].staged_late for n in range(7)}
+        assert late == {0: 0, 1: 0, 2: 1, 3: 1, 4: 0, 5: 0, 6: 1}
+        # also the records that only applied a cycle dispatched before
+        assert {phases.records[n].path for n in range(7)} == {"fused"}
+        when = phases.when
+        assert when("stage", 0) == pytest.approx(1.0)
+        assert when("stage", 1) == pytest.approx(1.0 + 0.060 + 0.040)
+        assert when("fetch", 1) == pytest.approx(2.0)
+        # late: staged a lead before the deadline, applied AT it
+        lead = (0.060 + 0.040) * 1.25
+        for n, due in ((2, 3.0), (3, 4.0), (6, 7.5)):
+            assert when("stage", n) == pytest.approx(due - lead)
+            assert when("fetch", n) == when("apply", n) \
+                == pytest.approx(due)
+            assert phases.records[n].to_doc()["lead_ms"] \
+                == pytest.approx(lead * 1000)
+        # the overrun re-anchors: tick 5 starts as cycle 3's apply ends
+        assert when("stage", 4) == pytest.approx(5.5)
+        assert when("fetch", 5) == pytest.approx(6.5)
+        assert phases.records[5].pipeline_lag_ms \
+            == pytest.approx((6.5 - when("stage", 5)) * 1000)
+        assert "lead_ms" not in phases.records[5].to_doc()
+        # masks only in the overlapped order, and no job launched twice
+        masked = {e[1] for e in phases.log if e[0] == "stage" and e[2]}
+        assert masked == {1, 5}
+        counts = live_counts(store)
+        assert max(counts.values()) == 1 and len(counts) == 4 * 8
+        assert sched._pipeline.conflicts_state == 0
+
+    @pytest.mark.parametrize("stop_at_wait, cycles", [(3, [0, 1]),
+                                                      (4, [0, 1, 2])],
+                             ids=["before-the-stage", "before-the-apply"])
+    def test_a_shutdown_in_either_sleep_applies_nothing_twice(
+            self, monkeypatch, stop_at_wait, cycles):
+        # the cycle thread's waits: ticks 1 and 2 one each (the
+        # overlapped order, then its cycle in flight), tick 3 two — to
+        # the stage, then to the deadline
+        store, sched, phases = virtual_loop(monkeypatch, lambda n: 0.2,
+                                            stop_at_wait=stop_at_wait)
+        sched.run()
+        for t in sched._threads:
+            t.join(timeout=60.0)
+        sched.shutdown()
+        assert not any(t.is_alive() for t in sched._threads)
+        assert sched._stop.is_set()
+        order = phases.order()
+        assert [n for phase, n in order if phase == "stage"] == cycles
+        assert [n for phase, n in order if phase == "apply"] == cycles
+        assert sched._pipeline.inflight() == 0
+        counts = live_counts(store)
+        assert max(counts.values()) == 1 and len(counts) == 4 * len(cycles) + 4
 
 
 class TestObservability:
