@@ -258,9 +258,13 @@ def phase_parity(args) -> dict:
 
 
 def phase_multichip(args) -> dict:
-    """The four-chip path that exists: make_pool_cycle(pool_mesh(4),
-    structured=True) — BASELINE config 4 cut to 4 pools x 50k jobs — on
-    real devices, against the same pools run one by one on one device."""
+    """A kernel-level check of the pool-sharded cycle on four real
+    devices: make_pool_cycle(pool_mesh(4), structured=True) — BASELINE
+    config 4 cut to 4 pools x 50k jobs — against the same pools run one
+    by one on one device.  The bare kernel, not the served path: the
+    daemon's own four-device cycle (``pipeline.mesh_devices``: pipelined,
+    resident, warmed) is what the benchmark cell ``4chip-8pool-drain``
+    runs (PERF.md section 4)."""
     import jax
     import jax.numpy as jnp
     import numpy as np

@@ -534,11 +534,26 @@ class PipelineConfig:
     #: also warm the gpu DRU-mode variant of the cycle (pools with
     #: dru_mode=gpu compile a separate kernel)
     warmup_gpu: bool = False
+    #: devices of the fused cycle's 1-D pool mesh (docs/DEPLOY.md "pool
+    #: mesh"): the pools a dispatch stacks are split over them in
+    #: contiguous blocks, in store order, and every cycle is one SPMD
+    #: dispatch over all of them.  A statement of topology like
+    #: ``partitions.count``, never read off ``jax.devices()``: a
+    #: one-device deployment on a four-chip host stays one device.  Boot
+    #: refuses more devices than the process has and a mesh over one
+    #: device together with ``partitions.shards`` (the other, exclusive
+    #: layout: one process a shard).
+    mesh_devices: int = 1
 
     def __post_init__(self):
         if not isinstance(self.depth, int) or self.depth < 0:
             raise ValueError(
                 f"pipeline depth must be an int >= 0, got {self.depth!r}")
+        if not isinstance(self.mesh_devices, int) \
+                or isinstance(self.mesh_devices, bool) \
+                or self.mesh_devices < 1:
+            raise ValueError("pipeline mesh_devices must be an int >= 1, "
+                             f"got {self.mesh_devices!r}")
         for k in ("warmup_tasks", "warmup_hosts", "warmup_users"):
             v = getattr(self, k)
             if not isinstance(v, int) or v < 0:

@@ -214,6 +214,12 @@ def build_scheduler_config(spec: Dict) -> Config:
             _check_plane_wire_bytes(conf_key, value_key, val)
             table.append((rx, val))
         setattr(cfg, attr, table)
+    # the pool mesh and controller shards are exclusive layouts
+    # (docs/DEPLOY.md "pool mesh"); how many devices the leader really
+    # has is checked where it first touches them (Scheduler.__init__)
+    from .parallel.mesh import validate_pool_mesh
+    validate_pool_mesh(cfg.pipeline.mesh_devices,
+                       shards=cfg.partitions.shards)
     return cfg
 
 
@@ -662,7 +668,9 @@ class CookDaemon:
                 dev = self.scheduler.device
                 print(f"cook_tpu: scheduler kernels on platform="
                       f"{dev['platform']} device_kind={dev['device_kind']} "
-                      f"count={dev['count']}", flush=True)
+                      f"count={dev['count']} mesh_devices="
+                      f"{dev.get('mesh_devices')} ids="
+                      f"{dev.get('mesh_device_ids')}", flush=True)
                 # a kernel that cannot build stops the cycle threads;
                 # same exit as a lost election: non-zero, supervisor
                 # restarts, nothing keeps serving on a dead device path

@@ -87,6 +87,40 @@ def _donate_default() -> bool:
     return jax.default_backend() not in ("cpu",)
 
 
+class _Replicated:
+    """Where the delta kernels' host inputs go: onto every device of the
+    pool mesh (``NamedSharding(mesh, P())``), so a scatter into the
+    pool-sharded resident buffers and an append to the replicated base
+    mirror read their operands from the device that runs them and
+    nothing crosses between devices at dispatch.  ``mesh_fn`` gives the
+    mesh when asked (the fused driver builds its mesh lazily); on one
+    device, and without one, it is the plain uncommitted
+    ``jnp.asarray``."""
+
+    def __init__(self, mesh_fn=None):
+        self._mesh_fn = mesh_fn
+
+    @property
+    def mesh(self):
+        return None if self._mesh_fn is None else self._mesh_fn()
+
+    @property
+    def replicas(self) -> int:
+        """Copies a put makes: what an upload's bytes are counted by
+        (``h2d_bytes`` is the sum over devices)."""
+        mesh = self.mesh
+        return 1 if mesh is None else int(mesh.size)
+
+    def put(self, a):
+        mesh = self.mesh
+        if mesh is None or mesh.size == 1:
+            import jax.numpy as jnp
+            return jnp.asarray(a)
+        import jax
+        from jax.sharding import NamedSharding, PartitionSpec
+        return jax.device_put(a, NamedSharding(mesh, PartitionSpec()))
+
+
 class _StagedDelta:
     """One staged (h2d-in-flight) scatter batch: the padded device
     arrays whose host->device copies started at :meth:`stage` time.
@@ -118,11 +152,17 @@ class PackDeltaApplier:
     coded as deltas against their own target position, so a steady-state
     scatter row costs 4 (idx) + 1-2 (value) + 1 (flag) bytes instead of 9 —
     losslessly, with automatic wide fallback when a batch's deltas
-    overflow the narrow width."""
+    overflow the narrow width.
 
-    def __init__(self, donate: Optional[bool] = None):
+    With ``mesh`` (a zero-argument callable giving it) the buffers are
+    that mesh's pool-sharded resident pack: the delta batch is placed on every
+    device and the scatter's outputs keep the buffers' sharding."""
+
+    def __init__(self, donate: Optional[bool] = None, mesh=None):
         self._fns: Dict[Tuple, object] = {}
         self._donate = donate
+        # the delta batch goes to every device of the buffers' mesh
+        self._to = _Replicated(mesh)
 
     def _fn(self, shape: Tuple[int, ...], kb: int, codec: int = 0):
         key = (shape, kb, codec)
@@ -149,9 +189,18 @@ class PackDeltaApplier:
                 return (flat_r.reshape(rows_buf.shape),
                         flat_f.reshape(flags_buf.shape))
 
+            # over a mesh the outputs are pinned to the buffers' own
+            # placement: they are the next scatter's (and the cycle's)
+            # inputs, and a placement of the compiler's choosing would
+            # miss the warmed executables and reshard at every dispatch
+            pinned = {}
+            mesh = self._to.mesh
+            if mesh is not None and mesh.size > 1:
+                from ..parallel.mesh import pool_sharding
+                pinned["out_shardings"] = (pool_sharding(mesh),) * 2
             fn = telemetry.instrument_jit("delta.apply", jax.jit(
                 _apply,
-                donate_argnums=(0, 1) if self._donate else ()))
+                donate_argnums=(0, 1) if self._donate else (), **pinned))
             self._fns[key] = fn
         return fn
 
@@ -162,7 +211,6 @@ class PackDeltaApplier:
         copies for one delta batch.  Split from :meth:`commit` so a
         pipelined driver's stage-(k+1) h2d overlaps cycle k's in-flight
         kernel (the double-buffering half of ISSUE 14's wire work)."""
-        import jax.numpy as jnp
         n_flat = int(np.prod(shape))
         T = int(shape[-1])
         k = int(idx.size)
@@ -192,11 +240,12 @@ class PackDeltaApplier:
             rows_p[:k] = rows_vals
         flags_p = np.zeros(kb, dtype=np.uint8)
         flags_p[:k] = flags_vals
-        nbytes = idx_p.nbytes + rows_p.nbytes + flags_p.nbytes
+        nbytes = (idx_p.nbytes + rows_p.nbytes + flags_p.nbytes) \
+            * self._to.replicas
         telemetry.count_transfer("h2d", nbytes)
-        return _StagedDelta(tuple(shape), kb, codec, jnp.asarray(idx_p),
-                            jnp.asarray(rows_p), jnp.asarray(flags_p),
-                            nbytes)
+        put = self._to.put
+        return _StagedDelta(tuple(shape), kb, codec, put(idx_p),
+                            put(rows_p), put(flags_p), nbytes)
 
     def commit(self, rows_dev, flags_dev, st: _StagedDelta):
         """Dispatch the scatter against a previously staged batch."""
@@ -246,8 +295,11 @@ class DeviceBaseMirror:
     overflow triggers a full (re)upload.  Shared by the fused driver and
     the columnar rank path."""
 
-    def __init__(self):
+    def __init__(self, mesh=None):
         self._floor = 0                   # least capacity of an upload
+        # the mirror is replicated over the pool mesh: every shard
+        # gathers its own pools' rows from its own copy
+        self.placement = _Replicated(mesh)
         self.reset()
 
     def reset(self) -> None:
@@ -275,7 +327,7 @@ class DeviceBaseMirror:
         a compaction epoch change or capacity overflow, else one bucketed
         chunk append of the rows added since the last cycle.  Returns the
         (res, disk) device arrays (capacity-padded)."""
-        import jax.numpy as jnp
+        put, replicas = self.placement.put, self.placement.replicas
         n = res_base.shape[0]
         full = (self._key != compactions or n > self._cap)
         if not full and n > self._n:
@@ -288,12 +340,11 @@ class DeviceBaseMirror:
                 chunk[:k] = res_base[self._n:n]
                 dchunk = np.zeros(kb, dtype=F32)
                 dchunk[:k] = disk_base[self._n:n]
-                off = jnp.asarray(self._n, dtype=jnp.int32)
-                telemetry.count_transfer("h2d",
-                                         chunk.nbytes + dchunk.nbytes)
-                self._res = append_chunk(self._res, jnp.asarray(chunk), off)
-                self._disk = append_chunk(self._disk, jnp.asarray(dchunk),
-                                          off)
+                off = put(np.asarray(self._n, dtype=np.int32))
+                telemetry.count_transfer(
+                    "h2d", (chunk.nbytes + dchunk.nbytes) * replicas)
+                self._res = append_chunk(self._res, put(chunk), off)
+                self._disk = append_chunk(self._disk, put(dchunk), off)
                 self._n = n
         if full:
             cap = max(bucket(n, minimum=1024), self._floor)
@@ -301,8 +352,9 @@ class DeviceBaseMirror:
             res_p[:n] = res_base
             disk_p = np.zeros(cap, dtype=F32)
             disk_p[:n] = disk_base
-            telemetry.count_transfer("h2d", res_p.nbytes + disk_p.nbytes)
-            self._res = jnp.asarray(res_p)
-            self._disk = jnp.asarray(disk_p)
+            telemetry.count_transfer(
+                "h2d", (res_p.nbytes + disk_p.nbytes) * replicas)
+            self._res = put(res_p)
+            self._disk = put(disk_p)
             self._key, self._n, self._cap = compactions, n, cap
         return self._res, self._disk

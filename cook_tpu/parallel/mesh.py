@@ -87,6 +87,36 @@ def validate_shard_alignment(pmap, n_shards: int,
     return layout
 
 
+def validate_pool_mesh(mesh_devices: int, shards: int = 0,
+                       shard_id: Optional[int] = None,
+                       local_devices: Optional[int] = None) -> None:
+    """Boot-time check of ``pipeline.mesh_devices`` (docs/DEPLOY.md
+    "pool mesh").  A mesh over one device and controller shards are
+    exclusive layouts: a shard process commits resident buffers for ITS
+    pools only, and a pool mesh under it would commit them for pools
+    other processes own — double-owned device state, the split-brain
+    :func:`validate_shard_alignment` exists to refuse.  And a mesh of
+    more devices than the process has must fail the boot: a cycle that
+    quietly ran on fewer chips than the deployment states would read as
+    an ordinary, slower one.  ``local_devices`` None skips the second
+    check (the daemon validates its configuration before any process of
+    it touches JAX)."""
+    if mesh_devices > 1 and (shards > 0 or shard_id is not None):
+        who = (f"controller shard {shard_id}" if shard_id is not None
+               else f"partitions.shards = {shards}")
+        raise ShardAlignmentError(
+            f"pipeline.mesh_devices = {mesh_devices} with {who}: a shard "
+            "process commits resident buffers for ITS pools only, so its "
+            "cycle runs on one device; use partitions.shards (one process "
+            "a shard) or pipeline.mesh_devices (one process, pools split "
+            "over its devices), not both")
+    if local_devices is not None and mesh_devices > max(local_devices, 1):
+        raise ValueError(
+            f"pipeline.mesh_devices = {mesh_devices} but this process has "
+            f"{local_devices} local device(s): the cycle's pool mesh "
+            "cannot be built, and nothing falls back to fewer chips")
+
+
 def pool_mesh(n_devices: Optional[int] = None) -> Mesh:
     """1-D mesh over the pool axis; single-slice, collectives ride ICI."""
     devices = jax.devices()

@@ -497,9 +497,12 @@ def make_pool_cycle(mesh, *, gpu_mode: bool = False,
         # 1-D mesh this rides ICI; on ("dcn", "pool") it is the only
         # cross-slice traffic, sized [pools, 4] + [pools].
         base_all, gid_all = pool_base, inp.group_id
-        for axis in reversed(axes):
-            base_all = jax.lax.all_gather(base_all, axis, axis=0, tiled=True)
-            gid_all = jax.lax.all_gather(gid_all, axis, axis=0, tiled=True)
+        with jax.named_scope("reconcile.quota_groups"):
+            for axis in reversed(axes):
+                base_all = jax.lax.all_gather(base_all, axis, axis=0,
+                                              tiled=True)
+                gid_all = jax.lax.all_gather(gid_all, axis, axis=0,
+                                             tiled=True)
         group_base = jax.vmap(
             lambda gid: jnp.sum(
                 base_all * ((gid_all == gid) & (gid >= 0))[:, None], axis=0)
@@ -531,10 +534,12 @@ def make_pool_cycle(mesh, *, gpu_mode: bool = False,
         # Reconciliation collective #2: global matched usage + placement
         # count (cycle telemetry, scheduler.clj:1210-1280).
         matched_usage_global = matched_usage
-        for axis in reversed(axes):
-            matched_usage_global = jax.lax.all_gather(
-                matched_usage_global, axis, axis=0, tiled=True)
-        total = jax.lax.psum(jnp.sum((assign >= 0).astype(jnp.int32)), axes)
+        with jax.named_scope("reconcile.matched_usage"):
+            for axis in reversed(axes):
+                matched_usage_global = jax.lax.all_gather(
+                    matched_usage_global, axis, axis=0, tiled=True)
+            total = jax.lax.psum(
+                jnp.sum((assign >= 0).astype(jnp.int32)), axes)
         return PoolCycleResult(order=order, num_ranked=num_ranked, dru=dru,
                                assign=assign, match_valid=match_valid,
                                queue_ok=queue_ok, accepted=accepted,

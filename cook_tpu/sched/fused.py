@@ -215,13 +215,13 @@ class FusedCycleDriver:
         # unchanged, so steady-state cycles upload only the NEW rows
         # (ops/delta.DeviceBaseMirror, shared with the columnar rank path)
         from ..ops.delta import DeviceBaseMirror, PackDeltaApplier
-        self._mirror = DeviceBaseMirror()
+        self._mirror = DeviceBaseMirror(mesh=self.mesh)
         # device-RESIDENT pack (ISSUE 7 tentpole): the stacked [P, T]
         # rows/flags wire arrays live in device buffers across cycles,
         # keyed by DRU mode; each stage diffs the freshly built host
         # arrays against the shadow and scatter-applies just the delta
         self._resident: Dict[bool, _ResidentPack] = {}
-        self._applier = PackDeltaApplier()
+        self._applier = PackDeltaApplier(mesh=self.mesh)
         # quiet-pool fast path: the index's tx-event delta feed
         # (state/index.py attach_pack_consumer) tells the pack when a
         # pool saw zero churn since its last pack, letting it reuse the
@@ -237,20 +237,34 @@ class FusedCycleDriver:
 
             from ..parallel.mesh import POOL_AXIS
             self._mesh = Mesh(np.array(jax.devices()[:1]), (POOL_AXIS,))
-        if self.shard_id is not None and self._mesh.size > 1:
-            # one partition = one process = one mesh shard: a shard
-            # worker driving a multi-device pool mesh would commit
-            # resident buffers for pools OTHER processes own —
-            # double-owned device state, the exact split-brain the boot
-            # alignment check (parallel.mesh.validate_shard_alignment)
-            # exists to refuse
-            from ..parallel.mesh import ShardAlignmentError
-            raise ShardAlignmentError(
-                f"controller shard {self.shard_id} was given a "
-                f"{self._mesh.size}-device pool mesh: a shard process "
-                "commits resident buffers for ITS pools only; give each "
-                "shard its local device slice")
         return self._mesh
+
+    def _put(self, tree):
+        """Host arrays to the device, every [P, ...] array split over the
+        pool mesh so that each device is handed its own pools' slice
+        straight from the host (parallel/mesh.pool_sharding): the
+        placement the cycle's shard_map and the resident buffers expect,
+        so a dispatch moves nothing between devices.  On one device it
+        is the plain uncommitted upload."""
+        import jax
+        mesh = self.mesh()
+        if mesh.size == 1:
+            import jax.numpy as jnp
+            return jax.tree_util.tree_map(jnp.asarray, tree)
+        from ..parallel.mesh import pool_sharding
+        return jax.device_put(tree, pool_sharding(mesh))
+
+    @staticmethod
+    def _pool_row(stacked, slot: int):
+        """Row ``slot`` of a pool-stacked [P, ...] device array, sliced on
+        the device that holds it: no gather of the other shards' rows,
+        and the slice (an async device op) does not keep the P-wide
+        buffer alive."""
+        for sh in stacked.addressable_shards:
+            lo, hi, _ = sh.index[0].indices(stacked.shape[0])
+            if lo <= slot < hi:
+                return sh.data[slot - lo]
+        raise IndexError(f"pool slot {slot} is on no addressable shard")
 
     def _cycle_fn(self, gpu_mode: bool, considerable_cap: int,
                   structured: bool = False, compact: bool = False):
@@ -367,7 +381,6 @@ class FusedCycleDriver:
         """One zero-world execution of the compact fused cycle per distinct
         cap bucket at [P, T] x H; returns the executions."""
         import jax
-        import jax.numpy as jnp
 
         from ..parallel.sharded import CompactPoolCycleInputs
         E = 8  # exception bucket floor: no complex jobs in the zero world
@@ -378,30 +391,41 @@ class FusedCycleDriver:
         caps.update(bucket(mc.max_jobs_considered)
                     for _rx, mc in self.config.pool_matchers)
         caps = sorted({min(c, T) for c in caps})
-        f32, i32 = jnp.float32, jnp.int32
+        f32, i32 = np.float32, np.int32
         runs = 0
         with tracing.span("warmup.cycle", P=P, T=T, H=H, gpu=gm) as sp:
+            # jit keys its executables on the inputs' placement: the
+            # zeroed inputs carry the live cycle's (pool-sharded, the
+            # base mirror replicated), or the warm pass would leave the
+            # variant the live cycle calls cold
+            replicated = self._mirror.placement.put
             inp = CompactPoolCycleInputs(
-                rows=jnp.zeros((P, T), dtype=i32),
-                flags=jnp.zeros((P, T), dtype=jnp.uint8),
-                res_base=jnp.zeros((mir, 4), dtype=f32),
-                disk_base=jnp.zeros(mir, dtype=f32),
-                tokens_u=jnp.full((P, U), jnp.inf, dtype=f32),
-                shares_u=jnp.full((P, U, 3), jnp.inf, dtype=f32),
-                quota_u=jnp.full((P, U, 4), jnp.inf, dtype=f32),
-                num_considerable=jnp.zeros((P,), dtype=i32),
-                pool_quota=jnp.full((P, 4), jnp.inf, dtype=f32),
-                group_quota=jnp.full((P, 4), jnp.inf, dtype=f32),
-                group_id=jnp.full((P,), -1, dtype=i32),
-                host_gpu=jnp.zeros((P, H), dtype=bool),
-                host_blocked=jnp.ones((P, H), dtype=bool),
-                exc_rows=jnp.full((P, E), -1, dtype=i32),
-                exc_mask=jnp.zeros((P, E, H), dtype=bool),
-                avail=jnp.zeros((P, H, 4), dtype=f32),
-                capacity=jnp.zeros((P, H, 4), dtype=f32))
+                res_base=replicated(np.zeros((mir, 4), dtype=f32)),
+                disk_base=replicated(np.zeros(mir, dtype=f32)),
+                **self._put(dict(
+                    rows=np.zeros((P, T), dtype=i32),
+                    flags=np.zeros((P, T), dtype=np.uint8),
+                    tokens_u=np.full((P, U), np.inf, dtype=f32),
+                    shares_u=np.full((P, U, 3), np.inf, dtype=f32),
+                    quota_u=np.full((P, U, 4), np.inf, dtype=f32),
+                    num_considerable=np.zeros((P,), dtype=i32),
+                    pool_quota=np.full((P, 4), np.inf, dtype=f32),
+                    group_quota=np.full((P, 4), np.inf, dtype=f32),
+                    group_id=np.full((P,), -1, dtype=i32),
+                    host_gpu=np.zeros((P, H), dtype=bool),
+                    host_blocked=np.ones((P, H), dtype=bool),
+                    exc_rows=np.full((P, E), -1, dtype=i32),
+                    exc_mask=np.zeros((P, E, H), dtype=bool),
+                    avail=np.zeros((P, H, 4), dtype=f32),
+                    capacity=np.zeros((P, H, 4), dtype=f32))))
             for cap in caps:
                 fn = self._cycle_fn(gm, cap, True, compact=True)
-                jax.block_until_ready(fn(inp).n_queue)
+                res = fn(inp)
+                # the apply's per-pool slice of the ranked queue, on
+                # every device that holds one
+                jax.block_until_ready(
+                    [res.n_queue] + [self._pool_row(res.queue_rows, i)
+                                     for i in range(P)])
                 runs += 1
             _count_warmup("fused.pool_cycle", runs)
             sp.set_tag("runs", runs)
@@ -417,7 +441,6 @@ class FusedCycleDriver:
         input sharding, so an unsharded warm pass would leave the
         sharded variant cold."""
         import jax
-        import jax.numpy as jnp
 
         from ..ops.delta import _DELTA_MIN_BUCKET
         n_flat = P * T
@@ -427,15 +450,8 @@ class FusedCycleDriver:
             k *= 2
         kbs.add(n_flat)  # the clamped top bucket
         with tracing.span("warmup.delta_apply", P=P, T=T) as sp:
-            if self.mesh().size > 1:
-                from ..parallel.mesh import pool_sharding
-                sh = pool_sharding(self.mesh())
-                rows_b = jax.device_put(np.zeros((P, T), dtype=np.int32), sh)
-                flags_b = jax.device_put(np.zeros((P, T), dtype=np.uint8),
-                                         sh)
-            else:
-                rows_b = jnp.zeros((P, T), dtype=jnp.int32)
-                flags_b = jnp.zeros((P, T), dtype=jnp.uint8)
+            rows_b, flags_b = self._put((np.zeros((P, T), dtype=np.int32),
+                                         np.zeros((P, T), dtype=np.uint8)))
             # all-sentinel indices make every scatter a no-op, so the
             # buffers stay zeros; with the quantized wire the narrow
             # value codecs are warmed too (i8 via zero deltas, i16 via an
@@ -464,19 +480,20 @@ class FusedCycleDriver:
         as the mirror has ``room`` (a chunk beyond it re-uploads
         instead)."""
         import jax
-        import jax.numpy as jnp
 
         from ..ops.delta import APPEND_MIN_BUCKET, append_chunk
         with tracing.span("warmup.delta_append", rows=mir) as sp:
-            res = jnp.zeros((mir, 4), dtype=jnp.float32)
-            disk = jnp.zeros(mir, dtype=jnp.float32)
-            off = jnp.asarray(0, dtype=jnp.int32)
+            # replicated like the live mirror and its chunks
+            put = self._mirror.placement.put
+            res = put(np.zeros((mir, 4), dtype=np.float32))
+            disk = put(np.zeros(mir, dtype=np.float32))
+            off = put(np.asarray(0, dtype=np.int32))
             runs, kb = 0, APPEND_MIN_BUCKET
             while kb <= min(max(design, APPEND_MIN_BUCKET), room):
                 res = append_chunk(
-                    res, jnp.zeros((kb, 4), dtype=jnp.float32), off)
+                    res, put(np.zeros((kb, 4), dtype=np.float32)), off)
                 disk = append_chunk(
-                    disk, jnp.zeros(kb, dtype=jnp.float32), off)
+                    disk, put(np.zeros(kb, dtype=np.float32)), off)
                 runs += 2
                 kb *= 2
             jax.block_until_ready((res, disk))
@@ -587,24 +604,12 @@ class FusedCycleDriver:
                         st.rows_dev, st.flags_dev = rows_dev, flags_dev
                         st.rows_host, st.flags_host = rows_p, flags_p
                         return rows_dev, flags_dev
-        import jax.numpy as jnp
         registry.counter_inc("cook_resident_repack",
                              labels={"reason": reason})
         _flight.note_repack(reason)
         telemetry.count_transfer("h2d", rows_p.nbytes + flags_p.nbytes)
-        mesh = self.mesh()
-        if mesh.size > 1:
-            # each pool shard owns its own resident buffer slice: commit
-            # the [P, T] arrays with the pool-axis sharding the cycle's
-            # shard_map expects (parallel/mesh.pool_sharding)
-            import jax
-            from ..parallel.mesh import pool_sharding
-            sh = pool_sharding(mesh)
-            rows_dev = jax.device_put(rows_p, sh)
-            flags_dev = jax.device_put(flags_p, sh)
-        else:
-            rows_dev = jnp.asarray(rows_p)
-            flags_dev = jnp.asarray(flags_p)
+        # each pool shard owns its own slice of the resident buffers
+        rows_dev, flags_dev = self._put((rows_p, flags_p))
         self._resident[gpu_mode] = _ResidentPack(
             key, epoch, rows_dev, flags_dev, rows_p, flags_p)
         return rows_dev, flags_dev
@@ -1426,8 +1431,6 @@ class FusedCycleDriver:
         """Fold quota-group caps and build one DRU-mode group's padded,
         stacked kernel inputs (the wire form :meth:`dispatch_group`
         uploads)."""
-        import jax.numpy as jnp
-
         # Quota-group ids are per dispatch; member pools NOT in this
         # dispatch (no pending jobs, different dru-mode, or direct) still
         # consume the group's cap, so their running usage is folded into
@@ -1490,19 +1493,24 @@ class FusedCycleDriver:
             for i, pp in enumerate(group):
                 avail_p[i, :pp.avail.shape[0]] = pp.avail
                 cap_p[i, :pp.capacity.shape[0]] = pp.capacity
-            scalars = dict(
-                num_considerable=jnp.asarray(np.array(
+            # ``host``: every [P, ...] input as a host array; ONE
+            # placement at the end (self._put, span stage.put) hands each
+            # mesh device its own pools' slice
+            host = dict(
+                num_considerable=np.array(
                     [pp.num_considerable for pp in group]
-                    + [0] * (P - len(group)), dtype=np.int32)),
-                pool_quota=jnp.asarray(np.stack(
+                    + [0] * (P - len(group)), dtype=np.int32),
+                pool_quota=np.stack(
                     [pp.pool_quota for pp in group]
-                    + [np.full(4, INF, dtype=F32)] * (P - len(group)))),
-                group_quota=jnp.asarray(np.stack(
+                    + [np.full(4, INF, dtype=F32)] * (P - len(group))),
+                group_quota=np.stack(
                     [pp.group_quota for pp in group]
-                    + [np.full(4, INF, dtype=F32)] * (P - len(group)))),
-                group_id=jnp.asarray(np.array(
+                    + [np.full(4, INF, dtype=F32)] * (P - len(group))),
+                group_id=np.array(
                     [pp.group_id for pp in group]
-                    + [-1] * (P - len(group)), dtype=np.int32)))
+                    + [-1] * (P - len(group)), dtype=np.int32),
+                avail=avail_p, capacity=cap_p)
+            resident = {}
             if structured:
                 # COMPACT wire form: the per-task upload is the sorted row
                 # permutation + one flags byte (~5 B/task); resource
@@ -1546,54 +1554,41 @@ class FusedCycleDriver:
                     shares_u_p[i, :pp.shares_u.shape[0]] = pp.shares_u
                     quota_u_p[i, :pp.quota_u.shape[0]] = pp.quota_u
                     tokens_u_p[i, :pp.tokens_u.shape[0]] = pp.tokens_u
+                host.update(
+                    tokens_u=tokens_u_p, shares_u=shares_u_p,
+                    quota_u=quota_u_p, host_gpu=host_gpu_p,
+                    host_blocked=host_blocked_p, exc_rows=exc_rows_p,
+                    exc_mask=exc_mask_p)
+                resident = dict(res_base=mir_res, disk_base=mir_disk)
                 if self.config.resident_pack:
                     # DEVICE-RESIDENT wire arrays: steady state ships only
                     # the scatter delta, not the [P, T] world (ISSUE 7)
                     key = (tuple(pp.pool.name for pp in group), P, T)
-                    rows_dev, flags_dev = self._sync_resident(
-                        gpu_mode, key, rows_p, flags_p, epoch)
+                    resident["rows"], resident["flags"] = \
+                        self._sync_resident(gpu_mode, key, rows_p, flags_p,
+                                            epoch)
                 else:  # rebuild mode: dispatch_group accounts the upload
-                    rows_dev = jnp.asarray(rows_p)
-                    flags_dev = jnp.asarray(flags_p)
-                inp = CompactPoolCycleInputs(
-                    rows=rows_dev,
-                    flags=flags_dev,
-                    res_base=mir_res,
-                    disk_base=mir_disk,
-                    tokens_u=jnp.asarray(tokens_u_p),
-                    shares_u=jnp.asarray(shares_u_p),
-                    quota_u=jnp.asarray(quota_u_p),
-                    **scalars,
-                    host_gpu=jnp.asarray(host_gpu_p),
-                    host_blocked=jnp.asarray(host_blocked_p),
-                    exc_rows=jnp.asarray(exc_rows_p),
-                    exc_mask=jnp.asarray(exc_mask_p),
-                    avail=jnp.asarray(avail_p),
-                    capacity=jnp.asarray(cap_p))
+                    host.update(rows=rows_p, flags=flags_p)
+                in_type = CompactPoolCycleInputs
             else:
                 cmask_p = np.zeros((P, T, H), dtype=bool)
                 for i, pp in enumerate(group):
                     cmask_p[i, :pp.n_tasks, :pp.cmask.shape[1]] = pp.cmask
-                inp = PoolCycleInputs(
-                    usage=jnp.asarray(arr("usage", 0)),
-                    quota=jnp.asarray(arr("quota", INF)),
-                    shares=jnp.asarray(arr("shares", INF)),
-                    first_idx=jnp.asarray(arr("first_idx", 0)),
-                    user_rank=jnp.asarray(arr("user_rank", 2**31 - 1)),
-                    pending=jnp.asarray(arr("pending", False)),
-                    valid=jnp.asarray(arr("valid", False)),
-                    enqueue_ok=jnp.asarray(
-                        stack(lambda pp: padT(pp.enqueue_ok, False))),
-                    launch_ok=jnp.asarray(
-                        stack(lambda pp: padT(pp.launch_ok, False))),
-                    tokens=jnp.asarray(
-                        stack(lambda pp: padT(pp.tokens, 0.0))),
-                    **scalars,
-                    job_res=jnp.asarray(
-                        stack(lambda pp: padT(pp.job_res, 0.0))),
-                    cmask=jnp.asarray(cmask_p),
-                    avail=jnp.asarray(avail_p),
-                    capacity=jnp.asarray(cap_p))
+                host.update(
+                    usage=arr("usage", 0), quota=arr("quota", INF),
+                    shares=arr("shares", INF),
+                    first_idx=arr("first_idx", 0),
+                    user_rank=arr("user_rank", 2**31 - 1),
+                    pending=arr("pending", False),
+                    valid=arr("valid", False),
+                    enqueue_ok=stack(lambda pp: padT(pp.enqueue_ok, False)),
+                    launch_ok=stack(lambda pp: padT(pp.launch_ok, False)),
+                    tokens=stack(lambda pp: padT(pp.tokens, 0.0)),
+                    job_res=stack(lambda pp: padT(pp.job_res, 0.0)),
+                    cmask=cmask_p)
+                in_type = PoolCycleInputs
+            with tracing.span("stage.put", arrays=len(host)):
+                inp = in_type(**self._put(host), **resident)
 
             # static match-problem cap: the configured max_jobs_considered
             # (>= every pool's dynamic num_considerable), bucketed so the
@@ -1649,6 +1644,7 @@ class FusedCycleDriver:
                 if copy_async is not None:
                     copy_async()
         _flight.note_path("fused")
+        _flight.note_mesh(self.mesh().size)
         return _GroupDispatch(sg, res, outs)
 
     def fetch_group(self, gd: "_GroupDispatch"):
@@ -1727,7 +1723,7 @@ class FusedCycleDriver:
         # device op): the published queue's closure must NOT keep the whole
         # P-wide buffer — or the rest of pp — alive for its lifetime
         with tracing.span("apply.audit", step="queue-slice"):
-            dev_rows = queue_rows_dev[pool_slot]
+            dev_rows = self._pool_row(queue_rows_dev, pool_slot)
         rows_s = pp.rows_s
         fetched_rows: List[Optional[np.ndarray]] = [None]
 

@@ -144,10 +144,15 @@ class Scheduler:
         # optimistic driver, sched/pipeline.py)
         self._fused = None
         self._pipeline = None
+        self._pool_mesh = None
         # where the kernels run, said once at construction and carried on
         # /debug/health and every CycleRecord: a process that slid off
         # the accelerator must be visible without reading a traceback
         if rank_backend == "cpu":
+            # (the cpu rank backend has no devices to build a mesh from)
+            from ..parallel.mesh import validate_pool_mesh
+            validate_pool_mesh(self.config.pipeline.mesh_devices,
+                               local_devices=0)
             self.device = {"platform": "numpy", "device_kind": "host",
                            "count": 0}
         else:
@@ -155,13 +160,30 @@ class Scheduler:
             self.device["compilation_cache_dir"] = \
                 telemetry.enable_compilation_cache(
                     self.config.pipeline.compilation_cache_dir)
+            # the fused cycle's pool mesh, as the deployment states it
+            # (pipeline.mesh_devices): refused here, before the warm-up,
+            # if this process cannot build it — no cycle quietly runs on
+            # fewer chips than stated
+            import jax
+            from ..parallel.mesh import pool_mesh, validate_pool_mesh
+            n_mesh = self.config.pipeline.mesh_devices
+            validate_pool_mesh(n_mesh,
+                               shards=self.config.partitions.shards,
+                               shard_id=shard_id,
+                               local_devices=jax.local_device_count())
+            self._pool_mesh = pool_mesh(n_mesh)
+            self.device["mesh_devices"] = n_mesh
+            self.device["mesh_device_ids"] = [
+                int(d.id) for d in self._pool_mesh.devices.flat]
         from ..utils import flight
         flight.set_device(self.device)
         import logging
         logging.getLogger(__name__).info(
-            "scheduler kernels run on platform=%s device_kind=%s count=%d",
+            "scheduler kernels run on platform=%s device_kind=%s count=%d "
+            "mesh_devices=%s ids=%s",
             self.device["platform"], self.device["device_kind"],
-            self.device["count"])
+            self.device["count"], self.device.get("mesh_devices"),
+            self.device.get("mesh_device_ids"))
         if rank_backend != "cpu":
             # cold-start tail killer (config.PipelineConfig): with the
             # persistent compilation cache placed above, the boot-time
@@ -698,7 +720,8 @@ class Scheduler:
             from .fused import FusedCycleDriver
             self._fused = FusedCycleDriver(
                 self.store, self.config, self.matcher, self.plugins,
-                self.rate_limits, shard_id=self.shard_id)
+                self.rate_limits, mesh=self._pool_mesh,
+                shard_id=self.shard_id)
             if self.config.pipeline.depth > 0:
                 from .pipeline import PipelinedCycleDriver
                 self._pipeline = PipelinedCycleDriver(

@@ -75,6 +75,9 @@ DETAIL_BY_SPAN = {
     "cycle.launch": "apply",
     "pipeline.host": "pipeline",
     "cycle.publish": "publish",
+    # part of stage (fused._stage_group): host arrays -> device, each
+    # [P, ...] input onto the mesh device that owns its pools
+    "stage.put": "stage_put",
     # parts of pack (fused._pack_pool_columnar / _pack_pool_cached)
     "pack.index": "pack_index",
     "pack.offers": "pack_offers",
@@ -98,6 +101,7 @@ DETAIL_BY_SPAN = {
 }
 #: detail_ms key -> the key it is a part of
 DETAIL_PARENT = {
+    "stage_put": "stage",
     "pack_index": "pack", "pack_offers": "pack", "pack_rows": "pack",
     "apply_lookup": "apply", "apply_txn": "apply",
     "apply_journal": "apply", "apply_cluster": "apply",
@@ -220,7 +224,8 @@ class CycleRecord:
                  "flush_audit_ms", "cpu_ms", "blocked_ms", "lock_holder",
                  "offcpu_ms", "background_ms", "staged_tx", "pipeline_lag_ms",
                  "status_txns", "status_updates", "staged_late",
-                 "_lock_wait_max", "_thread", "_cpu0", "_t0", "_idle")
+                 "mesh_devices", "_lock_wait_max", "_thread", "_cpu0", "_t0",
+                 "_idle")
 
     def __init__(self, seq: int, kind: str):
         self.seq = seq
@@ -277,6 +282,10 @@ class CycleRecord:
         # to split) is visible in /debug/cycles and the Perfetto export
         self.kernel_launches = 0
         self.path: Optional[str] = None
+        # devices of the pool mesh this cycle's dispatch ran over (the
+        # least, should a cycle's groups differ); None, and absent from
+        # the document, on a record that dispatched nothing
+        self.mesh_devices: Optional[int] = None
         # the tick around the cycle (Scheduler.run's loop): the wait for
         # its deadline — the part that preceded the record and, where
         # the cycle was staged a lead before the deadline, the rest of
@@ -405,6 +414,8 @@ class CycleRecord:
         }
         if self.staged_late:
             doc["lead_ms"] = doc["pipeline_lag_ms"]
+        if self.mesh_devices is not None:
+            doc["mesh_devices"] = self.mesh_devices
         return doc
 
 
@@ -685,6 +696,16 @@ class FlightRecorder:
         sp = tracing.tracer.current()
         if sp is not None:
             sp.set_tag("path", rec.path)
+
+    def note_mesh(self, devices: int) -> None:
+        """The pool mesh a dispatch of the current cycle ran over, in
+        devices: a one-device cycle in a four-device deployment (or the
+        reverse) reads off every record."""
+        rec = _current_record.get()
+        if rec is not None:
+            with self._lock:
+                rec.mesh_devices = (int(devices) if rec.mesh_devices is None
+                                    else min(rec.mesh_devices, int(devices)))
 
     def note_fault(self, point: str, n: int = 1) -> None:
         """A fault-point trigger or degradation (kernel fallback, breaker

@@ -28,6 +28,7 @@ WRAPPER_LABELS = {"cook.stage", "cook.dispatch", "cook.fetch", "cook.apply"}
 APPLY_PARTS = ("apply_lookup", "apply_txn", "apply_journal",
                "apply_cluster", "apply_audit")
 PACK_PARTS = ("pack_index", "pack_offers", "pack_rows")
+STAGE_PARTS = ("stage_put",)
 
 
 def make_jobs(n, tag=0, cpus=1.0):
@@ -76,8 +77,10 @@ class TestDetailSplit:
         assert doc["jobs_placed"] > 0
         for key in ("pools", "pack", "stage", "dispatch", "fetch", "apply",
                     "pipeline", "publish", "other") + APPLY_PARTS \
-                + PACK_PARTS:
+                + PACK_PARTS + STAGE_PARTS:
             assert key in d, key
+        # the placement of the staged inputs is a part of the stage
+        assert 0.0 < d["stage_put"] <= d["stage"] + 0.05
         apply_parts = sum(d[k] for k in APPLY_PARTS)
         pack_parts = sum(d[k] for k in PACK_PARTS)
         # the parts never overlap, so they cannot exceed the whole; what
@@ -151,7 +154,8 @@ class TestDetailSplit:
         assert not set(flight.DETAIL_SWEEPS) & set(flight.DETAIL_TOP_LEVEL)
         assert set(flight.DETAIL_PARENT.values()) \
             <= set(flight.DETAIL_TOP_LEVEL)
-        assert set(APPLY_PARTS + PACK_PARTS) == set(flight.DETAIL_PARENT)
+        assert set(APPLY_PARTS + PACK_PARTS + STAGE_PARTS) \
+            == set(flight.DETAIL_PARENT)
 
     def test_finish_reads_no_span_ring_and_phases_match_it(self,
                                                            monkeypatch):
