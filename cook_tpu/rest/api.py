@@ -2691,6 +2691,14 @@ class _Handler(BaseHTTPRequestHandler):
     timeout = 120
 
     # ------------------------------------------------------------- plumbing
+    def setup(self):
+        # one thread per connection: a REST handler thread from here on,
+        # by name (tracing.thread_role: the "http" of the wait and drain
+        # accounting) and on the profiler's clock, on a line of its own
+        threading.current_thread().name = "cook-http"
+        tracing.annotate_spans(thread_name="cook-http")
+        super().setup()
+
     def log_message(self, fmt, *args):  # pragma: no cover - silence
         pass
 
@@ -2877,6 +2885,7 @@ class _Handler(BaseHTTPRequestHandler):
             bytes_in = 0
         obs.begin()
         t0 = time.perf_counter()
+        cpu0 = time.thread_time()
         trace_id = None
         phases: Dict[str, float] = {}
         try:
@@ -2891,16 +2900,19 @@ class _Handler(BaseHTTPRequestHandler):
                 if user:
                     sp.set_tag("user", user)
         finally:
+            wall_s = time.perf_counter() - t0
+            cpu_s = time.thread_time() - cpu0
             obs.end(
                 method=method, endpoint=endpoint, status=self._status,
-                duration_s=time.perf_counter() - t0, phases=phases,
+                duration_s=wall_s, phases=phases,
                 params=(urllib.parse.parse_qs(parsed.query)
                         if parsed.query else {}),
                 request_id=self._request_id, trace_id=trace_id,
                 user=str(getattr(self, "_auth_user", "") or ""),
                 bytes_in=bytes_in, bytes_out=self._bytes_out,
                 objective_s=self.api.config.slo
-                .endpoint_latency_objective_s)
+                .endpoint_latency_objective_s,
+                cpu_s=cpu_s)
 
     def _drained_bucket_reject(self) -> bool:
         """Ingress fast path for the stampede case (DAGOR: reject at

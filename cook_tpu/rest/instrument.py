@@ -35,6 +35,7 @@ import time as _time
 from collections import deque
 from typing import Any, Dict, List, Optional, Tuple
 
+from ..utils import flight, tracing
 from ..utils.metrics import registry
 
 # endpoint label value for paths matching no registered route: the 404
@@ -46,13 +47,25 @@ UNMATCHED = "{unmatched}"
 REQUEST_SIZE_BUCKETS = (256.0, 1024.0, 4096.0, 16384.0, 65536.0,
                         262144.0, 1048576.0, 4194304.0)
 
-# the span names the phase decomposition publishes (the tracing phase
+# the waits of a request on other threads, each a bare duration handed
+# to its phase dict (tracing.wait_time / timed_wait), none inside
+# another: a contended store lock (utils/flight.py's contention sink),
+# the spin while another thread holds store.notify, this thread's own
+# delivery of queued events, the group-commit round (state/store.py)
+WAIT_PHASES = ("store.lock-wait", "store.notify-wait", "store.drain",
+               "journal.commit-wait")
+
+# the phase names the decomposition publishes (the tracing phase
 # collector records EVERY span; exporting them all as label values would
 # let any future span silently widen a metric family).  journal.fsync is
 # the group-commit stage's batched force, attributed back into each
-# waiting request via tracer.record_finished (state/store.py).
+# waiting request via tracer.record_finished (state/store.py).  The
+# WAIT_PHASES with ``cpu`` (the handler thread's own CPU outside them)
+# and ``offcpu`` (what is left: runnable, waiting for the GIL or a core)
+# partition the request's wall, as CycleRecord's blocked_ms, cpu_ms and
+# offcpu_ms partition a cycle's
 PHASE_SPANS = ("journal.append", "journal.fsync", "repl.ack_wait",
-               "remote.launch")
+               "remote.launch") + WAIT_PHASES + ("cpu", "offcpu")
 
 # query params whose values never reach the capture ring verbatim
 _REDACT_KEYS = frozenset({"token", "password", "authorization", "secret"})
@@ -193,8 +206,20 @@ class RequestObserver:
             duration_s: float, phases: Dict[str, float],
             params: Dict[str, List[str]], request_id: str,
             trace_id: Optional[str], user: str, bytes_in: int,
-            bytes_out: int, objective_s: Optional[float] = None) -> None:
+            bytes_out: int, objective_s: Optional[float] = None,
+            cpu_s: Optional[float] = None) -> None:
         labels = {"endpoint": endpoint, "method": method}
+        if cpu_s is not None:
+            # the handler thread's time.thread_time() over the request,
+            # less its waits' own.  A thread CPU clock can tick coarsely
+            # (10 ms on some hosts): no request ran longer than its wall
+            # outside its waits, so the parts still sum to the wall
+            rest = max(duration_s - sum(
+                phases.get(name, 0.0) for name in WAIT_PHASES), 0.0)
+            cpu = min(max(cpu_s - phases.get(tracing.WAIT_CPU, 0.0), 0.0),
+                      rest)
+            phases["cpu"] = cpu
+            phases["offcpu"] = rest - cpu
         registry.counter_inc("cook_http_requests", 1.0,
                              {**labels, "code": str(status)})
         registry.observe("cook_http_request_duration_seconds",
@@ -247,6 +272,8 @@ class RequestObserver:
         if ack_share is not None:
             registry.gauge_set("cook_http_ack_wait_share",
                                round(ack_share, 6))
+        # the lock waits queued meanwhile, this request's among them
+        flight.publish_waits()
 
     # ---------------------------------------------------------------- queries
     def snapshot(self, limit: int = 50) -> Dict[str, Any]:

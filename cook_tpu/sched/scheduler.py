@@ -413,8 +413,11 @@ class Scheduler:
             self._gc_collect_due = False
             import gc
             t0 = time.perf_counter()
-            gc.collect()
-            gc.freeze()
+            # a span too, on the profiler's clock (the cycle thread's
+            # line); it ends outside any record, so no record key
+            with tracing.span("tick.gc"):
+                gc.collect()
+                gc.freeze()
             # a field of the tick: carried on this thread's next record
             flight_recorder.note_tick(
                 "gc_ms", (time.perf_counter() - t0) * 1000.0)
@@ -903,7 +906,8 @@ class Scheduler:
         # outside the record (duration_ms sums what it always summed);
         # timed as a field of the tick, carried on the NEXT record
         t0 = time.perf_counter()
-        self.store.flush_audit()
+        with tracing.span("tick.flush-audit"):
+            self.store.flush_audit()
         flight_recorder.note_tick(
             "flush_audit_ms", (time.perf_counter() - t0) * 1000.0)
         return results

@@ -602,8 +602,11 @@ class Store:
         self._subscribers: List[Callable[[int, List[TxEvent]], None]] = []
         # Commit-ordered event delivery (the reference's tx-report *queue*):
         # events enqueue under the main lock and drain under _notify_lock, so
-        # subscribers always observe transactions in tx_id order.
-        self._event_queue: List[Tuple[int, List[TxEvent]]] = []
+        # subscribers always observe transactions in tx_id order.  Each
+        # batch carries the thread that committed it: whoever drains says
+        # whose events it delivered (cook_store_event_batches_total)
+        self._event_queue: List[
+            Tuple[int, List[TxEvent], threading.Thread]] = []
         self._notify_lock = named_lock("store.notify" + _sfx)
         self._draining = threading.local()
         # durable redo journal (attached via attach_journal / Store.open)
@@ -726,7 +729,8 @@ class Store:
             for latch in txn.latch_pops:
                 self._latches.pop(latch, None)
             if txn.events:
-                self._event_queue.append((self._tx_id, txn.events))
+                self._event_queue.append(
+                    (self._tx_id, txn.events, threading.current_thread()))
         if drain_span and tracing.tracer.current() is not None:
             with tracing.span("store.drain-events", events=len(txn.events)):
                 self._drain_events()
@@ -735,14 +739,13 @@ class Store:
         if waiter is not None:
             # the group-commit round this thread blocks on.  A scheduler
             # cycle's record takes it as blocked_ms.commit_wait and
-            # detail_ms.apply_journal (utils/flight.py) — as a bare
-            # duration, not a span: every transaction of every thread
-            # passes here, and a cycle waits twice a pool (the launch
-            # transaction, the burst's one status transaction)
-            t0 = time.perf_counter()
-            err = waiter.stage.wait(waiter)
-            tracing.cycle_time("journal.commit-wait",
-                               time.perf_counter() - t0)
+            # detail_ms.apply_journal (utils/flight.py), a request as its
+            # journal.commit-wait part — as a bare duration, not a span:
+            # every transaction of every thread passes here, and a cycle
+            # waits twice a pool (the launch transaction, the burst's one
+            # status transaction)
+            with tracing.timed_wait("journal.commit-wait"):
+                err = waiter.stage.wait(waiter)
             # attribute the SHARED round's cost into this request's own
             # trace/phase breakdown (rest/instrument.py PHASE_SPANS):
             # the committer measured it once; every waiter reports it
@@ -1169,27 +1172,62 @@ class Store:
         every subscriber's view in tx_id order (and avoiding re-entry)."""
         if getattr(self._draining, "active", False):
             return
-        while not self._notify_lock.acquire(blocking=False):
+        if not self._notify_lock.acquire(blocking=False):
             # Another thread is draining and will deliver our events — unless
             # it is just exiting; spin until the queue empties or we win the
             # lock (waiting blocking would serialize commits behind callbacks).
-            with self._lock:
-                if not self._event_queue:
-                    return
-            time.sleep(0)
+            # The spin is a wait on that thread: a request's
+            # store.notify-wait, a cycle's blocked_ms.notify, with the
+            # store-lock waits of its looks at the queue inside it
+            with tracing.timed_wait("store.notify-wait"):
+                while True:
+                    with self._lock:
+                        if not self._event_queue:
+                            return
+                    time.sleep(0)
+                    if self._notify_lock.acquire(blocking=False):
+                        break
         self._draining.active = True
+        owners: Dict[threading.Thread, int] = {}
         try:
-            while True:
-                with self._lock:
-                    if not self._event_queue:
-                        return
-                    tx_id, events = self._event_queue.pop(0)
-                    subscribers = list(self._subscribers)
-                for sub in subscribers:
-                    sub(tx_id, events)
+            # a request's store.drain: what this thread spent delivering,
+            # its own events and whoever else's were queued
+            with tracing.timed_wait("store.drain") \
+                    if tracing.collecting() else nullcontext():
+                while True:
+                    with self._lock:
+                        if not self._event_queue:
+                            return
+                        tx_id, events, owner = self._event_queue.pop(0)
+                        subscribers = list(self._subscribers)
+                    for sub in subscribers:
+                        sub(tx_id, events)
+                    owners[owner] = owners.get(owner, 0) + 1
         finally:
             self._draining.active = False
             self._notify_lock.release()
+            self._count_batches(owners)
+
+    @staticmethod
+    def _count_batches(owners: Dict[threading.Thread, int]) -> None:
+        """The event batches one drain delivered, by the thread whose
+        transaction made each: ``cook_store_event_batches_total{drainer,
+        owner}`` by thread role, and the other threads' batches on the
+        drainer's cycle record (``foreign_event_batches``)."""
+        if not owners:
+            return
+        me = threading.current_thread()
+        drainer = tracing.thread_role(me.name)
+        by_role: Dict[str, int] = {}
+        for owner, n in owners.items():
+            role = tracing.thread_role(owner.name)
+            by_role[role] = by_role.get(role, 0) + n
+        for role, n in by_role.items():
+            _metrics.counter_inc("cook_store_event_batches", float(n),
+                                 {"drainer": drainer, "owner": role})
+        foreign = sum(n for owner, n in owners.items() if owner is not me)
+        if foreign:
+            flight.recorder.note_foreign_batches(foreign)
 
     def subscribe(self, fn: Callable[[int, List[TxEvent]], None]) -> None:
         with self._lock:

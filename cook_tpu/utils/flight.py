@@ -116,7 +116,10 @@ DETAIL_TOP_LEVEL = ("pools", "pack", "stage", "dispatch", "fetch", "apply",
 DETAIL_SWEEPS = ("scan", "copy", "fold")
 
 #: span name -> blocked_ms key: a named wait of the recording thread
-BLOCKED_BY_SPAN = {"journal.commit-wait": "commit_wait"}
+#: (both bare durations, tracing.cycle_time: the group-commit round, and
+#: the spin in Store._drain_events while another thread delivers events)
+BLOCKED_BY_SPAN = {"journal.commit-wait": "commit_wait",
+                   "store.notify-wait": "notify"}
 # the three tables above merged, so a finished span costs one lookup:
 # name -> (phase, detail key, the key's declared parent, blocked key)
 _ROUTES = {
@@ -133,7 +136,7 @@ CYCLE_KINDS = frozenset({"cycle", "fused", "rank", "match"})
 BACKGROUND_LOOPS = ("reapers", "monitor", "rebalance")
 #: blocked_ms keys every record carries; a contended lock of another
 #: family adds ``<family>_lock`` beside them
-BLOCKED_KEYS = ("store_lock", "commit_wait", "device", "gc")
+BLOCKED_KEYS = ("store_lock", "commit_wait", "device", "gc", "notify")
 
 # the current record IS the tracer's per-cycle span sink: one context
 # variable, set once per cycle (copied contexts — the per-cluster launch
@@ -175,6 +178,32 @@ def _publish_gc_pauses(limit: int = 512) -> None:
             return
         registry.observe("cook_gc_pause_seconds", seconds,
                          {"generation": str(gen)})
+
+
+# Contended named-lock waits not yet on cook_lock_wait_seconds.  The
+# contention sink runs while the waiter holds the lock it waited for, so
+# it only appends here, and publish_waits() observes them outside any
+# lock, by family and thread role (at a record's end, a request's end).
+_lock_waits: "deque[tuple]" = deque(maxlen=16384)  # (family, waiter, holder, s)
+
+
+def publish_waits(limit: int = 16384) -> None:
+    """Observe the pending contended lock waits on
+    ``cook_lock_wait_seconds{lock, waiter, holder}`` (one registry call a
+    label set) and the pending collector pauses."""
+    groups: Dict[tuple, List[float]] = {}
+    for _ in range(limit):
+        try:
+            fam, waiter, holder, seconds = _lock_waits.popleft()
+        except IndexError:
+            break
+        key = (fam, tracing.thread_role(waiter), tracing.thread_role(holder))
+        groups.setdefault(key, []).append(seconds)
+    for (fam, waiter, holder), values in groups.items():
+        registry.observe_many("cook_lock_wait_seconds", values,
+                              {"lock": fam, "waiter": waiter,
+                               "holder": holder})
+    _publish_gc_pauses()
 
 # process-wide shard identity (ISSUE 19): a sharded-controller process
 # owns exactly ONE partition shard, so the id is process state, not
@@ -224,8 +253,8 @@ class CycleRecord:
                  "flush_audit_ms", "cpu_ms", "blocked_ms", "lock_holder",
                  "offcpu_ms", "background_ms", "staged_tx", "pipeline_lag_ms",
                  "status_txns", "status_updates", "staged_late",
-                 "mesh_devices", "_lock_wait_max", "_thread", "_cpu0", "_t0",
-                 "_idle")
+                 "mesh_devices", "foreign_event_batches", "_lock_wait_max",
+                 "_wait_cpu", "_thread", "_cpu0", "_t0", "_idle")
 
     def __init__(self, seq: int, kind: str):
         self.seq = seq
@@ -324,7 +353,14 @@ class CycleRecord:
         # burst acknowledged in one transaction reads 1 and the burst
         self.status_txns = 0
         self.status_updates = 0
+        # event batches of OTHER threads' transactions this cycle's
+        # thread delivered (Store._drain_events: whoever holds the notify
+        # lock delivers everybody's events)
+        self.foreign_event_batches = 0
         self._lock_wait_max = 0.0
+        # CPU this thread burned inside its timed waits (the notify
+        # spin): counted there, so taken off cpu_ms
+        self._wait_cpu = 0.0
         self._thread = threading.get_ident()
         self._cpu0 = time.thread_time()
         self._t0 = time.perf_counter()
@@ -340,11 +376,14 @@ class CycleRecord:
             over -= max(0.0, min(hi, i1) - max(lo, i0))
         return max(0.0, over) * 1000.0
 
-    def add_span(self, name: str, seconds: float, open_spans) -> None:
+    def add_span(self, name: str, seconds: float, open_spans,
+                 cpu_s: float = 0.0) -> None:
         """A span ended inside this cycle (called by Tracer._record, in
         the ending thread): route its time to the phase, the detail_ms
         key and the blocked_ms key its name maps to.  ``open_spans`` is
-        the span stack still open around it, innermost last."""
+        the span stack still open around it, innermost last; ``cpu_s``
+        the CPU a bare wait burned (tracing.cycle_time), which a named
+        wait of this record's own thread takes off ``cpu_ms``."""
         route = _ROUTES.get(name)
         if route is None:
             return
@@ -354,6 +393,8 @@ class CycleRecord:
             self.phases[phase] = self.phases.get(phase, 0.0) + ms
         if blocked is not None:
             self.blocked_ms[blocked] += ms
+            if cpu_s and threading.get_ident() == self._thread:
+                self._wait_cpu += cpu_s * 1000.0
         if key is None:
             return
         detail = self.detail_ms
@@ -409,6 +450,7 @@ class CycleRecord:
             "pipeline_lag_ms": round(self.pipeline_lag_ms, 3),
             "status_txns": self.status_txns,
             "status_updates": self.status_updates,
+            "foreign_event_batches": self.foreign_event_batches,
             "staged_late": self.staged_late,
             "error": self.error,
         }
@@ -515,8 +557,8 @@ class FlightRecorder:
                     own_gc_ms += over
         blocked["gc"] = gc_ms
         # a collection on this very thread is CPU time of this thread:
-        # it counts as the pause it was, once
-        rec.cpu_ms -= own_gc_ms
+        # it counts as the pause it was, once; so does a spin
+        rec.cpu_ms -= own_gc_ms + rec._wait_cpu
         rec.offcpu_ms = rec.duration_ms - rec.cpu_ms - sum(blocked.values())
         with self._lock:
             if rec.kind in CYCLE_KINDS:
@@ -528,7 +570,7 @@ class FlightRecorder:
                         rec.background_ms[kind] = \
                             rec.background_ms.get(kind, 0.0) + over
             self._ring.append(rec)
-        _publish_gc_pauses()
+        publish_waits()
         registry.observe("cook_cycle_duration_seconds",
                          rec.duration_ms / 1000.0, {"kind": rec.kind})
         if rec.jobs_considered:
@@ -612,15 +654,23 @@ class FlightRecorder:
         notes[field] = notes.get(field, 0.0) + float(ms)
 
     def note_lock_wait(self, lock_name: str, seconds: float,
-                       holder: Optional[str]) -> None:
-        """A CONTENDED named-lock acquisition by the current thread
-        (utils/locks.py reports every one): the wait lands on this
-        thread's record under ``<family>_lock``, and the holder of the
-        longest wait is kept."""
+                       holder: Optional[str], cpu_s: float = 0.0) -> None:
+        """A CONTENDED named-lock acquisition by the current thread outside
+        a timed wait (utils/locks.py reports every one): it is queued for
+        ``cook_lock_wait_seconds`` (publish_waits); a wait on the store
+        lock is a part of the current request's wall (``store.lock-wait``,
+        with the CPU it burned, tracing.wait_time); and the wait lands on
+        this thread's record under ``<family>_lock``, and the holder of
+        the longest wait is kept."""
+        fam = locks.family(lock_name)
+        _lock_waits.append(
+            (fam, threading.current_thread().name, holder, seconds))
+        if fam == "store":
+            tracing.wait_time("store.lock-wait", seconds, cpu_s)
         rec = _current_record.get()
         if rec is None:
             return
-        key = locks.family(lock_name).replace(".", "_") + "_lock"
+        key = fam.replace(".", "_") + "_lock"
         rec.blocked_ms[key] = rec.blocked_ms.get(key, 0.0) \
             + seconds * 1000.0
         if seconds > rec._lock_wait_max:
@@ -648,6 +698,15 @@ class FlightRecorder:
             with self._lock:
                 rec.status_txns += 1
                 rec.status_updates += int(entries)
+
+    def note_foreign_batches(self, n: int) -> None:
+        """Event batches of other threads' transactions the current
+        thread delivered (Store._drain_events); outside a record it is
+        one contextvar read."""
+        rec = _current_record.get()
+        if rec is not None and n:
+            with self._lock:
+                rec.foreign_event_batches += int(n)
 
     def note_delta(self, rows: int) -> None:
         """Delta rows scatter-applied into the device-resident pack this

@@ -80,6 +80,8 @@ import time
 import traceback
 from typing import Any, Dict, List, Optional, Set, Tuple
 
+from . import tracing
+
 #: (lock name, operation) pairs that are BY DESIGN blocking while held —
 #: each entry is a documented contract, not an oversight.  Consulted by
 #: BOTH rails: the armed runtime detector below AND the static
@@ -151,10 +153,12 @@ class LockMonitor:
         self.violations: List[Dict[str, Any]] = []
         self.blocking_events: List[Dict[str, Any]] = []
         self.allowed_blocking: Set[Tuple[str, str]] = set(ALLOWED_BLOCKING)
-        # called as (lock name, seconds waited, holder thread name) after
-        # every CONTENDED acquisition, in the thread that waited; the
-        # flight recorder (utils/flight.py) installs itself here so the
-        # wait lands on the waiting thread's CycleRecord
+        # called as (lock name, seconds waited, holder thread name, CPU
+        # seconds the wait burned) after every CONTENDED acquisition made
+        # outside a timed wait, in the thread that waited; the flight
+        # recorder (utils/flight.py)
+        # installs itself here so the wait lands on the waiting thread's
+        # CycleRecord, its request's phases and cook_lock_wait_seconds
         self.contention_sink = None
         self._armed = False
         self._originals: Dict[str, Any] = {}
@@ -444,22 +448,43 @@ class NamedLock:
         # always was; only a lock somebody else holds is timed
         ok = self._lock.acquire(False)
         if not ok and blocking:
-            holder = self._holder_name()
-            t0 = time.perf_counter()
-            if timeout is None or timeout < 0:
-                # wake now and then to look at who holds it: the holder
-                # of a long wait is then the one seen during the wait,
-                # not whoever held it at the first attempt
-                while not self._lock.acquire(True, 0.05):
-                    holder = self._holder_name() or holder
-                ok = True
+            if tracing.in_timed_wait():
+                # a wait inside a timed wait is a part of it (the notify
+                # spin looks at the queue under this lock turn after
+                # turn): the frame's clock has it, so no clock, no sink
+                ok = self._lock.acquire(
+                    True, -1 if timeout is None else timeout)
             else:
-                ok = self._lock.acquire(True, timeout)
-            sink = self._monitor.contention_sink
-            if sink is not None:
-                sink(self.name, time.perf_counter() - t0, holder)
+                ok = self._timed_acquire(timeout)
         if ok and not reentrant:
             self._monitor._note_acquired(self)
+        return ok
+
+    def _timed_acquire(self, timeout: Optional[float]) -> bool:
+        """A contended acquisition, timed and reported to the contention
+        sink with the thread that held the lock."""
+        holder = self._holder_name()
+        # on the profiler's clock too, on an annotated thread (the
+        # cycle's, a REST handler's): "store.lock-wait", ...
+        note = tracing._annotation(family(self.name) + ".lock-wait")
+        if note is not None:
+            note.__enter__()
+        t0, c0 = time.perf_counter(), time.thread_time()
+        if timeout is None or timeout < 0:
+            # wake now and then to look at who holds it: the holder
+            # of a long wait is then the one seen during the wait,
+            # not whoever held it at the first attempt
+            while not self._lock.acquire(True, 0.05):
+                holder = self._holder_name() or holder
+            ok = True
+        else:
+            ok = self._lock.acquire(True, timeout)
+        if note is not None:
+            note.__exit__(None, None, None)
+        sink = self._monitor.contention_sink
+        if sink is not None:
+            sink(self.name, time.perf_counter() - t0, holder,
+                 time.thread_time() - c0)
         return ok
 
     def _holder_name(self) -> Optional[str]:

@@ -70,11 +70,13 @@ _cycle_var: "contextvars.ContextVar[Optional[Any]]" = \
     contextvars.ContextVar("cook_cycle_sink", default=None)
 
 # Scheduler threads (the cycle thread and Scheduler.run's loop threads)
-# also put every span on the PROFILER's clock: a jax.profiler
-# TraceAnnotation of the same name, so the program's spans land in the
-# xplane host plane beside the device ops whoever started the trace
-# (POST /debug/profile, a benchmark harness).  REST threads never set
-# the flag: an http.request span costs no annotation.
+# and the REST handler threads (rest/api.py, the ``cook-http`` line) also
+# put every span on the PROFILER's clock: a jax.profiler TraceAnnotation
+# of the same name, so the program's spans land in the xplane host plane
+# beside the device ops whoever started the trace (POST /debug/profile,
+# a benchmark harness) — a submission's ``store.lock-wait`` lines up with
+# the cycle's ``store.launch-txn``.  A thread that never set the flag
+# (a test's, a bulk load's) opens no annotation.
 _annotate_var: "contextvars.ContextVar[bool]" = \
     contextvars.ContextVar("cook_span_annotate", default=False)
 _annotation_cls: Any = None
@@ -130,17 +132,113 @@ def _annotation(name: str):
     return cls(name) if cls else None
 
 
-def cycle_time(name: str, seconds: float) -> None:
+# The timed wait open in this context (:func:`timed_wait`): [seconds,
+# cpu seconds] of the timed waits that ended inside it, which are parts
+# of their own and are left out of it, so that no part of a request's
+# wall is counted twice (a subscriber's commit wait inside the drain).
+# A contended lock acquired inside it is not timed at all: it is a part
+# of the frame (the notify spin's look at the queue, turn after turn)
+_wait_var: "contextvars.ContextVar[Optional[list]]" = \
+    contextvars.ContextVar("cook_open_wait", default=None)
+
+#: phase-dict key of the CPU a thread burned inside its timed waits (a
+#: spin, a drain): rest/instrument.py takes it off the request's own
+#: ``cpu`` part, since those waits are parts of their own.  Never exported
+WAIT_CPU = "wait.cpu"
+
+
+def wait_time(name: str, seconds: float, cpu_s: float = 0.0) -> None:
+    """Hand an already-measured wait to the current REQUEST: its phase
+    dict (:func:`collect_phases`) under ``name``, and the timed wait
+    open around it, which leaves it out.  No cycle record; outside a
+    request and a timed wait it is two contextvar reads."""
+    phases = _phases_var.get()
+    if phases is not None:
+        phases[name] = phases.get(name, 0.0) + seconds
+        if cpu_s:
+            phases[WAIT_CPU] = phases.get(WAIT_CPU, 0.0) + cpu_s
+    frame = _wait_var.get()
+    if frame is not None:
+        frame[0] += seconds
+        frame[1] += cpu_s
+
+
+def cycle_time(name: str, seconds: float, cpu_s: float = 0.0) -> None:
     """Hand an already-measured duration to the current cycle's record
     under a span NAME, without minting a span: for a wait every
     transaction of every thread passes through (the group-commit wait),
     where a span each would cost more than the wait tells.
     It goes through the same name -> key table as a finished span
-    (flight.DETAIL_BY_SPAN), nesting carve-out included; outside a cycle
-    it is one contextvar read."""
+    (flight.DETAIL_BY_SPAN), nesting carve-out included, and to the
+    current request's phase dict as well (:func:`wait_time`).  ``cpu_s``
+    is the CPU the waiting thread burned inside the wait (a spin), which
+    is then not its own CPU time."""
+    wait_time(name, seconds, cpu_s)
     sink = _cycle_var.get()
     if sink is not None:
-        sink.add_span(name, seconds, _stack_var.get())
+        sink.add_span(name, seconds, _stack_var.get(), cpu_s)
+
+
+@contextmanager
+def timed_wait(name: str):
+    """Time what runs inside as a bare duration under ``name``
+    (:func:`cycle_time`): a wait of this thread on another one (the
+    notify spin) or its delivery of other threads' work (the event
+    drain).  The timed waits that end inside it are left out of it —
+    they are parts of their own — while a contended lock acquired inside
+    it is a part of it (:func:`in_timed_wait`); the CPU it burned is
+    reported beside it.  On an annotated thread it is a profiler annotation of
+    the same name too, so the wait lines up with what it waited for."""
+    note = _annotation(name)
+    if note is not None:
+        note.__enter__()
+    frame = [0.0, 0.0]
+    token = _wait_var.set(frame)
+    t0, c0 = time.perf_counter(), time.thread_time()
+    try:
+        yield
+    finally:
+        wall = time.perf_counter() - t0 - frame[0]
+        cpu = time.thread_time() - c0 - frame[1]
+        _wait_var.reset(token)
+        if note is not None:
+            note.__exit__(None, None, None)
+        cycle_time(name, max(wall, 0.0), max(cpu, 0.0))
+
+
+def in_timed_wait() -> bool:
+    """True inside a :func:`timed_wait`: a contended lock acquired there is
+    a part of that wait, not a wait of its own (utils/locks.py)."""
+    return _wait_var.get() is not None
+
+
+def collecting() -> bool:
+    """True inside a request's phase collector: what only a request reads
+    (its event drain) is timed only there."""
+    return _phases_var.get() is not None
+
+
+# --------------------------------------------------------- thread roles
+# Who waits on whom, in a bounded label set: the role of a thread, read
+# off the name the program gives it (Scheduler.run's loop threads, the
+# REST handler threads, the group committer).  Any other thread is
+# "other", so a label of cook_lock_wait_seconds /
+# cook_store_event_batches_total never takes more than these values.
+ROLES = ("cycle", "monitor", "reapers", "rebalance", "http", "committer",
+         "other")
+_ROLE_BY_PREFIX = (("cook-cycle", "cycle"), ("cook-monitor", "monitor"),
+                   ("cook-reapers", "reapers"),
+                   ("cook-rebalance", "rebalance"), ("cook-http", "http"),
+                   ("cook-group-commit", "committer"))
+
+
+def thread_role(name: Optional[str]) -> str:
+    """The role of the thread named ``name`` (one of :data:`ROLES`)."""
+    if name:
+        for prefix, role in _ROLE_BY_PREFIX:
+            if name.startswith(prefix):
+                return role
+    return "other"
 
 
 @contextmanager

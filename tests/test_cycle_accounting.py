@@ -265,10 +265,12 @@ class TestCycleThreadWaits:
         with plain:
             pass
         t.join()
-        assert [(n, h) for n, _s, h in seen] == [("index", "holder")] \
-            or [(n, h) for n, _s, h in seen] == [("index", "holder"),
-                                                  ("audit", None)]
+        assert [(n, h) for n, _s, h, _c in seen] == [("index", "holder")] \
+            or [(n, h) for n, _s, h, _c in seen] == [("index", "holder"),
+                                                      ("audit", None)]
         assert seen[0][1] >= 0.05
+        # the CPU the waiting thread burned in the wait: a sliver of it
+        assert 0.0 <= seen[0][3] < seen[0][1]
         # uncontended: the sink hears nothing
         seen.clear()
         with lk:
@@ -829,6 +831,11 @@ def test_names_the_yardstick_reads_are_stable():
                   "pipeline_lag_ms", "staged_tx", "cpu_ms", "lock_holder",
                   "pools", "status_txns", "status_updates"):
         assert field in doc, field
+    assert "notify" in doc["blocked_ms"]
+    from cook_tpu.rest import instrument
+    assert {"cpu", "offcpu", "store.lock-wait", "store.notify-wait",
+            "store.drain", "journal.commit-wait"} \
+        <= set(instrument.PHASE_SPANS)
     by_key = {}
     for span, key in flight.DETAIL_BY_SPAN.items():
         by_key.setdefault(key, set()).add(span)
